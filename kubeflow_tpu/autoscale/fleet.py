@@ -215,6 +215,13 @@ def subprocess_launcher(
     fresh path the subprocess must write its bound port to; the launcher
     then polls ``ready_path`` until the replica answers ready.
 
+    One process per chip: this launcher (and the gateway it runs beside)
+    imports JAX but never opens a backend, so the children own the
+    devices — but nothing here restricts which. Every child sees the
+    whole host; a fleet of more than one TPU replica per host must pin
+    each to its own chip in ``command`` (``{index}`` is substituted), or
+    the second child fails or hangs at backend start.
+
     Returns an async ``launch(index)`` suitable for :class:`ReplicaFleet`.
     """
     import os

@@ -21,21 +21,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # jax < 0.5: experimental namespace, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, check_vma: bool = True, **kwargs):
-        return _shard_map_exp(f, check_rep=check_vma, **kwargs)
-
-try:
-    axis_size = lax.axis_size
-except AttributeError:  # jax < 0.5: psum of a unit constant folds to the
-    # axis size as a concrete int at trace time
-    def axis_size(axis_name):
-        return lax.psum(1, axis_name)
-
 
 # --------------------------------------------------------------------------- #
 # Named-axis wrappers. Inside shard_map/pjit these lower to single ICI
@@ -67,7 +52,7 @@ def ring_shift(x, axis: str, *, shift: int = 1):
     """Rotate shards around the axis ring with ``ppermute`` — the building
     block of ring attention (KV rotation) and pipeline stage handoff.
     ICI tori make each hop a physical-neighbor transfer."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis, perm)
 
@@ -135,7 +120,7 @@ def benchmark_collective(
     def step(x):
         # check_vma=False: all_gather output is replicated over `axis`, which
         # the static varying-manifest check can't always infer.
-        return shard_map(
+        return jax.shard_map(
             op, mesh=mesh, in_specs=spec, out_specs=_out_spec(kind, axis),
             check_vma=False,
         )(x)

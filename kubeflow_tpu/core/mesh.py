@@ -263,28 +263,6 @@ def single_device_mesh() -> Mesh:
     return build_mesh(MeshSpec(), devices=jax.devices()[:1])
 
 
-def mesh_context(mesh: Mesh):
-    """``jax.set_mesh(mesh)`` where it exists (jax ≥ 0.5); on older jax
-    the ``with mesh:`` physical-mesh context is the same ambient-mesh
-    mechanism (it is what :func:`current_mesh` reads back)."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
-
-
-def current_mesh():
-    """The ambient mesh (``.empty`` when none): the public
-    ``jax.sharding.get_abstract_mesh`` on new jax; on jax < 0.5 — where
-    that API doesn't exist — the ``with mesh:`` context's physical mesh."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        return getter()
-    from jax._src import mesh as _mesh_src
-
-    return _mesh_src.thread_resources.env.physical_mesh
-
-
 def per_device_batch(global_batch: int, spec: MeshSpec) -> int:
     """Per-batch-shard size; validates divisibility like DDP samplers do."""
     parts = spec.batch_partitions

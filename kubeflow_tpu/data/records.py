@@ -1,6 +1,6 @@
 """Native record IO: the C++ input pipeline's Python surface.
 
-Binds ``native/kftdata.cpp`` (built on demand with g++ into a cache dir)
+Binds ``native/kftdata.cpp`` (built on demand with g++ into ``build/native``)
 via ctypes — no pybind11 in this image (SURVEY.md §0). The native library
 owns the hot path: record reads, seeded shuffle, batch assembly, and a
 bounded prefetch queue run in a C++ producer thread; Python receives one
@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import ctypes
 import dataclasses
+import hashlib
 import os
 import subprocess
 import threading
@@ -26,7 +27,9 @@ from typing import Iterator, Mapping, Sequence
 
 import numpy as np
 
-_SRC = Path(__file__).resolve().parent.parent.parent / "native" / "kftdata.cpp"
+_REPO = Path(__file__).resolve().parents[2]
+_SRC = _REPO / "native" / "kftdata.cpp"
+_BUILD_DIR = _REPO / "build" / "native"
 _MAGIC = 0x4B465452
 _HEADER = np.dtype(
     [("magic", "<u4"), ("record_bytes", "<u4"), ("count", "<u8")]
@@ -40,22 +43,17 @@ class NativeBuildError(RuntimeError):
     pass
 
 
-def _cache_dir() -> Path:
-    d = os.environ.get("KFT_NATIVE_CACHE") or os.path.join(
-        os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-        "kubeflow_tpu",
-    )
-    Path(d).mkdir(parents=True, exist_ok=True)
-    return Path(d)
-
-
-def ensure_built(force: bool = False) -> Path:
-    """Compile libkftdata.so if missing/stale; returns its path. Compiles
-    to a per-pid temp name and publishes with os.replace so concurrent
-    processes sharing the cache never dlopen a half-written .so."""
-    out = _cache_dir() / "libkftdata.so"
-    if not force and out.exists() and out.stat().st_mtime >= _SRC.stat().st_mtime:
+def ensure_built() -> Path:
+    """Compile libkftdata.so under the checkout's ignored ``build/``
+    directory, named by the source's content hash — a library built from
+    another checkout or an older source is never loaded. Compiles to a
+    per-pid temp name and publishes with os.replace so concurrent
+    processes never dlopen a half-written .so."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    out = _BUILD_DIR / f"libkftdata-{digest}.so"
+    if out.exists():
         return out
+    _BUILD_DIR.mkdir(parents=True, exist_ok=True)
     tmp = out.with_suffix(f".so.tmp-{os.getpid()}")
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",

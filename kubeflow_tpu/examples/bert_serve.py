@@ -56,27 +56,13 @@ def main(argv: list[str] | None = None) -> int:
         cfg = bert_base()
     # else: config comes from the model dir's config.json
 
-    # Compiled Pallas kernels need a TPU; on CPU fall back to the XLA
-    # reference attention (or interpret mode if explicitly asked).
-    if jax.default_backend() == "cpu" or args.interpret:
-        import dataclasses
-        import json
-        import os
+    # Compiled Pallas kernels need a TPU. Nothing is swapped from the
+    # backend: without one, ask for interpret mode explicitly.
+    overrides = {"interpret_kernels": True} if args.interpret else {}
 
-        if cfg is None:
-            from kubeflow_tpu.models.convert import bert_config_from_hf
-
-            cfg_file = os.path.join(args.model_dir, "config.json")
-            if os.path.isfile(cfg_file):
-                cfg = bert_config_from_hf(json.loads(open(cfg_file).read()))
-        if cfg is not None:
-            cfg = dataclasses.replace(
-                cfg,
-                attn_impl=cfg.attn_impl if args.interpret else "reference",
-                interpret_kernels=args.interpret,
-            )
-
-    model = BertRuntimeModel(args.name, args.model_dir, config=cfg)
+    model = BertRuntimeModel(
+        args.name, args.model_dir, config=cfg, **overrides
+    )
     model.load()  # fail-closed: a corrupt --model-dir dies HERE, not mid-request
 
     server = ModelServer(http_port=args.port)

@@ -66,7 +66,8 @@ def main(argv: list[str] | None = None) -> int:
     print(
         f"process {cfg.process_id}/{cfg.num_processes}: "
         f"{jax.local_device_count()} local / {jax.device_count()} global "
-        f"{jax.default_backend()} devices",
+        f"{jax.default_backend()} devices "
+        f"(device_kind={jax.devices()[0].device_kind})",
         flush=True,
     )
 

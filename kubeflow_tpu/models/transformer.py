@@ -31,9 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from kubeflow_tpu.core.collectives import shard_map
-
-from kubeflow_tpu.core.mesh import Axis, current_mesh
+from kubeflow_tpu.core.mesh import Axis
 from kubeflow_tpu.ops.flash_attention import flash_attention, reference_attention
 from kubeflow_tpu.ops.paged_attention import (
     dequantize_kv,
@@ -142,7 +140,7 @@ class TransformerConfig:
 
 def _act_constraint(x: jax.Array, *, seq_dim: int = 1) -> jax.Array:
     """(batch, seq, d) activations: batch over data+fsdp, seq over seq."""
-    mesh = current_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or Axis.DATA not in mesh.axis_names:
         return x
     spec = [None] * x.ndim
@@ -454,7 +452,7 @@ class Attention(nn.Module):
 
 def dispatch_attention(q, k, v, cfg: TransformerConfig, *, segment_ids=None):
     """Route to the configured attention strategy. q/k/v: (B, H, S, D)."""
-    mesh = current_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     kw = dict(
         causal=cfg.causal,
         block_q=cfg.attn_block_q,
@@ -516,7 +514,7 @@ def dispatch_attention(q, k, v, cfg: TransformerConfig, *, segment_ids=None):
             "attn_impl='flash' cannot shard the seq axis; use 'ring' or "
             "'ulysses' for sequence parallelism"
         )
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, spec, seg_spec),

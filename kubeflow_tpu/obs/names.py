@@ -60,6 +60,16 @@ TRAIN_DEVICE_STEP_MS = "kubeflow_tpu_train_device_step_ms"
 TRAIN_COMPILE_MS = "kubeflow_tpu_train_compile_ms"
 TRAIN_STEPS_PER_SEC = "kubeflow_tpu_train_steps_per_sec"
 
+# -- XLA programs (core/compcache.py) ----------------------------------- #
+
+#: counter — programs this process built: compiled, or loaded from the
+#: persistent compilation cache
+XLA_PROGRAMS_TOTAL = "kubeflow_tpu_xla_programs_total"
+#: counter — wall seconds spent building them (cache loads included)
+XLA_COMPILE_SECONDS_TOTAL = "kubeflow_tpu_xla_compile_seconds_total"
+#: counter — of those programs, how many the persistent cache supplied
+XLA_CACHE_HITS_TOTAL = "kubeflow_tpu_xla_cache_hits_total"
+
 # -- inference gateway (gateway/) --------------------------------------- #
 
 #: counter{service,code} — requests answered at the edge, by HTTP status

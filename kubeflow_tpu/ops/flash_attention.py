@@ -33,9 +33,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # renamed from TPUCompilerParams
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 NEG_INF = -1e30  # large-but-finite: keeps exp() well-defined on fully-masked rows
 
 DEFAULT_BLOCK_Q = 128

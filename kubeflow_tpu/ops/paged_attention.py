@@ -56,9 +56,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # jax < 0.5 spelling
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 NEG_INF = -1e30  # matches the gather path's masked-score fill
 
 
@@ -99,8 +96,8 @@ def _paged_attn_kernel(
     q_ref,      # (1, 1, G*S, D) — queries, GQA group folded into the span axis
     k_ref,      # (1, P, D) — the page picked by the index map
     v_ref,      # (1, P, D)
-    ks_ref,     # (1, P) f32 or None
-    vs_ref,     # (1, P) f32 or None
+    ks_ref,     # (1, 1, 1, P) f32 or None
+    vs_ref,     # (1, 1, 1, P) f32 or None
     o_ref,      # (1, 1, G*S, D)
     # VMEM scratch
     acc_ref,    # (G*S, D) f32
@@ -141,8 +138,8 @@ def _paged_attn_kernel(
         k = k_ref[0].astype(jnp.float32)  # (P, D)
         v = v_ref[0].astype(jnp.float32)
         if ks_ref is not None:
-            k = k * ks_ref[0][:, None]
-            v = v * vs_ref[0][:, None]
+            k = k * ks_ref[0, 0].T
+            v = v * vs_ref[0, 0].T
         if scale is None:
             mult = 1.0 / jnp.sqrt(jnp.float32(d))  # gather-path spelling
         else:
@@ -258,11 +255,20 @@ def paged_attention(
     ]
     operands = [qg, k_pool, v_pool]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, page_size), lambda b, h, i, tbl, p0: (h, tbl[b, i])),
-            pl.BlockSpec((1, page_size), lambda b, h, i, tbl, p0: (h, tbl[b, i])),
+        # a (1, page) block over the (kv_heads, pool_tokens) scale array
+        # is off Mosaic's (8, 128) tiling; viewed as (kv_heads, pages, 1,
+        # page) the block's last two dims equal the array's. The pool
+        # layout is untouched; XLA makes the view a relayout of the two
+        # scale arrays (4 bytes per token per head) on each call.
+        scale_spec = pl.BlockSpec(
+            (1, 1, 1, page_size),
+            lambda b, h, i, tbl, p0: (h, tbl[b, i], 0, 0),
+        )
+        in_specs += [scale_spec, scale_spec]
+        operands += [
+            s.reshape(Hkv, T // page_size, 1, page_size)
+            for s in (k_scale, v_scale)
         ]
-        operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

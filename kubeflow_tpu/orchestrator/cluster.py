@@ -16,7 +16,7 @@ import threading
 import time
 
 from kubeflow_tpu.obs import names, prom
-from kubeflow_tpu.orchestrator.envwire import WiringConfig
+from kubeflow_tpu.orchestrator.envwire import WiringConfig, check_gang_fits
 from kubeflow_tpu.orchestrator.gang import GangScheduler
 from kubeflow_tpu.orchestrator.launcher import ProcessLauncher
 from kubeflow_tpu.orchestrator.reconciler import JobController, JobObject
@@ -185,6 +185,7 @@ class LocalCluster:
     # -- job API (what the SDK client calls) --------------------------- #
 
     def submit(self, spec: JobSpec) -> str:
+        check_gang_fits(self.wiring, spec.total_replicas)
         with self._submit_lock:
             spec = self.admission.admit(spec)
             self.jobs.create(spec.uid, JobObject(spec=spec))
@@ -240,6 +241,7 @@ class LocalCluster:
     def scale(self, uid: str, replicas: int) -> int:
         """Resize an elastic job's scalable group (HPA analog); the gang
         re-forms at the new size and resumes from checkpoint."""
+        check_gang_fits(self.wiring, replicas)
         applied = self.controller.scale(uid, replicas)
         self._wake.set()
         return applied

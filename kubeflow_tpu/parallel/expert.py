@@ -18,13 +18,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from kubeflow_tpu.core.mesh import Axis, current_mesh
+from kubeflow_tpu.core.mesh import Axis
 
 
 def _constrain(x: jax.Array, spec: P) -> jax.Array:
     """Sharding constraint that no-ops outside a mesh context (pure
     single-device use keeps working)."""
-    mesh = current_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or Axis.EXPERT not in mesh.axis_names:
         return x
     return jax.lax.with_sharding_constraint(x, spec)

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from kubeflow_tpu.core.collectives import axis_size, shard_map
-
 from kubeflow_tpu.core.mesh import Axis
 
 
@@ -41,7 +39,7 @@ def spmd_pipeline_local(
     every rank — the last stage's results are broadcast back with a psum
     over one-hot masking).
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     s = lax.axis_index(axis_name)
     n_micro = microbatches.shape[0]
     mb_shape = microbatches.shape[1:]
@@ -118,7 +116,7 @@ def pipeline_apply(
             stage_fn, squeezed, xm_local, axis_name=axis_name
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(param_specs, x_spec),
@@ -223,7 +221,7 @@ def pipeline_value_and_grad(
 
     def local(params_stage, xm_local):
         params = jax.tree_util.tree_map(lambda p: p[0], params_stage)
-        n = axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         s = lax.axis_index(axis_name)
         m = xm_local.shape[0]
         mb_shape = xm_local.shape[1:]
@@ -303,7 +301,7 @@ def pipeline_value_and_grad(
         grads = jax.tree_util.tree_map(lambda g: g[None], grads)
         return loss, grads
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(param_specs, x_spec),

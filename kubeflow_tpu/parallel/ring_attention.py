@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from kubeflow_tpu.core.collectives import axis_size, shard_map
-
 from kubeflow_tpu.core.mesh import Axis
 from kubeflow_tpu.ops.flash_attention import (
     NEG_INF,
@@ -47,7 +45,7 @@ def global_seg_operand(mesh, seg_spec, segment_ids, q):
 
 def _rotate(x, axis_name: str):
     """One ring hop: shard i → shard i+1."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     return lax.ppermute(x, axis_name, [(i, (i + 1) % n) for i in range(n)])
 
 
@@ -105,7 +103,7 @@ def _ring_fwd_pass(
     q, k, v, q_seg, kv_seg, axis_name, causal, scale, block_q, block_k,
     interpret,
 ):
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     B, H, S, D = q.shape
     o = jnp.zeros_like(q)
@@ -162,7 +160,7 @@ def _ring_local_bwd(axis_name, causal, scale, blocks, interpret, res, do):
     """
     block_q, block_k = blocks
     q, k, v, q_seg, kv_seg, o, lse = res
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
 
     dq = jnp.zeros_like(q, dtype=jnp.float32)
@@ -273,7 +271,7 @@ def ring_attention(
             interpret=interpret,
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh, in_specs=(spec, spec, spec, seg_spec),
         out_specs=spec, check_vma=False,
     )

@@ -14,8 +14,6 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from kubeflow_tpu.core.collectives import axis_size, shard_map
-
 from kubeflow_tpu.core.mesh import Axis
 from kubeflow_tpu.ops.flash_attention import flash_attention
 from kubeflow_tpu.parallel.ring_attention import global_seg_operand
@@ -45,7 +43,7 @@ def ulysses_attention_local(
             segment_ids, axis_name, axis=1, tiled=True
         )
         seg_kw = {"q_segment_ids": full_seg, "kv_segment_ids": full_seg}
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return flash_attention(
             q, k, v, causal=causal, scale=scale, **seg_kw,
@@ -94,7 +92,7 @@ def ulysses_attention(
             interpret=interpret,
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh, in_specs=(spec, spec, spec, seg_spec),
         out_specs=spec, check_vma=False,
     )

@@ -29,6 +29,7 @@ from typing import Any
 
 from aiohttp import web
 
+from kubeflow_tpu.core import compcache
 from kubeflow_tpu.obs import names, prom
 from kubeflow_tpu.obs import trace as _trace
 from kubeflow_tpu.obs.trace import (
@@ -1112,6 +1113,13 @@ class ModelServer:
         # ObsServer registry scrape
         lines.extend(_trace.TTFT_MS.expose())
         lines.extend(_trace.TPOT_MS.expose())
+        # what start-up cost this replica: programs built, seconds spent,
+        # persistent-cache hits (core/compcache.py)
+        for counter in (
+            compcache.XLA_PROGRAMS, compcache.XLA_COMPILE_SECONDS,
+            compcache.XLA_CACHE_HITS,
+        ):
+            lines.extend(counter.expose())
         return web.Response(text="\n".join(lines) + "\n")
 
     # -- runtime ------------------------------------------------------------

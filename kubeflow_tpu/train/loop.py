@@ -23,9 +23,7 @@ import numpy as np
 from flax.training import train_state
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from kubeflow_tpu.core.mesh import (
-    Axis, MeshSpec, build_mesh, mesh_context, per_device_batch,
-)
+from kubeflow_tpu.core.mesh import Axis, MeshSpec, build_mesh, per_device_batch
 from kubeflow_tpu.train.checkpoint import CheckpointConfig, Checkpointer
 from kubeflow_tpu.train.metrics import MetricWriter
 
@@ -187,7 +185,7 @@ class Trainer:
 
         # set_mesh: models read the context mesh for activation sharding
         # constraints and shard_map attention (ring/ulysses/flash).
-        with mesh_context(self.mesh):
+        with jax.set_mesh(self.mesh):
             if self.param_spec_fn is None:
                 out_shardings = self.repl
             else:
@@ -471,7 +469,7 @@ class Trainer:
         t_last = time.perf_counter()
         last_logged = start_step
         try:
-            with mesh_context(self.mesh):
+            with jax.set_mesh(self.mesh):
                 return self._fit_loop(
                     state, step_fn, it, ckpt, writer, hooks, history,
                     start_step, t_last, last_logged, hb,

@@ -121,12 +121,11 @@ def test_build_resolves_overlay(capsys):
 
 
 def test_doctor_reports_backend(capsys):
-    rc = main(["doctor", "--timeout", "120"])
+    rc = main(["doctor"])
     report = json.loads(capsys.readouterr().out)
-    assert "backend" in report
-    assert rc in (0, 1)
-    if rc == 0:
-        assert report["devices"] >= 1
+    assert rc == 0
+    assert report["backend"] == "cpu"  # the suite's platform, in-process
+    assert report["devices"] == 8 and report["device_kind"]
 
 
 def test_serve_subprocess_answers_rest(tmp_path):

@@ -10,7 +10,7 @@ from kubeflow_tpu.core.mesh import Axis, MeshSpec, build_mesh
 
 
 def _shmap(mesh, fn, in_specs, out_specs):
-    return coll.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
 def test_grad_allreduce_is_mean(devices8):

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from kubeflow_tpu.core.mesh import Axis, MeshSpec, build_mesh, mesh_context
+from kubeflow_tpu.core.mesh import Axis, MeshSpec, build_mesh
 from kubeflow_tpu.parallel.expert import (
     MoEConfig,
     moe_ffn,
@@ -67,7 +67,7 @@ def test_moe_sharded_on_expert_axis(devices8):
     x = jnp.asarray(rng.randn(64, d), jnp.float32)
 
     mesh = build_mesh(MeshSpec(expert=8))
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         out_sharded, _, _ = jax.jit(
             lambda *a: moe_ffn(*a, cfg)
         )(x, router, up, down)

@@ -1234,7 +1234,7 @@ def test_compilation_cache_speeds_second_cold_start(tmp_path):
 
     cache_dir = str(tmp_path / "xla-cache")
     prog = (
-        "import time, jax; jax.config.update('jax_platforms','cpu');\n"
+        "import time\n"
         "from kubeflow_tpu.models.bert import bert_tiny\n"
         "from kubeflow_tpu.serve.model import BucketSpec\n"
         "from kubeflow_tpu.serve.runtimes import BertRuntimeModel\n"
@@ -1246,12 +1246,11 @@ def test_compilation_cache_speeds_second_cold_start(tmp_path):
         "s = ModelServer([m]); m.warmup()\n"
         "print('COLD', time.perf_counter() - t0)\n"
     )
+    # the one way in: JAX's own variable (compcache sets no dir in code
+    # when it is set)
     env = dict(
-        os.environ, KFT_COMPILATION_CACHE_DIR=cache_dir, JAX_PLATFORMS="cpu"
+        os.environ, JAX_COMPILATION_CACHE_DIR=cache_dir, JAX_PLATFORMS="cpu"
     )
-    # ambient settings on a developer machine must not defeat the test's
-    # own cache dir (compcache keeps a pre-set JAX dir verbatim)
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.pop("KFT_NO_COMPILATION_CACHE", None)
 
     def run():
@@ -1278,9 +1277,41 @@ def test_compilation_cache_speeds_second_cold_start(tmp_path):
     assert t_second < t_first * 2.0, (t_first, t_second)
 
 
-def test_compilation_cache_opt_out(tmp_path, monkeypatch):
+def test_compilation_cache_opt_out(monkeypatch):
+    import jax
+
     from kubeflow_tpu.core.compcache import enable_compilation_cache
 
+    before = jax.config.jax_compilation_cache_dir
     monkeypatch.setenv("KFT_NO_COMPILATION_CACHE", "1")
-    assert enable_compilation_cache(str(tmp_path / "x")) is None
-    assert not (tmp_path / "x").exists()
+    assert enable_compilation_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compilation_cache_default_is_inside_the_checkout(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR unset → the fixed path inside the
+    checkout, resolved from the package's location (the test above steers
+    through the variable; there is no other knob)."""
+    import os
+    import subprocess
+    import sys
+
+    from kubeflow_tpu.core.compcache import DEFAULT_CACHE_DIR
+
+    env = dict(
+        os.environ, JAX_PLATFORMS="cpu",
+        PYTHONPATH=str(DEFAULT_CACHE_DIR.parent),
+    )
+    env.pop("KFT_NO_COMPILATION_CACHE", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "from kubeflow_tpu.core.compcache import enable_compilation_cache"
+         " as e; import jax; print(e()); print(e());"
+         " print(jax.config.jax_compilation_cache_dir)"],
+        capture_output=True, text=True, env=env, timeout=120,
+        cwd=str(tmp_path),  # not the checkout: the cwd must not matter
+    )
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split() == [str(DEFAULT_CACHE_DIR)] * 3
+    assert DEFAULT_CACHE_DIR.name == ".jax_cache"

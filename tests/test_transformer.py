@@ -7,7 +7,7 @@ import numpy as np
 import optax
 import pytest
 
-from kubeflow_tpu.core.mesh import Axis, MeshSpec, build_mesh, mesh_context
+from kubeflow_tpu.core.mesh import Axis, MeshSpec, build_mesh
 from kubeflow_tpu.data.synthetic import TokenLMDataset, local_shard_iterator
 from kubeflow_tpu.models.transformer import (
     TransformerConfig,
@@ -73,7 +73,7 @@ def test_attention_impls_match_reference(ref_setup, tokens, devices8, impl, mesh
     model = TransformerLM(cfg)
     if mesh_kw:
         mesh = build_mesh(MeshSpec(**mesh_kw))
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             logits = jax.jit(
                 lambda p, t: model.apply({"params": p}, t)
             )(params, tokens)
@@ -89,7 +89,7 @@ def test_flash_rejects_seq_sharding(ref_setup, tokens, devices8):
     params, _ = ref_setup
     model = TransformerLM(_cfg(attn_impl="flash"))
     mesh = build_mesh(MeshSpec(seq=8))
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         with pytest.raises(ValueError, match="ring|ulysses"):
             jax.jit(lambda p, t: model.apply({"params": p}, t))(params, tokens)
 
