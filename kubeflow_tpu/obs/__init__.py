@@ -14,7 +14,7 @@ from kubeflow_tpu.obs.heartbeat import (
     read_heartbeat,
 )
 from kubeflow_tpu.obs.jsonlog import JsonFormatter, configure_json_logging
-from kubeflow_tpu.obs.profiler import ObsServer, capture_trace, trace_step
+from kubeflow_tpu.obs.profiler import ObsServer, capture_trace
 from kubeflow_tpu.obs.prom import (
     REGISTRY,
     Counter,
@@ -39,5 +39,4 @@ __all__ = [
     "heartbeat_path_from_env",
     "is_stale",
     "read_heartbeat",
-    "trace_step",
 ]

@@ -47,18 +47,6 @@ def capture_trace(logdir: str | Path):
         jax.profiler.stop_trace()
 
 
-def trace_step(fn: Callable[[], Any], logdir: str | Path, name: str = "step") -> Any:
-    """Profile one call (e.g. a single jitted train step) under a named
-    annotation; returns the call's result."""
-    import jax
-
-    with capture_trace(logdir):
-        with jax.profiler.TraceAnnotation(name):
-            out = fn()
-        jax.block_until_ready(out)
-    return out
-
-
 class ObsServer(ThreadedAiohttpServer):
     """Observability sidecar-in-process. Thread-hosted aiohttp app."""
 

@@ -46,6 +46,7 @@ epoch, and those are exactly the points that re-upload.
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
@@ -94,6 +95,23 @@ from kubeflow_tpu.serve.kv_tier import HostKVTier
 #: idle park bound — every waker (submit, stream-cancel, stop) sets
 #: ``_work``, so this timeout is only a belt-and-braces sweep, not a poll
 _IDLE_PARK_S = 5.0
+
+#: what the scheduler thread is doing, as ``LMEngine._phase`` names it: on
+#: that thread the phases never overlap except by nesting (a single-piece
+#: admission prefills inside ``admit``), each counts its SELF time, and with
+#: the small unnamed rest they add up to ``stats["sched_loop_s"]``
+_SCHED_PHASES = (
+    "admit",             # retire cancelled/expired rows, queue poll, pages,
+                         # mirrors, prefix store, first-token push
+    "prefill_dispatch",  # build one prefill piece and enqueue its program
+    "prefill_wait",      # blocked until the device finished that piece —
+                         # and every program queued before it
+    "carry_upload",      # the epoch's H2D of the per-row arrays
+    "chunk_dispatch",    # rng split, table widening, enqueue a decode chunk
+    "drain_wait",        # blocked on a chunk's results (D2H)
+    "drain_emit",        # credit tokens, push, spans, retirements
+    "park",              # nothing to do
+)
 
 #: disaggregated-serving wire metrics: per-request KV span bytes by leg
 #: (``export`` = prefill replica serving :prefill, ``import`` = decode
@@ -594,6 +612,9 @@ class LMEngine:
             # cross-replica prefix-KV transfer (peer pull endpoints)
             "prefix_imported": 0, "prefix_exported": 0,
             "prefill_pieces": 0, "idle_wakes": 0,
+            # what the prefill programs computed: prompt tokens, and the
+            # token slots they were padded to (pieces x piece length)
+            "prefill_tokens": 0, "prefill_padded_tokens": 0,
             # speculative decoding: drafts proposed/accepted (the tokens-
             # per-forward multiplier — kft_engine_spec_*_total)
             "spec_proposed": 0, "spec_accepted": 0,
@@ -611,7 +632,14 @@ class LMEngine:
             "kv_ship_bytes": 0, "kv_ship_fallbacks": 0,
             # host-RAM KV tier: sessions swapped out on finish / back in
             "kv_offload_out": 0, "kv_offload_in": 0,
+            # the scheduler thread's wall time, once per loop iteration, and
+            # under it each phase's self seconds and entries (_phase)
+            "sched_loop_s": 0.0,
+            **{f"sched_{name}_s": 0.0 for name in _SCHED_PHASES},
+            **{f"sched_{name}_n": 0 for name in _SCHED_PHASES},
         }
+        #: open phases, innermost last: seconds spent in phases nested in each
+        self._phase_nested: list[float] = []
         # pipelined-decode state: the device-resident carry of per-row
         # scheduling arrays, its dirtiness (host edits pending merge), and
         # the paged horizon bookkeeping for speculative chunks. ``overlap``
@@ -2013,6 +2041,8 @@ class LMEngine:
                 .set_attr("prefix_hit", base > 0)
                 .set_attr("prefix_tokens_reused", base)
                 .set_attr("pieces", n_pieces)
+                .set_attr("prompt_tokens", len(req.ids))
+                .set_attr("padded_tokens", n_pieces * C)
             )
         self._prefilling[row] = {
             "req": req, "rest": rest, "base": base, "C": C,
@@ -2116,55 +2146,59 @@ class LMEngine:
     def _advance_prefill(self, row: int) -> None:
         """Run ONE prefill piece for a prefilling row; the final piece
         yields the first token and activates (or finishes) the request."""
-        st = self._prefilling[row]
-        req, rest, base, C = st["req"], st["rest"], st["base"], st["C"]
-        i = st["piece"]
-        final = i == st["n_pieces"] - 1
-        piece_ids = rest[i * C: (i + 1) * C]
-        piece = np.full((1, C), self.pad_id, np.int32)
-        piece[0, : len(piece_ids)] = piece_ids
-        self._rng, sub = jax.random.split(self._rng)
-        # the sampled token's absolute position: one past this piece's
-        # last prompt token (only the FINAL piece's sample is kept, where
-        # this equals len(req.ids) — the first generated position)
-        seed = -1 if req.seed is None else req.seed
-        pos = base + i * C + len(piece_ids)
-        if self.paged:
-            pages_w = self._pages_w(base + i * C + C)
-            self.cache, tok, valid, qerr = self._suffix_prefill(
-                self.params,
-                self.cache,
-                jnp.asarray(piece),
-                jnp.asarray([len(piece_ids)], np.int32),
-                base + i * C,
-                jnp.asarray(self.pager.table[row : row + 1, :pages_w].copy()),
-                jnp.float32(req.temperature),
-                seed,
-                pos,
-                sub,
-                seeded=req.seed is not None,
-            )
-        else:
-            self.cache, tok, valid, qerr = self._suffix_prefill(
-                self.params,
-                self.cache,
-                jnp.asarray(piece),
-                jnp.asarray([len(piece_ids)], np.int32),
-                base + i * C,
-                row,
-                jnp.float32(req.temperature),
-                seed,
-                pos,
-                sub,
-                seeded=req.seed is not None,
-            )
+        with self._phase("prefill_dispatch"):
+            st = self._prefilling[row]
+            req, rest, base, C = st["req"], st["rest"], st["base"], st["C"]
+            i = st["piece"]
+            final = i == st["n_pieces"] - 1
+            piece_ids = rest[i * C: (i + 1) * C]
+            piece = np.full((1, C), self.pad_id, np.int32)
+            piece[0, : len(piece_ids)] = piece_ids
+            self._rng, sub = jax.random.split(self._rng)
+            # the sampled token's absolute position: one past this piece's
+            # last prompt token (only the FINAL piece's sample is kept, where
+            # this equals len(req.ids) — the first generated position)
+            seed = -1 if req.seed is None else req.seed
+            pos = base + i * C + len(piece_ids)
+            if self.paged:
+                pages_w = self._pages_w(base + i * C + C)
+                self.cache, tok, valid, qerr = self._suffix_prefill(
+                    self.params,
+                    self.cache,
+                    jnp.asarray(piece),
+                    jnp.asarray([len(piece_ids)], np.int32),
+                    base + i * C,
+                    jnp.asarray(self.pager.table[row : row + 1, :pages_w].copy()),
+                    jnp.float32(req.temperature),
+                    seed,
+                    pos,
+                    sub,
+                    seeded=req.seed is not None,
+                )
+            else:
+                self.cache, tok, valid, qerr = self._suffix_prefill(
+                    self.params,
+                    self.cache,
+                    jnp.asarray(piece),
+                    jnp.asarray([len(piece_ids)], np.int32),
+                    base + i * C,
+                    row,
+                    jnp.float32(req.temperature),
+                    seed,
+                    pos,
+                    sub,
+                    seeded=req.seed is not None,
+                )
         if self.kv_quant == "int8":
             # same inline sync budget as the final piece's int(tok) below:
             # prefill is synchronous by design (one row, host-driven)
-            e, d = float(qerr[0]), float(qerr[1])
+            with self._phase("prefill_wait"):
+                e, d = float(qerr[0]), float(qerr[1])
             if d > 0:
                 self._ewma("kv_quant_error", e / d)
         self.stats["prefill_pieces"] += 1
+        self.stats["prefill_tokens"] += len(piece_ids)
+        self.stats["prefill_padded_tokens"] += C
         st["piece"] = i + 1
         if not final:
             return  # tok is a throwaway sample from a non-final position
@@ -2174,7 +2208,8 @@ class LMEngine:
             req.pspan = None
         if self._prefix_cache is not None:
             self._store_prefix(req.ids, row)
-        tok = int(tok)
+        with self._phase("prefill_wait"):
+            tok, valid = int(tok), bool(valid)
         if req.want_kv_span:
             # disaggregated prefill: extract the finished span (ceil-16
             # window) and retire the row WITHOUT activating — a prefill
@@ -2186,18 +2221,18 @@ class LMEngine:
             req.kv_span_meta = {
                 "real_len": len(req.ids),
                 "first_tok": tok,
-                "valid": bool(valid),
+                "valid": valid,
             }
             self.stats["kv_spans_exported"] += 1
             self._finish(row)
             return
-        if bool(valid):
+        if valid:
             req.push([tok])
             if self.spec_k:
                 self.hist_host[row, len(req.ids)] = tok
         self.last_tok[row] = tok
         # one-token completions (eos first, or budget 1) finish here
-        finished = (not bool(valid)) or req.max_new_tokens <= 1
+        finished = (not valid) or req.max_new_tokens <= 1
         if finished:
             self._finish(row)
         else:
@@ -2310,6 +2345,30 @@ class LMEngine:
         self._offload_q.put(done)
         return done.wait(timeout_s)
 
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """One scheduler phase, two outputs at the same boundary: a host
+        span ``engine.<name>`` on the profiler's clock (beside the device's
+        events in a ``POST /profile`` capture; free while no profile runs),
+        and ``stats["sched_<name>_s"]`` / ``_n`` — the phase's self seconds
+        (its own minus those of phases nested in it) and entries, exported
+        by ``/metrics`` like every ``stats`` key. Scheduler thread only. The
+        annotation takes no keyword arguments: they would be formatted into
+        the event's name and split one phase into many."""
+        nested = self._phase_nested
+        nested.append(0.0)
+        with jax.profiler.TraceAnnotation("engine." + name):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                took = time.perf_counter() - t0
+                inner = nested.pop()
+                if nested:
+                    nested[-1] += took
+                self.stats[f"sched_{name}_s"] += took - inner
+                self.stats[f"sched_{name}_n"] += 1
+
     def _loop(self) -> None:
         try:
             self._loop_inner()
@@ -2339,65 +2398,75 @@ class LMEngine:
     def _loop_inner(self) -> None:
         pending: _PendingChunk | None = None
         while not self._stop.is_set():
-            # watchdog heartbeat: stale while work exists ⇒ the loop is
-            # wedged inside a device call (or a chaos hook)
-            self._beat = time.monotonic()
+            t0 = time.perf_counter()
+            pending = self._loop_once(pending)
+            self.stats["sched_loop_s"] += time.perf_counter() - t0
+
+    def _loop_once(self, pending: _PendingChunk | None) -> _PendingChunk | None:
+        """One scheduler iteration; takes and returns the chunk in flight."""
+        # watchdog heartbeat: stale while work exists ⇒ the loop is
+        # wedged inside a device call (or a chaos hook)
+        self._beat = time.monotonic()
+        with self._phase("admit"):
             self._admit_all()
             self._advance_prefills()  # one piece per prefilling row
-            if not self.active.any():
-                if pending is not None:
-                    # burst tail: the speculative chunk outlived its rows
-                    # (host mirrors may also lag it by one chunk) — drain
-                    # it, then re-evaluate
-                    self._drain_chunk(pending)
-                    pending = None
-                    continue
-                if self._prefilling:
-                    continue  # keep advancing pieces, don't park
-                # idle: park until submit/stream-cancel/stop sets _work —
-                # every waker does, so the long timeout is only a
-                # belt-and-braces sweep, never a 20 Hz poll. Clearing after
-                # the wait cannot lose work: _admit_all re-polls the queue
-                # at the top of the next iteration.
-                self._last_dispatch = None
-                self.stats["idle_wakes"] += 1
-                self._work.wait(_IDLE_PARK_S)
-                self._work.clear()
-                continue
-            if self.pipeline_depth == 0:
-                # inline parity/debug path: per-chunk H2D upload and an
-                # immediate D2H drain — the pre-pipeline hot loop, kept
-                # selectable so pipelined parity is provable seed-for-seed
-                self._upload_carry()
-                self._drain_chunk(self._dispatch_chunk())
-                continue
-            if self._carry_dirty:
-                if pending is not None:
-                    # merge point: drain the in-flight chunk first so the
-                    # host mirrors are current (retired rows masked out),
-                    # then loop — the drain may free rows/pages admission
-                    # wants before the single merged re-upload
-                    self._drain_chunk(pending)
-                    pending = None
-                    continue
-                self._upload_carry()
-            if pending is not None and self._all_may_retire():
-                # end-of-burst: every active row can exhaust its budget
-                # inside the in-flight chunk, so a speculative dispatch
-                # would likely decode only dead rows — drain first instead
-                # and let the retirements land (EOS tails still cost at
-                # most one dead chunk; budgets are host-knowable, EOS
-                # isn't)
-                self._drain_chunk(pending)
-                pending = None
-                continue
-            # one-chunk-ahead: dispatch N+1 on the device carry BEFORE
-            # draining N, so N's token D2H + host postprocess overlap
-            # N+1's device compute
-            nxt = self._dispatch_chunk()
+        if not self.active.any():
             if pending is not None:
+                # burst tail: the speculative chunk outlived its rows
+                # (host mirrors may also lag it by one chunk) — drain
+                # it, then re-evaluate
                 self._drain_chunk(pending)
-            pending = nxt
+                return None
+            if self._prefilling:
+                return None  # keep advancing pieces, don't park
+            # idle: park until submit/stream-cancel/stop sets _work —
+            # every waker does, so the long timeout is only a
+            # belt-and-braces sweep, never a 20 Hz poll. Clearing after
+            # the wait cannot lose work: _admit_all re-polls the queue
+            # at the top of the next iteration.
+            self._last_dispatch = None
+            self.stats["idle_wakes"] += 1
+            with self._phase("park"):
+                self._work.wait(_IDLE_PARK_S)
+            self._work.clear()
+            return None
+        if self.pipeline_depth == 0:
+            # inline parity/debug path: per-chunk H2D upload and an
+            # immediate D2H drain — the pre-pipeline hot loop, kept
+            # selectable so pipelined parity is provable seed-for-seed
+            with self._phase("carry_upload"):
+                self._upload_carry()
+            with self._phase("chunk_dispatch"):
+                nxt = self._dispatch_chunk()
+            self._drain_chunk(nxt)
+            return None
+        if self._carry_dirty:
+            if pending is not None:
+                # merge point: drain the in-flight chunk first so the
+                # host mirrors are current (retired rows masked out),
+                # then loop — the drain may free rows/pages admission
+                # wants before the single merged re-upload
+                self._drain_chunk(pending)
+                return None
+            with self._phase("carry_upload"):
+                self._upload_carry()
+        if pending is not None and self._all_may_retire():
+            # end-of-burst: every active row can exhaust its budget
+            # inside the in-flight chunk, so a speculative dispatch
+            # would likely decode only dead rows — drain first instead
+            # and let the retirements land (EOS tails still cost at
+            # most one dead chunk; budgets are host-knowable, EOS
+            # isn't)
+            self._drain_chunk(pending)
+            return None
+        # one-chunk-ahead: dispatch N+1 on the device carry BEFORE
+        # draining N, so N's token D2H + host postprocess overlap
+        # N+1's device compute
+        with self._phase("chunk_dispatch"):
+            nxt = self._dispatch_chunk()
+        if pending is not None:
+            self._drain_chunk(pending)
+        return nxt
 
     # -- pipelined decode: carry upload / dispatch / drain ------------------- #
 
@@ -2566,92 +2635,102 @@ class LMEngine:
         retired while the chunk was speculatively in flight are masked
         out: their tokens belong to a request that no longer owns the
         row."""
-        t0 = time.perf_counter()
-        # decode boundary: generated tokens must reach the host to stream
-        # to clients — this D2H is the product, not a stall; it runs on the
-        # engine scheduler thread (never a request thread) and, pipelined,
-        # overlaps the NEXT chunk's device compute
-        toks, valid, act_in, last, genc, act_out = (
-            np.asarray(x)  # kft: noqa[jax-sync] — sanctioned decode-boundary D2H on the scheduler thread; overlapped by the in-flight next chunk
-            for x in (p.toks, p.valid, p.active_in, p.last_tok,
-                      p.gen_count, p.active_out)
-        )
-        if self.spec_k:
-            eos_pl, prop_pl, acc_pl = (
-                np.asarray(x)  # kft: noqa[jax-sync] — same sanctioned decode-boundary D2H; tiny (B, steps) planes riding the token drain
-                for x in (p.eos, p.prop, p.acc)
+        with self._phase("drain_wait"):
+            t0 = time.perf_counter()
+            # decode boundary: generated tokens must reach the host to stream
+            # to clients — this D2H is the product, not a stall; it runs on the
+            # engine scheduler thread (never a request thread) and, pipelined,
+            # overlaps the NEXT chunk's device compute
+            toks, valid, act_in, last, genc, act_out = (
+                np.asarray(x)  # kft: noqa[jax-sync] — sanctioned decode-boundary D2H on the scheduler thread; overlapped by the in-flight next chunk
+                for x in (p.toks, p.valid, p.active_in, p.last_tok,
+                          p.gen_count, p.active_out)
             )
-        self._ewma("d2h_drain_ms", (time.perf_counter() - t0) * 1e3)
-        chunk_prop = chunk_acc = 0
-        for row in range(self.max_batch):
-            req = p.slots[row]
-            if req is None or not act_in[row]:
-                continue  # free or still prefilling at dispatch: no tokens
-            if self._slots[row] is not req:
-                # retired (cancelled / re-admitted) while this chunk was in
-                # flight: mask its speculative results — mirrors for this
-                # row were rewritten by the host edit and must stand
-                continue
-            hit_eos = False
-            fresh: list[int] = []
             if self.spec_k:
-                # (steps, K+1) planes: each step's valid tokens are a
-                # PREFIX of its span (live positions are a prefix and EOS
-                # can only be the last live one) — a non-valid plane
-                # inside a step means "not emitted", only the eos flag (a
-                # LIVE EOS landed) stops the row. Walked with numpy, not
-                # a python scalar loop: B x steps x (K+1) iterations per
-                # chunk would hand back the very host time the pipeline
-                # exists to hide.
-                v, t, e = valid[row], toks[row], eos_pl[row]
-                hit_eos = bool(e.any())
-                stop_s = (
-                    int(np.argmax(e)) if hit_eos else self.chunk_steps - 1
+                eos_pl, prop_pl, acc_pl = (
+                    np.asarray(x)  # kft: noqa[jax-sync] — same sanctioned decode-boundary D2H; tiny (B, steps) planes riding the token drain
+                    for x in (p.eos, p.prop, p.acc)
                 )
-                flat = t[: stop_s + 1][v[: stop_s + 1]]   # prefix-ordered
-                remaining = req.max_new_tokens - len(req.tokens)
-                fresh = [int(x) for x in flat[:remaining]]
-                row_prop = int(prop_pl[row].sum())
-                row_acc = int(acc_pl[row].sum())
-                self.stats["spec_proposed"] += row_prop
-                self.stats["spec_accepted"] += row_acc
-                chunk_prop += row_prop
-                chunk_acc += row_acc
-                # history mirror: drained tokens land at their token
-                # positions so the next epoch re-upload is exact
-                start = int(self.real_len[row]) + len(req.tokens)
-                self.hist_host[row, start : start + len(fresh)] = fresh
-            else:
-                for j in range(self.chunk_steps):
-                    if len(req.tokens) + len(fresh) >= req.max_new_tokens:
-                        break
-                    if not valid[row, j]:
-                        hit_eos = True
-                        break
-                    fresh.append(int(toks[row, j]))
-            req.push(fresh)
-            if req.espan is not None and fresh:
-                # retroactive decode.chunk span (host ints only): stamped
-                # at dispatch, reported here so the loop never holds an
-                # open span per chunk
-                attrs: dict[str, Any] = {"row": row, "tokens": len(fresh)}
+            self._ewma("d2h_drain_ms", (time.perf_counter() - t0) * 1e3)
+        with self._phase("drain_emit"):
+            chunk_prop = chunk_acc = 0
+            for row in range(self.max_batch):
+                req = p.slots[row]
+                if req is None or not act_in[row]:
+                    continue  # free or still prefilling at dispatch: no tokens
+                if self._slots[row] is not req:
+                    # retired (cancelled / re-admitted) while this chunk was in
+                    # flight: mask its speculative results — mirrors for this
+                    # row were rewritten by the host edit and must stand
+                    continue
+                hit_eos = False
+                fresh: list[int] = []
                 if self.spec_k:
-                    attrs["spec_proposed"] = row_prop
-                    attrs["spec_accepted"] = row_acc
-                TRACER.record_span(
-                    "decode.chunk", parent=req.espan,
-                    start=p.t_dispatch, end=time.monotonic(), attrs=attrs,
-                )
-            # lazy mirror refresh from the drained outputs — the only place
-            # host state learns device progress; per-row (not wholesale) so
-            # rows edited by admit/prefill keep their newer host values
-            self.last_tok[row] = last[row]
-            self.gen_count[row] = genc[row]
-            self.active[row] = bool(act_out[row])
-            if hit_eos or len(req.tokens) >= req.max_new_tokens:
-                # device-visible retirement: the carry already gates this
-                # row in-graph, so no epoch is burned
-                self._finish(row, carry_stale=False)
+                    # (steps, K+1) planes: each step's valid tokens are a
+                    # PREFIX of its span (live positions are a prefix and EOS
+                    # can only be the last live one) — a non-valid plane
+                    # inside a step means "not emitted", only the eos flag (a
+                    # LIVE EOS landed) stops the row. Walked with numpy, not
+                    # a python scalar loop: B x steps x (K+1) iterations per
+                    # chunk would hand back the very host time the pipeline
+                    # exists to hide.
+                    v, t, e = valid[row], toks[row], eos_pl[row]
+                    hit_eos = bool(e.any())
+                    stop_s = (
+                        int(np.argmax(e)) if hit_eos else self.chunk_steps - 1
+                    )
+                    flat = t[: stop_s + 1][v[: stop_s + 1]]   # prefix-ordered
+                    remaining = req.max_new_tokens - len(req.tokens)
+                    fresh = [int(x) for x in flat[:remaining]]
+                    row_prop = int(prop_pl[row].sum())
+                    row_acc = int(acc_pl[row].sum())
+                    self.stats["spec_proposed"] += row_prop
+                    self.stats["spec_accepted"] += row_acc
+                    chunk_prop += row_prop
+                    chunk_acc += row_acc
+                    # history mirror: drained tokens land at their token
+                    # positions so the next epoch re-upload is exact
+                    start = int(self.real_len[row]) + len(req.tokens)
+                    self.hist_host[row, start : start + len(fresh)] = fresh
+                else:
+                    for j in range(self.chunk_steps):
+                        if len(req.tokens) + len(fresh) >= req.max_new_tokens:
+                            break
+                        if not valid[row, j]:
+                            hit_eos = True
+                            break
+                        fresh.append(int(toks[row, j]))
+                req.push(fresh)
+                if req.espan is not None and fresh:
+                    # retroactive decode.chunk span (host ints only): stamped
+                    # at dispatch, reported here so the loop never holds an
+                    # open span per chunk
+                    attrs: dict[str, Any] = {"row": row, "tokens": len(fresh)}
+                    if self.spec_k:
+                        attrs["spec_proposed"] = row_prop
+                        attrs["spec_accepted"] = row_acc
+                    TRACER.record_span(
+                        "decode.chunk", parent=req.espan,
+                        start=p.t_dispatch, end=time.monotonic(), attrs=attrs,
+                    )
+                # lazy mirror refresh from the drained outputs — the only place
+                # host state learns device progress; per-row (not wholesale) so
+                # rows edited by admit/prefill keep their newer host values
+                self.last_tok[row] = last[row]
+                self.gen_count[row] = genc[row]
+                self.active[row] = bool(act_out[row])
+                if hit_eos or len(req.tokens) >= req.max_new_tokens:
+                    # device-visible retirement: the carry already gates this
+                    # row in-graph, so no epoch is burned
+                    self._finish(row, carry_stale=False)
+        if not self.active.any():
+            # the batch ran empty: whatever passes before the next dispatch
+            # (idling, an admission's prefill, a program load) is no decode
+            # gap. Parking resets this too, but only if the loop gets there
+            # before the next request does — and one such sample seeds the
+            # EWMA that estimate_admission sheds by (found on the chip: a
+            # 3.4 s seed from warm-up shed a fifth of the first wave)
+            self._last_dispatch = None
         if chunk_prop:
             # kft_engine_spec_acceptance: EWMA accepted/proposed ratio —
             # the live signal for whether prompt-lookup pays on this

@@ -1287,3 +1287,50 @@ def test_engine_resume_validation_errors(model_and_params):
         assert len(out) <= 1
     finally:
         eng.stop()
+
+
+# ------------------- program names the benchmark's device metrics match on
+
+
+@pytest.mark.parametrize("spec", [0, 3], ids=["plain", "spec"])
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_program_names_match_the_benchmarks_module_patterns(
+    model_and_params, paged, spec
+):
+    """``engine_decode_device_ms`` and the two ``engine_prefill_device_*``
+    metrics find the engine's programs on the trace's module line by name
+    (``jit_<function>(<fingerprint>)``). A rename must fail here, not empty
+    a metric — and a new name is a new compile-cache entry besides."""
+    import re
+
+    from benchmark.manifest import Manifest
+
+    manifest = Manifest()
+
+    def pattern(metric):
+        return re.compile(manifest.layer_metric(metric)["module"])
+
+    decode = pattern("engine_decode_device_ms")
+    prefills = [
+        pattern("engine_prefill_device_ms_p50"), pattern("engine_prefill_device_share"),
+    ]
+    model, params = model_and_params
+    eng = LMEngine(
+        model, CFG, params, max_batch=2, max_seq=64, chunk_steps=4,
+        prefill_buckets=(32,), eos_id=EOS, spec_draft_tokens=spec,
+        **(dict(kv_pool_tokens=16 * 8, page_size=16) if paged else {}),
+    )
+
+    def _probe_impl(x):
+        return x + 1
+
+    # how this JAX names a jitted function's program
+    assert "module @jit__probe_impl" in jax.jit(_probe_impl).lower(1.0).as_text()
+    chunk = "jit_" + eng._chunk.__name__ + "(12345)"
+    prefill = "jit_" + eng._suffix_prefill.__name__ + "(12345)"
+    assert chunk == (
+        f"jit__chunk{'_spec' if spec else ''}{'_paged' if paged else ''}_impl(12345)"
+    )
+    assert decode.search(chunk) and not decode.search(prefill)
+    for rx in prefills:
+        assert rx.search(prefill) and not rx.search(chunk)

@@ -587,3 +587,140 @@ def test_paged_spec_temperature_determinism(model_and_params):
 
     a, b = run(), run()
     assert a == b and len(a) > 0
+
+
+# ------------------------------- scheduler phases and prefill padding (PR 24)
+
+
+def _burst(eng, prompts, *, max_new_tokens=12, trace=None):
+    """Every prompt at once from a thread of its own; the first carries
+    ``trace``. Returns when all have completed."""
+    errors: list[Exception] = []
+
+    def worker(i):
+        try:
+            eng.submit(prompts[i], max_new_tokens=max_new_tokens,
+                       trace=trace if i == 0 else None)
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+
+    threads = [
+        threading.Thread(target=worker, args=(i,)) for i in range(len(prompts))
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(180)
+    assert not errors, errors
+    assert not any(t.is_alive() for t in threads)
+
+
+def _chunked_paged_engine(model, params):
+    return LMEngine(
+        model, CFG, params, max_batch=4, max_seq=64, chunk_steps=4,
+        prefill_buckets=(32,), eos_id=EOS, prefill_chunk=16,
+        kv_pool_tokens=16 * 24, page_size=16,
+    )
+
+
+def test_scheduler_phases_add_up_and_count_what_the_counters_count(
+    model_and_params,
+):
+    """``_phase`` gives each scheduler phase a sum and a count at the
+    boundary the profiler annotation has: with the loop's own time they
+    must account for the thread (what no phase names stays small), and
+    their counts must agree with the counters of the same work."""
+    from kubeflow_tpu.obs.trace import TRACER
+    from kubeflow_tpu.serve.engine import _SCHED_PHASES
+
+    model, params = model_and_params
+    eng = _chunked_paged_engine(model, params)
+    # pre-initialised: /metrics iterates the dict from another thread
+    assert eng.stats["sched_loop_s"] == 0.0
+    for name in _SCHED_PHASES:
+        assert eng.stats[f"sched_{name}_s"] == 0.0
+        assert eng.stats[f"sched_{name}_n"] == 0
+    keys = set(eng.stats)
+    eng.start()
+    rng = np.random.default_rng(24)
+    prompts = _prompts(rng, 9, lo=5, hi=45)
+    old_sampling = TRACER.sample_every
+    TRACER.clear()
+    TRACER.sample_every = 1
+    try:
+        root = TRACER.span("test.root")
+        _burst(eng, prompts, trace=root)
+        root.end()
+        traces = TRACER.snapshot()["traces"]
+        # the last request returns from inside a drain; a chunk dispatched
+        # ahead of it may still be in flight — let the loop drain it
+        settle = time.monotonic() + 30
+        while (
+            eng.stats["sched_drain_emit_n"] < eng.stats["chunks"]
+            and time.monotonic() < settle
+        ):
+            time.sleep(0.01)
+    finally:
+        eng.stop()
+        TRACER.sample_every = old_sampling
+        TRACER.clear()
+    assert not eng._thread.is_alive()
+    stats = eng.stats
+    assert set(stats) == keys, "a key inserted after start races /metrics"
+    named = sum(stats[f"sched_{name}_s"] for name in _SCHED_PHASES)
+    loop = stats["sched_loop_s"]
+    assert 0.0 < named <= loop + 1e-6
+    assert loop - named < 0.1 * loop, (loop, named)
+    assert all(stats[f"sched_{name}_s"] >= 0.0 for name in _SCHED_PHASES)
+    assert stats["sched_chunk_dispatch_n"] == stats["chunks"] > 0
+    # the batch ran empty: no dispatch time is left to measure a gap from
+    assert eng._last_dispatch is None
+    assert stats["sched_prefill_dispatch_n"] == stats["prefill_pieces"]
+    assert stats["sched_drain_wait_n"] == stats["sched_drain_emit_n"] == stats["chunks"]
+    # one blocking read per request: its last piece's first token
+    assert stats["sched_prefill_wait_n"] == len(prompts)
+    assert stats["sched_park_n"] == stats["idle_wakes"] >= 1
+    # every prompt is padded to whole 16-token pieces
+    assert stats["prefill_tokens"] == sum(map(len, prompts))
+    assert stats["prefill_padded_tokens"] == stats["prefill_pieces"] * 16
+    assert stats["prefill_pieces"] == sum(-(-len(p) // 16) for p in prompts)
+    (prefill,) = [
+        s for t in traces for s in t["spans"] if s["name"] == "prefill"
+    ]
+    assert prefill["attrs"]["prompt_tokens"] == len(prompts[0])
+    assert prefill["attrs"]["padded_tokens"] == -(-len(prompts[0]) // 16) * 16
+
+
+def test_scheduler_phases_are_on_the_profilers_clock(
+    model_and_params, tmp_path
+):
+    """The same phases as host spans in a ``jax.profiler`` capture, found
+    the way the benchmark's reduction finds them (``/host:CPU`` events by
+    name), so idle gaps of the device get a cause."""
+    from benchmark import trace_reduce
+
+    model, params = model_and_params
+    eng = _chunked_paged_engine(model, params).start()
+    rng = np.random.default_rng(25)
+    try:
+        _burst(eng, _prompts(rng, 3, lo=5, hi=20))     # compile outside the capture
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0                   # host annotations only
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            _burst(eng, _prompts(rng, 6, lo=5, hi=20))
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.stop()
+    trace = trace_reduce.load(trace_reduce.newest_xplane(str(tmp_path)))
+    seen = {e.name for e in trace.host if e.name.startswith("engine.")}
+    assert {
+        "engine.admit", "engine.prefill_dispatch", "engine.prefill_wait",
+        "engine.carry_upload", "engine.chunk_dispatch", "engine.drain_wait",
+        "engine.drain_emit",
+    } <= seen, seen
+    # no keyword arguments on the annotation: one name per phase
+    assert all("#" not in name for name in seen), seen
+    waits = [e for e in trace.host if e.name == "engine.drain_wait"]
+    assert all(e.dur >= 0.0 for e in waits) and len(waits) >= 2
