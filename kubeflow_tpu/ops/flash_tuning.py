@@ -1,30 +1,34 @@
-"""Flash-attention block-size selection: measured table + sweep tool.
+"""Flash-attention tile geometry (a rule on the observed shape) and the
+sweep tool that measured it; plus the paged kernel's page-size table.
 
-The S512 regime measured ~23% MFU against 37% at S128 with the fixed
-128/128 blocks (VERDICT r04 weak-item 3): block shape is the one flash
-knob that moves long-sequence throughput, and the right value is a
-HARDWARE measurement, not a formula. This module closes the loop:
-
-- :func:`select_blocks` — (block_q, block_k) for a shape. Resolution:
-  a measured table (``ops/flash_blocks_v5e.json``, produced by the sweep
-  below, override path via ``KFT_FLASH_BLOCKS_FILE``) keyed by sequence
-  bucket, else a conservative heuristic (128×128 at short sequences —
-  the measured S128 sweet spot — widening block_k at S ≥ 256 to amortize
-  per-tile softmax overhead across fewer grid steps).
-- :func:`sweep_blocks` — ON-CHIP timing of candidate shapes with the
-  chained two-point method (the constant cost of the closing host sync
-  cancels in the difference), writing the winners back to the table.
+The three flash kernels (forward, dq, dkv) each take a :class:`Tile`: how
+many q and kv rows one grid step stages, the width of the sub-tile its
+in-kernel loop computes at a time, and how many heads share the step.
+:func:`select_geometry` chooses all three from what a call can observe at
+trace time — sequence lengths, head size, element size, head count — so
+BERT's short non-causal rows with small heads and a decoder's long causal
+rows get different parameters of one algorithm, with no option
+and no file to keep in step. The rule's numbers were measured on a TPU v5e
+with :func:`sweep_blocks` at the two training shapes of the benchmark
+(PERF.md section 6, PR 26): a grid step costs about 0.35 us whatever it
+computes, so a step stages whole rows where they fit and loops over score
+tiles of a quarter to half a million elements, and only then do the MXU
+and the VPU, not the step, set the time.
 
 ``flash_attention(block_q=None)`` (and TransformerConfig
-``attn_block_q=None``) routes through :func:`select_blocks`, so a tuned
-table takes effect everywhere — training, serving, ring hops — without
-touching call sites.
+``attn_block_q=None``) routes through the rule; explicit ``block_q`` /
+``block_k`` are honoured as the staged block of every kernel
+(:func:`geometry_from_blocks`), which is what the ring hops pass.
+
+The table file (``ops/flash_blocks_v5e.json``, override
+``KFT_FLASH_BLOCKS_FILE``) now serves the paged kernel's page size only.
 """
 
 from __future__ import annotations
 
 import json
 import os
+from typing import NamedTuple
 
 _TABLE: dict | None = None
 _TABLE_PATH = os.path.join(
@@ -49,39 +53,133 @@ def reset_table_cache() -> None:
     _TABLE = None
 
 
-def _largest_divisor_leq(n: int, cap: int) -> int:
-    for c in range(min(n, cap), 0, -1):
-        if n % c == 0:
-            return c
-    return n
+# --------------------------------------------------------------------- #
+# flash kernels: tile geometry
+# --------------------------------------------------------------------- #
+
+LANES = 128  # a block's last dim tiles in 128s; so do in-kernel slices
+
+
+class Tile(NamedTuple):
+    """One kernel's work per grid step."""
+
+    block_q: int  #: q rows staged per step
+    block_k: int  #: kv rows staged per step
+    #: rows of the looped operand per in-kernel sub-tile: kv rows for the
+    #: forward and dq (score tile ``block_q x sub``), q rows for dkv
+    #: (score tile ``block_k x sub``, computed transposed)
+    sub: int
+    heads: int = 1  #: heads sharing one step (and its segment blocks)
+
+
+class Geometry(NamedTuple):
+    fwd: Tile
+    dq: Tile
+    dkv: Tile
 
 
 def _fit(seq: int, cap: int) -> int:
-    """cap adapted to divide ``seq`` — but never DEGENERATE: a prime-ish
-    sequence length must hit the kernel's explicit 'pad inputs'
-    divisibility error, not silently run a block-1 grid."""
-    d = _largest_divisor_leq(seq, cap)
-    if d == seq or d >= 64:
-        return d
+    """The largest block <= cap that tiles ``seq``: the whole sequence if
+    it is short, else a multiple of 128 that divides it, else any divisor
+    of 64 or more (the interpreter takes those; Mosaic wants the 128s).
+    Never DEGENERATE: a prime-ish length keeps the non-dividing cap so the
+    kernel's explicit 'pad inputs' error fires instead of a block-1 grid."""
+    if seq <= cap:
+        return seq
+    for c in range(cap - cap % LANES, 0, -LANES):
+        if seq % c == 0:
+            return c
+    for c in range(cap, 63, -1):
+        if seq % c == 0:
+            return c
     return cap
 
 
+def _sub(block: int, cap: int) -> int:
+    """Sub-tile width for a staged block: the block itself when it is
+    small, else a multiple of 128 that divides it (in-kernel slices must
+    sit on lane tiles)."""
+    if block <= cap:
+        return block
+    for c in range(cap - cap % LANES, 0, -LANES):
+        if block % c == 0:
+            return c
+    return block
+
+
+def _heads_per_step(heads: int, tile_elems: int) -> int:
+    """How many heads share a backward step: as many as keep the step's
+    score elements within ``_STEP_ELEMS``, a divisor of the head count, at
+    most four (each head is unrolled into the kernel's body; six and
+    twelve measured no better)."""
+    best = 1
+    for h in range(2, min(heads, 4) + 1):
+        if heads % h == 0 and h * tile_elems <= _STEP_ELEMS:
+            best = h
+    return best
+
+
+#: score elements a backward step takes on before more heads stop paying:
+#: four heads of BERT's 512 x 512
+_STEP_ELEMS = 4 * 512 * 512
+#: rows of the looped operand (kv for forward and dq, q for dkv) one grid
+#: step stages: x4's whole rows. A step costs about 0.35 us whatever it
+#: computes and refetches its blocks, so the fewer the better until VMEM
+#: says no (4096 x 128 bf16 is 1 MB a block, two blocks, two buffers)
+_STAGED_ROWS = 4096
+
+
+def select_geometry(
+    seq_q: int,
+    seq_kv: int,
+    head_dim: int,
+    *,
+    heads: int = 1,
+    itemsize: int = 2,
+) -> Geometry:
+    """Tile geometry of the three kernels for a call's shape (measured on
+    a v5e at (32, 12, 512, 64) non-causal with segment ids and at
+    (2, 16, 4096, 128) causal: PERF.md section 6, PR 26). Causality and
+    the window do not enter: dead sub-tiles are skipped inside a step
+    whatever its size."""
+    if head_dim > 128 or itemsize > 2:
+        # operand tiles and f32 temporaries scale with D and the element
+        # size: stay at 128-class tiles (what every shape ran before PR 26)
+        bq, bk = _fit(seq_q, 128), _fit(seq_kv, 256 if head_dim <= 128 else 128)
+        t = Tile(bq, bk, bk, 1)
+        return Geometry(t, t, Tile(bq, bk, bq, 1))
+    block_q, block_k = _fit(seq_q, 512), _fit(seq_kv, 512)
+    staged_q, staged_kv = _fit(seq_q, _STAGED_ROWS), _fit(seq_kv, _STAGED_ROWS)
+    # the forward pays per sub-tile for its running max / denominator
+    # (columns of block_q/8 vregs each) and the accumulator's rescale, so
+    # it takes the wider sub-tile; the backward kernels have no such state
+    # and measured faster at 512
+    return Geometry(
+        fwd=Tile(block_q, staged_kv, _sub(staged_kv, 1024), 1),
+        dq=Tile(
+            block_q, staged_kv, _sub(staged_kv, 512),
+            _heads_per_step(heads, block_q * staged_kv),
+        ),
+        dkv=Tile(
+            staged_q, block_k, _sub(staged_q, 512),
+            _heads_per_step(heads, staged_q * block_k),
+        ),
+    )
+
+
+def geometry_from_blocks(block_q: int, block_k: int) -> Geometry:
+    """Explicit ``block_q`` / ``block_k``: every kernel stages exactly
+    those blocks, one head a step; only the sub-tile is derived."""
+    t = Tile(block_q, block_k, _sub(block_k, 512), 1)
+    return Geometry(t, t, Tile(block_q, block_k, _sub(block_q, 512), 1))
+
+
 def select_blocks(seq_q: int, seq_kv: int, head_dim: int) -> tuple[int, int]:
-    """(block_q, block_k) for a flash call. Table entries are keyed by
-    (seq bucket, head_dim) — a sweep at D=64 says nothing about the VMEM
-    footprint at D=256."""
-    entry = _table().get(f"{_seq_bucket(seq_kv)}:{head_dim}")
-    if entry:
-        bq, bk = int(entry[0]), int(entry[1])
-    elif seq_kv >= 256 and head_dim <= 128:
-        # heuristic until a sweep lands: wider K blocks amortize the
-        # per-tile online-softmax rescale over fewer grid steps; 128 rows
-        # of q keep the causal skip fine-grained. Large head_dim keeps
-        # 128x128 (tile bytes scale with D).
-        bq, bk = 128, 256
-    else:
-        bq, bk = 128, 128
-    return _fit(seq_q, bq), _fit(seq_kv, bk)
+    """(block_q, block_k) for a caller that resolves blocks once and passes
+    them on explicitly to several calls (the ring hops): the rule's
+    512-class pair, which every kernel can stage as given."""
+    geometry = select_geometry(seq_q, seq_kv, head_dim)
+    return geometry.dq.block_q, geometry.dkv.block_k
 
 
 def resolve_blocks(q, k, block_q, block_k) -> tuple[int, int]:
@@ -94,102 +192,101 @@ def resolve_blocks(q, k, block_q, block_k) -> tuple[int, int]:
     return block_q, block_k
 
 
-def _seq_bucket(s: int) -> int:
-    b = 128
-    while b < s:
-        b *= 2
-    return b
+def kernel_ms_from_trace(xplane_path: str, steps: int) -> dict[str, float]:
+    """Device milliseconds per step of each flash kernel in a profile:
+    ``{"flash_fwd_q512_...": ms, ...}`` — the durations of the device's
+    ``XLA Ops`` events whose text names a flash kernel, summed over the
+    trace and divided by ``steps``."""
+    import re
+
+    from jax.profiler import ProfileData
+
+    name = re.compile(r"flash_(?:fwd|dq|dkv)_q\d+_k\d+_t\d+_h\d+")
+    total: dict[str, float] = {}
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if not plane.name.startswith("/device:TPU:0"):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for e in line.events:
+                # the instruction's own name, not its operands' (a copy
+                # of a kernel's result names the kernel too)
+                m = name.search(e.name.partition(" = ")[0])
+                if m:
+                    total[m.group(0)] = (
+                        total.get(m.group(0), 0.0) + e.duration_ns * 1e-6
+                    )
+    return {k: v / steps for k, v in sorted(total.items())}
 
 
 def sweep_blocks(
     *,
-    batch: int = 8,
-    heads: int = 12,
-    seq_lens: tuple[int, ...] = (128, 256, 512, 1024),
-    head_dim: int = 64,
-    candidates: tuple[tuple[int, int], ...] = (
-        (128, 128), (128, 256), (128, 512), (256, 128),
-        (256, 256), (256, 512), (512, 512),
-    ),
-    causal: bool = True,
-    reps: int = 3,
-    write: bool = True,
-    table_path: str | None = None,
-) -> dict:
-    """Time every candidate block shape per sequence length on the LIVE
-    backend; returns {seq: {"blocks": (bq, bk), "ms": best, "all": {...}}}
-    and (optionally) writes the winners to the measured table. Run this
-    on the chip — CPU-interpret timings are meaningless."""
-    import time
+    batch: int,
+    heads: int,
+    seq: int,
+    head_dim: int,
+    causal: bool = False,
+    window: int | None = None,
+    segments: bool = False,
+    candidates: tuple[Geometry | None, ...] = (None,),
+    steps: int = 5,
+    logdir: str,
+) -> list[dict]:
+    """Time what the trainer runs — ``value_and_grad`` of the flash call,
+    bf16, at the given shape — for each candidate geometry (``None`` = the
+    rule's choice) on the LIVE backend, and return per candidate the
+    device milliseconds of its forward, dq and dkv kernels, read from a
+    profile (host timers cannot split the three, and include dispatch).
+    ``segments`` passes all-ones segment ids, as BERT's unpadded batches
+    do. Run this on the chip — CPU-interpret timings are meaningless. A
+    candidate the compiler refuses is reported with its error."""
+    import glob
+    import importlib
 
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
-    from kubeflow_tpu.ops.flash_attention import flash_attention
+    # the package re-exports the function under the module's name
+    fa = importlib.import_module("kubeflow_tpu.ops.flash_attention")
+    shape = (batch, heads, seq, head_dim)
+    q, k, v, w = (
+        jax.random.normal(key, shape, jnp.bfloat16)
+        for key in jax.random.split(jax.random.PRNGKey(0), 4)
+    )
+    seg = jnp.ones((batch, seq), jnp.int32) if segments else None
+    scale = head_dim ** -0.5
+    results = []
+    for i, cand in enumerate(candidates):
+        geometry = cand or select_geometry(seq, seq, head_dim, heads=heads)
 
-    results: dict = {}
-    for s in seq_lens:
-        per: dict[str, float] = {}
-        rng = jax.random.PRNGKey(0)
-        kq, kk, kv = jax.random.split(rng, 3)
-        shape = (batch, heads, s, head_dim)
-        q = jax.random.normal(kq, shape, jnp.bfloat16)
-        k = jax.random.normal(kk, shape, jnp.bfloat16)
-        v = jax.random.normal(kv, shape, jnp.bfloat16)
-        for bq, bk in candidates:
-            if s % bq or s % bk or bq > s or bk > s:
-                continue
-
-            fn = jax.jit(
-                lambda q, k, v, _bq=bq, _bk=bk: flash_attention(
-                    q, k, v, causal=causal, block_q=_bq, block_k=_bk
-                )
+        def loss(q, k, v, geometry=geometry):
+            out = fa._flash(
+                q, k, v, seg, seg, causal, scale, geometry, (False, window)
             )
-            out = fn(q, k, v)  # compile
-            np.asarray(out[0, 0, 0])  # host-transfer sync
+            return (out.astype(jnp.float32) * w.astype(jnp.float32)).sum()
 
-            def run(n):
-                t0 = time.perf_counter()
-                o = None
-                for _ in range(n):
-                    o = fn(q, k, v)
-                np.asarray(o[0, 0, 0])
-                return time.perf_counter() - t0
-
-            # chained two-point: the constant sync cost cancels
-            est = []
-            for _ in range(reps):
-                t_small, t_large = run(5), run(20)
-                est.append((t_large - t_small) / 15)
-            med = sorted(est)[len(est) // 2]
-            if med <= 0:
-                # timing noise exceeded the compute delta (fast shape)
-                # — an invalid sample must never be crowned the winner
-                continue
-            per[f"{bq}x{bk}"] = round(med * 1e3, 4)
-        if not per:
-            continue
-        best = min(per, key=per.get)
-        bq, bk = (int(x) for x in best.split("x"))
-        results[s] = {"blocks": (bq, bk), "ms": per[best], "all": per}
-    if write and results:
-        path = table_path or os.environ.get(
-            "KFT_FLASH_BLOCKS_FILE", _TABLE_PATH
-        )
-        # merge into the file BEING WRITTEN (not whatever _table() cached
-        # from the env/default path): successive sweeps at different
-        # head_dims into one explicit table_path must accumulate
+        step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+        row = {"geometry": [list(t) for t in geometry]}
         try:
-            with open(path) as f:
-                table = json.load(f)
-        except (OSError, ValueError):
-            table = {}
-        for s, r in results.items():
-            table[f"{_seq_bucket(s)}:{head_dim}"] = list(r["blocks"])
-        with open(path, "w") as f:
-            json.dump(table, f, indent=1, sort_keys=True)
-        reset_table_cache()
+            jax.block_until_ready(step(q, k, v))  # compile + warm
+            run_dir = os.path.join(logdir, f"cand{i}")
+            with jax.profiler.trace(run_dir):
+                for _ in range(steps):
+                    out = step(q, k, v)
+                jax.block_until_ready(out)
+            (path,) = glob.glob(
+                os.path.join(run_dir, "plugins", "profile", "*", "*.xplane.pb")
+            )
+            ms = kernel_ms_from_trace(path, steps)
+            for kind in ("fwd", "dq", "dkv"):
+                row[f"{kind}_ms"] = sum(
+                    t for n, t in ms.items() if n.startswith(f"flash_{kind}_")
+                )
+            row["total_ms"] = sum(ms.values())
+        except Exception as e:  # noqa: BLE001 — a refused tile is a result
+            row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+        results.append(row)
     return results
 
 
@@ -224,7 +321,7 @@ def sweep_paged_pages(
     table_path: str | None = None,
 ) -> dict:
     """Time the paged decode kernel per candidate page size on the LIVE
-    backend (chained two-point, same discipline as :func:`sweep_blocks`);
+    backend (host clock, chained two-point: 20 calls minus 5, over 15);
     returns {"page_size": best, "ms": ..., "all": {...}} and (optionally)
     writes the winner to the ``paged:{head_dim}`` table entry. Each
     candidate gets its own synthetic pool + block table covering

@@ -17,6 +17,18 @@ from kubeflow_tpu.parallel.ulysses import ulysses_attention
 
 B, H, S, D = 2, 8, 256, 32
 
+# what the rule returns at the two training cells' shapes (bf16; measured on
+# the chip, PERF.md section 6 "PR 26") — pinned so a change of the rule is a
+# decision, and shared with the compile test of those geometries
+from kubeflow_tpu.ops.flash_tuning import Geometry, Tile  # noqa: E402
+
+BERT_GEOMETRY = Geometry(
+    Tile(512, 512, 512, 1), Tile(512, 512, 512, 4), Tile(512, 512, 512, 4)
+)
+X4_GEOMETRY = Geometry(
+    Tile(512, 4096, 1024, 1), Tile(512, 4096, 512, 1), Tile(4096, 512, 512, 1)
+)
+
 
 @pytest.fixture(scope="module")
 def qkv():
@@ -354,38 +366,205 @@ def test_flash_nondefault_blocks_match_reference(blocks):
     )
 
 
-def test_block_selection_table_and_heuristic(tmp_path, monkeypatch):
+def test_block_selection_rule():
     from kubeflow_tpu.ops import flash_tuning as ft
+    from kubeflow_tpu.ops.flash_tuning import Geometry, Tile
 
-    # no table: heuristic — 128x128 short, wider K at 256+
-    monkeypatch.setenv("KFT_FLASH_BLOCKS_FILE", str(tmp_path / "none.json"))
-    ft.reset_table_cache()
-    assert ft.select_blocks(128, 128, 64) == (128, 128)
-    assert ft.select_blocks(512, 512, 64) == (128, 256)
-    # big head_dim stays conservative (tile bytes scale with D)
-    assert ft.select_blocks(512, 512, 256) == (128, 128)
+    # the two training cells (PERF.md section 6, PR 26). BERT: one head's
+    # whole rows per step, heads sharing a step; x4's per-chip problem:
+    # long causal rows, a staged kv block looped over in sub-tiles
+    assert ft.select_geometry(512, 512, 64, heads=12) == BERT_GEOMETRY
+    assert (
+        ft.select_geometry(4096, 4096, 128, heads=16)
+        == X4_GEOMETRY
+    )
+    # heads a backward step divide the head count
+    assert ft.select_geometry(512, 512, 64, heads=3).dq.heads == 3
+    assert ft.select_geometry(512, 512, 64, heads=7).dq.heads == 1
+    assert ft.select_geometry(2048, 2048, 64, heads=12).dkv.heads == 1
+    # short rows are staged whole; 128-class tiles where heads are wide or
+    # the operands are f32 (tile bytes scale with both)
+    assert ft.select_geometry(128, 128, 64).fwd[:3] == (128, 128, 128)
+    wide = ft.select_geometry(512, 512, 256)
+    assert wide == Geometry(
+        Tile(128, 128, 128, 1), Tile(128, 128, 128, 1), Tile(128, 128, 128, 1)
+    )
+    f32 = ft.select_geometry(512, 512, 64, heads=12, itemsize=4)
+    assert f32.fwd == Tile(128, 256, 256, 1) and f32.dkv == Tile(128, 256, 128, 1)
     # block sizes divide the sequence when a sane divisor exists
     assert ft.select_blocks(96, 96, 64) == (96, 96)
-    assert ft.select_blocks(384, 384, 64) == (128, 192)
+    assert ft.select_blocks(384, 384, 64) == (384, 384)
+    assert ft.select_blocks(1536, 1536, 64) == (512, 512)
+    assert ft.select_blocks(640, 640, 64) == (128, 128)
+    # the pair the ring hops pass on explicitly: 512-class, which all
+    # three kernels can stage as given
+    assert ft.select_blocks(4096, 4096, 128) == (512, 512)
+    assert ft.select_blocks(512, 512, 256) == (128, 128)
+    # explicit blocks are staged as given; only the sub-tile is derived
+    assert ft.geometry_from_blocks(128, 1024) == Geometry(
+        Tile(128, 1024, 512, 1), Tile(128, 1024, 512, 1),
+        Tile(128, 1024, 128, 1),
+    )
     # prime-ish lengths must NOT degrade to block-1 grids — selection
     # keeps a non-dividing cap so the kernel's explicit 'pad inputs'
     # divisibility error fires instead
     bq, bk = ft.select_blocks(509, 509, 64)
-    assert bq > 1 and bk > 1 and (509 % bq and 509 % bk)
-    q = jnp.zeros((1, 1, 509, 64), jnp.float32)
+    assert bq == bk == 509  # short: staged whole, one block
+    bq, bk = ft.select_blocks(4099, 4099, 64)
+    assert bq > 1 and bk > 1 and (4099 % bq and 4099 % bk)
+    q = jnp.zeros((1, 1, 4099, 64), jnp.bfloat16)
     with pytest.raises(ValueError, match="pad inputs"):
         flash_attention(q, q, q, causal=True, block_q=None, block_k=None,
                         interpret=True)
 
-    # a measured table wins (keyed by seq bucket AND head_dim)
-    (tmp_path / "t.json").write_text('{"512:64": [256, 512]}')
-    monkeypatch.setenv("KFT_FLASH_BLOCKS_FILE", str(tmp_path / "t.json"))
-    ft.reset_table_cache()
-    assert ft.select_blocks(512, 512, 64) == (256, 512)
-    assert ft.select_blocks(512, 512, 128) == (128, 256)  # other D: heuristic
-    # the table's bucket entry still adapts to non-dividing shapes
-    assert ft.select_blocks(384, 384, 64) == (192, 384)
-    ft.reset_table_cache()
+
+def _geometry_cases():
+    """Every kind of geometry the rule (or explicit blocks) can hand the
+    kernels: (shape, causal, window, segments, geometry)."""
+    from kubeflow_tpu.ops import flash_tuning as ft
+    from kubeflow_tpu.ops.flash_tuning import Geometry, Tile
+
+    g = lambda f, dq, dkv: Geometry(Tile(*f), Tile(*dq), Tile(*dkv))
+    return {
+        # BERT's case: non-causal, segment ids, whole rows, heads a step
+        "bert-rule": ((1, 4, 512, 16), False, None, True,
+                      ft.select_geometry(512, 512, 64, heads=4)),
+        # x4's case cut in length: a staged kv block with an inner loop,
+        # diagonal, interior and skipped sub-tiles; the window never bites
+        "x4-rule-s1024": ((1, 2, 1024, 16), True, 1024, False,
+                          ft.select_geometry(1024, 1024, 128)),
+        "x4-rule-s2048-window": ((1, 1, 2048, 8), True, 600, False,
+                                 ft.select_geometry(2048, 2048, 128)),
+        # window < S at block_q != block_k: diagonal tiles, band-edge
+        # tiles and skipped tiles all present, in all three kernels
+        "window40-uneven": ((2, 4, 256, 32), True, 40, True,
+                            g((64, 128, 32, 2), (32, 128, 64, 1),
+                              (128, 32, 64, 2))),
+        "window100-uneven": ((1, 2, 256, 16), True, 100, False,
+                             g((32, 64, 32, 1), (64, 128, 32, 1),
+                               (64, 32, 16, 1))),
+        "causal-inner-loop": ((2, 4, 256, 32), True, None, False,
+                              g((64, 128, 64, 1), (32, 256, 64, 2),
+                                (128, 64, 32, 1))),
+        "noncausal-heads4-inner-loop": ((2, 4, 256, 32), False, None, True,
+                                        g((128, 256, 128, 2),
+                                          (64, 128, 64, 4),
+                                          (256, 64, 128, 2))),
+        # lengths that are not a power of two
+        "s96-rule": ((1, 2, 96, 64), False, None, True,
+                     ft.select_geometry(96, 96, 64, heads=2)),
+        "s384-rule-causal": ((1, 2, 384, 32), True, None, True,
+                             ft.select_geometry(384, 384, 64, heads=2)),
+        "s1536-rule": ((1, 1, 1536, 8), True, None, False,
+                       ft.select_geometry(1536, 1536, 64)),
+        # f32 operands / wide heads: the 128-class tiles
+        "f32-rule": ((1, 2, 512, 32), True, None, False,
+                     ft.select_geometry(512, 512, 32, heads=2, itemsize=4)),
+        # explicit blocks wider than a sub-tile
+        "explicit-128x1024": ((1, 1, 1024, 16), True, None, False,
+                              ft.geometry_from_blocks(128, 1024)),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_geometry_cases()))
+def test_flash_geometries_match_reference(case):
+    """Forward AND gradients of every geometry against the plain-XLA
+    reference, in interpret mode (the same tile code Mosaic compiles)."""
+    import importlib
+
+    fa = importlib.import_module("kubeflow_tpu.ops.flash_attention")
+    shape, causal, window, segments, geometry = _geometry_cases()[case]
+    b, _, s, d = shape
+    q, k, v, w = (
+        jax.random.normal(kk, shape, jnp.float32)
+        for kk in jax.random.split(jax.random.PRNGKey(0), 4)
+    )
+    seg = None
+    if segments:
+        rng = np.random.RandomState(1)
+        seg = jnp.asarray(np.sort(rng.randint(0, 3, (b, s)), axis=-1))
+
+    def loss_flash(q, k, v):
+        out = fa._flash(
+            q, k, v, seg, seg, causal, d ** -0.5, geometry, (True, window)
+        )
+        return (out * w).sum(), out
+
+    def loss_ref(q, k, v):
+        out = reference_attention(
+            q, k, v, causal=causal, window=window,
+            q_segment_ids=seg, kv_segment_ids=seg,
+        )
+        return (out * w).sum(), out
+
+    (_, out), grads = jax.value_and_grad(
+        loss_flash, argnums=(0, 1, 2), has_aux=True
+    )(q, k, v)
+    (_, ref), grads_ref = jax.value_and_grad(
+        loss_ref, argnums=(0, 1, 2), has_aux=True
+    )(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    for name, a, r in zip("qkv", grads, grads_ref):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(r), atol=5e-5,
+            err_msg=f"d{name} mismatch ({case})",
+        )
+
+
+def test_flash_kernels_are_named_by_geometry():
+    """The device trace tells forward, dq and dkv apart, and which
+    geometry engaged: each pallas_call carries both in its name."""
+    import re
+
+    q = jnp.zeros((1, 4, 512, 64), jnp.bfloat16)
+    seg = jnp.ones((1, 512), jnp.int32)
+
+    def loss(q, k, v):
+        return flash_attention(
+            q, k, v, q_segment_ids=seg, kv_segment_ids=seg,
+            block_q=None, block_k=None, interpret=True,
+        ).astype(jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
+    names = set(re.findall(r"flash_[a-z]+_q\d+_k\d+_t\d+_h\d+", text))
+    assert {n.split("_")[1] for n in names} == {"fwd", "dq", "dkv"}, names
+
+
+def test_flash_fully_masked_rows_are_zero():
+    """A q row whose segment matches no key: output and every gradient
+    through it exactly zero (the ring's skipped partials rely on it)."""
+    b, h, s, d = 1, 2, 64, 16
+    q, k, v = (
+        jax.random.normal(kk, (b, h, s, d), jnp.float32)
+        for kk in jax.random.split(jax.random.PRNGKey(2), 3)
+    )
+    qseg = jnp.concatenate(
+        [jnp.full((b, 16), 7, jnp.int32), jnp.ones((b, s - 16), jnp.int32)], 1
+    )
+    kseg = jnp.ones((b, s), jnp.int32)
+
+    from kubeflow_tpu.ops.flash_attention import flash_attention_bwd
+
+    out, lse = flash_attention(
+        q, k, v, q_segment_ids=qseg, kv_segment_ids=kseg, block_q=32,
+        block_k=32, interpret=True, return_residuals=True,
+    )
+    assert lse.shape == (b, h, s)
+    dq, dk, dv = flash_attention_bwd(
+        q, k, v, out, lse, jnp.ones_like(out), causal=False,
+        q_segment_ids=qseg, kv_segment_ids=kseg, block_q=32, block_k=32,
+        interpret=True,
+    )
+    assert not np.asarray(dq[:, :, :16]).any()
+    ref_dk, ref_dv = jax.grad(
+        lambda k, v: reference_attention(
+            q[:, :, 16:], k, v, q_segment_ids=qseg[:, 16:],
+            kv_segment_ids=kseg,
+        ).sum(),
+        argnums=(0, 1),
+    )(k, v)
+    np.testing.assert_allclose(np.asarray(dk), np.asarray(ref_dk), atol=5e-5)
+    np.testing.assert_allclose(np.asarray(dv), np.asarray(ref_dv), atol=5e-5)
 
 
 def test_flash_auto_blocks_parity():

@@ -18,7 +18,8 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
-from kubeflow_tpu.ops.flash_attention import flash_attention
+from kubeflow_tpu.ops.flash_attention import _tile_name, flash_attention
+from kubeflow_tpu.ops.flash_tuning import select_geometry
 from kubeflow_tpu.ops.paged_attention import paged_attention
 
 
@@ -60,6 +61,25 @@ def _flash_case(seq, *, grad):
     return fn, [qkv, qkv, qkv, ((32, seq), jnp.int32)]
 
 
+def _flash_cell_case(shape, *, causal, window, segments):
+    """A training cell's attention call as the trainer runs it: forward and
+    gradients, bf16, geometry from the rule — the compile proves all three
+    chosen tiles fit VMEM and tile cleanly on the described v5e."""
+    def fn(q, k, v, seg):
+        seg = seg if segments else None
+
+        def f(q, k, v):
+            return flash_attention(
+                q, k, v, causal=causal, window=window, q_segment_ids=seg,
+                kv_segment_ids=seg, block_q=None, block_k=None,
+            ).astype(jnp.float32).sum()
+
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    qkv = (shape, jnp.bfloat16)
+    return fn, [qkv, qkv, qkv, ((shape[0], shape[2]), jnp.int32)]
+
+
 def _paged_case(*, kv_dtype, groups, span, page=64, batch=8, kv_heads=4,
                 head_dim=64, pages_per_row=16):
     """The engine's paged read: (B, H, span, D) queries over a flat
@@ -92,6 +112,21 @@ CASES = {
     "flash-fwd-S512": _flash_case(512, grad=False),
     "flash-bwd-S512": _flash_case(512, grad=True),
 }
+# the two training cells of BENCHMARK.json: BERT-base's step, and the
+# per-chip problem of mistral-7b_pretrain-x4 (16 of 32 heads, window = S);
+# a window that bites, a length staged whole, and f32 operands beside them
+CASES["flash-cell-bert-S512"] = _flash_cell_case(
+    (32, 12, 512, 64), causal=False, window=None, segments=True
+)
+CASES["flash-cell-x4-S4096"] = _flash_cell_case(
+    (2, 16, 4096, 128), causal=True, window=4096, segments=False
+)
+CASES["flash-cell-x4-S4096-window1024"] = _flash_cell_case(
+    (2, 16, 4096, 128), causal=True, window=1024, segments=False
+)
+CASES["flash-S384-D64"] = _flash_cell_case(
+    (4, 12, 384, 64), causal=False, window=None, segments=True
+)
 for _kv in (jnp.bfloat16, jnp.int8):
     for _g in (1, 4):  # MHA and 4:1 GQA
         # span 1 = decode, 5 = speculative verify (K=4), 128 = a prefill
@@ -113,5 +148,11 @@ for _p in (16, 128):
 def test_kernel_compiles_for_v5e(v5e, name):
     fn, shapes = CASES[name]
     args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    if name.startswith("flash-cell"):
+        # the kernels the rule chose for this shape are the ones compiled
+        (b, h, s, d), _ = shapes[0]
+        geometry = select_geometry(s, s, d, heads=h)
+        for kind, tile in zip(("fwd", "dq", "dkv"), geometry):
+            assert _tile_name(kind, tile) in text

@@ -107,47 +107,73 @@ def test_generation_scan_on_chip():
     assert (np.asarray(n_valid) <= 16).all()
 
 
-def test_block_sweep_and_tuned_s512_parity(tmp_path):
-    """Sweep candidate flash block shapes ON CHIP (compiled Mosaic, the
-    thing interpret mode cannot exercise), persist the winners, and pin
-    the tuned S512 configuration to reference numerics."""
-    import numpy as np
+CELL_CASES = {
+    # bert-base_mlm-s512's attention call, cut in batch and heads:
+    # non-causal, segment ids (pad = 0, valid = 1), heads of 64
+    "bert-s512": ((2, 4, 512, 64), False, None, True),
+    # mistral-7b_pretrain-x4's per-chip call, cut likewise: causal, heads
+    # of 128; the published window (= S: never bites) and one that does
+    "x4-s4096-window4096": ((1, 4, 4096, 128), True, 4096, False),
+    "x4-s4096-window1024": ((1, 4, 4096, 128), True, 1024, False),
+}
 
+
+@pytest.mark.parametrize("case", sorted(CELL_CASES))
+def test_flash_cell_shapes_compiled_parity(case):
+    """Compiled forward + gradients at the training cells' shapes, bf16,
+    geometry from the rule, against the f32 reference at full precision.
+    Errors are measured against the largest reference value: bf16 rounds
+    the output, p and ds at 2**-8 relative each."""
+    shape, causal, window, segments = CELL_CASES[case]
+    b, _, s, _ = shape
+    q, k, v, w = (
+        jax.random.normal(kk, shape, jnp.bfloat16)
+        for kk in jax.random.split(jax.random.PRNGKey(3), 4)
+    )
+    seg = None
+    if segments:
+        # rows padded to different lengths: valid tokens 1, pads 0
+        lens = np.linspace(s // 3, s, b).astype(int)
+        seg = jnp.asarray((np.arange(s)[None, :] < lens[:, None]).astype(np.int32))
+
+    def make(fn, **kw):
+        def loss(q, k, v):
+            out = fn(
+                q, k, v, causal=causal, window=window, q_segment_ids=seg,
+                kv_segment_ids=seg, **kw,
+            )
+            return (out.astype(jnp.float32) * w.astype(jnp.float32)).sum(), out
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
+
+    (_, out), grads = make(flash_attention, block_q=None, block_k=None)(q, k, v)
+    f32 = lambda x: x.astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        (_, ref), grads_ref = make(reference_attention)(f32(q), f32(k), f32(v))
+    for name, a, r in zip(
+        ("out", "dq", "dk", "dv"), (out, *grads), (ref, *grads_ref)
+    ):
+        a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
+        assert np.isfinite(a).all(), name
+        err = np.abs(a - r).max() / np.abs(r).max()
+        assert err < 2e-2, (case, name, err)
+
+
+def test_block_sweep_times_the_three_kernels(tmp_path):
+    """The sweep tool ON CHIP: value_and_grad at a given shape, device time
+    of forward, dq and dkv read from a profile, for the rule's geometry
+    and an explicit 128-class one — which the rule must beat."""
     from kubeflow_tpu.ops import flash_tuning as ft
-    from kubeflow_tpu.ops.flash_attention import (
-        flash_attention,
-        reference_attention,
+
+    small = ft.geometry_from_blocks(128, 256)
+    rows = ft.sweep_blocks(
+        batch=4, heads=12, seq=512, head_dim=64, segments=True,
+        candidates=(None, small), steps=3, logdir=str(tmp_path),
     )
-
-    res = ft.sweep_blocks(
-        batch=4, heads=8, seq_lens=(512,), head_dim=64, reps=2,
-        table_path=str(tmp_path / "blocks.json"),
-    )
-    assert 512 in res and res[512]["blocks"], res
-    # every candidate timed; winner is the argmin
-    best = res[512]["blocks"]
-    assert f"{best[0]}x{best[1]}" in res[512]["all"]
-
-    import os
-
-    os.environ["KFT_FLASH_BLOCKS_FILE"] = str(tmp_path / "blocks.json")
-    ft.reset_table_cache()
-    try:
-        assert ft.select_blocks(512, 512, 64) == tuple(best)
-        import jax
-        import jax.numpy as jnp
-
-        ks = jax.random.split(jax.random.PRNGKey(0), 3)
-        q, k, v = (
-            jax.random.normal(kk, (2, 4, 512, 64), jnp.bfloat16) for kk in ks
-        )
-        out = flash_attention(q, k, v, causal=True, block_q=None,
-                              block_k=None)
-        ref = reference_attention(q, k, v, causal=True)
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(ref, np.float32),
-            rtol=2e-2, atol=2e-2,  # bf16 operands
-        )
-    finally:
-        os.environ.pop("KFT_FLASH_BLOCKS_FILE", None)
-        ft.reset_table_cache()
+    assert len(rows) == 2 and not any("error" in r for r in rows), rows
+    for r in rows:
+        assert r["fwd_ms"] > 0 and r["dq_ms"] > 0 and r["dkv_ms"] > 0, r
+    assert rows[0]["geometry"] == [
+        list(t) for t in ft.select_geometry(512, 512, 64, heads=12)
+    ]
+    assert rows[0]["total_ms"] < rows[1]["total_ms"], rows
