@@ -30,6 +30,9 @@ class Evidence:
     attempted: int = 0
     failed: int = 0
     correct: bool = False
+    #: every number ``correct`` was decided on, each beside its limit
+    #: (``<name>_limit``): the result line's last key
+    check: dict[str, float] = dataclasses.field(default_factory=dict)
     notes: dict[str, Any] = dataclasses.field(default_factory=dict)
 
 

@@ -11,6 +11,13 @@ from benchmark import opcount, traffic as traffic_gen
 from benchmark.families import DTYPES
 from benchmark.reference import bert as reference
 
+# what ``benchmark/opcount.py`` asks of a family
+forward_flops_per_sequence = opcount.bert_forward_flops_per_sequence
+
+
+def attention_pairs(cfg: Mapping[str, Any], seq: int) -> int:
+    """(query, key) pairs of one sequence: bidirectional, every pair."""
+    return seq * seq
 
 
 def program_config(cfg: Mapping[str, Any], **overrides):

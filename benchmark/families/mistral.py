@@ -17,6 +17,13 @@ from benchmark import opcount, traffic as traffic_gen
 from benchmark.families import DTYPES
 from benchmark.reference import decoder as reference
 
+# what ``benchmark/opcount.py`` asks of a family
+forward_flops_per_sequence = opcount.decoder_forward_flops_per_sequence
+
+
+def attention_pairs(cfg: Mapping[str, Any], seq: int) -> int:
+    """(query, key) pairs of one sequence: causal, inside the window."""
+    return opcount.causal_pairs(seq, cfg.get("sliding_window"))
 
 
 def program_config(cfg: Mapping[str, Any], **overrides):
@@ -89,3 +96,25 @@ def abstract_params(model):
 
 def reference_logits(params, tokens, rows, cfg: Mapping[str, Any]):
     return reference.logits_at(params, np.asarray(tokens, np.int32), rows, cfg)
+
+
+def serve_context(cfg: Mapping[str, Any], mix: Mapping[str, Any], serve: Mapping[str, Any]) -> dict:
+    """What this family counts for a serving cell (published by the runner
+    as ``context.<key>``). ``forward_flops_per_token``: one token through
+    the held parameters and the head, plus attention at the mean context of
+    the mix's tokens — every seed offers the same lengths (the stratified
+    quantile midpoints), so the mean is a constant of the mix. Prompt token
+    ``i`` sees ``min(i + 1, window)`` keys, and so does the output token at
+    position ``i``; a request's last output token is never fed back."""
+    window = cfg.get("sliding_window")
+    mid = (np.arange(traffic_gen.BLOCK) + 0.5) / traffic_gen.BLOCK
+    tokens = pairs = 0
+    # prompt and output lengths are drawn independently: every pairing
+    for p in traffic_gen.lengths_at(mix["prompt_tokens"], mid):
+        for o in traffic_gen.lengths_at(mix["output_tokens"], mid):
+            tokens += p + o - 1
+            pairs += opcount.causal_pairs(int(p + o - 1), window)
+    return {
+        "forward_flops_per_token": opcount.decoder_forward_flops_per_token(cfg, pairs / tokens),
+        "mean_context_tokens": pairs / tokens,
+    }

@@ -10,6 +10,8 @@ from __future__ import annotations
 
 from typing import Mapping
 
+from benchmark.manifest import plugin
+
 
 def bert_forward_flops_per_sequence(cfg: Mapping, seq: int) -> float:
     """Matrix operations of one BertForMaskedLM forward pass over one
@@ -51,11 +53,9 @@ def causal_pairs(seq: int, window: int | None) -> int:
 
 def train_flops_per_token(family: str, cfg: Mapping, seq: int) -> float:
     """Forward + backward matrix operations per token of a training step
-    (3x forward), at sequence length ``seq``."""
-    fwd = {
-        "bert": bert_forward_flops_per_sequence,
-        "mistral": decoder_forward_flops_per_sequence,
-    }[family](cfg, seq)
+    (3x forward), at sequence length ``seq``. The forward count is the
+    family's own (``families/<family>.py::forward_flops_per_sequence``)."""
+    fwd = plugin("families", family).forward_flops_per_sequence(cfg, seq)
     return 3.0 * fwd / seq
 
 
@@ -67,14 +67,12 @@ def attention_train_cost(
     dP recomputed plus one product; dkv likewise — the flash-attention
     count), and the least bytes: q, k, v, o read or written once forward,
     and q, k, v, o, do read and dq, dk, dv written once backward, in the
-    activation type (2 bytes)."""
+    activation type (2 bytes). Which (query, key) pairs a sequence keeps
+    is the family's own (``attention_pairs``)."""
     heads = cfg["num_attention_heads"]
     d = cfg.get("head_dim") or cfg["hidden_size"] // heads
     layers = cfg["num_hidden_layers"]
-    if family == "bert":
-        pairs = seq * seq
-    else:
-        pairs = causal_pairs(seq, cfg.get("sliding_window"))
+    pairs = plugin("families", family).attention_pairs(cfg, seq)
     fwd = 4.0 * pairs * d * heads
     flops = batch * layers * (fwd + 2.5 * fwd)
     # q, k, v, o forward; q, k, v, o, do, dq, dk, dv backward. K and V count
@@ -82,6 +80,17 @@ def attention_train_cost(
     # the kernel, so that is what the kernel is given to read.
     elems = seq * heads * d
     return {"flops": flops, "bytes": batch * layers * 2.0 * (4 + 8) * elems}
+
+
+def decoder_forward_flops_per_token(cfg: Mapping, context: float) -> float:
+    """Matrix operations of one token's forward pass through the same
+    decoder while serving: every product of ``decoder_forward_flops_per_
+    sequence`` once, and QK^T and PV against ``context`` keys (the mean
+    number a token of the mix attends to, itself included)."""
+    heads = cfg["num_attention_heads"]
+    d = cfg.get("head_dim") or cfg["hidden_size"] // heads
+    one_key = 4.0 * cfg["num_hidden_layers"] * heads * d
+    return decoder_forward_flops_per_sequence(cfg, 1) + one_key * (context - 1)
 
 
 def roofline_seconds(flops: float, nbytes: float, peaks: Mapping) -> tuple[float, str]:

@@ -4,8 +4,10 @@
 
 Loads, warms, measures for ``--seconds``, checks the outputs, and prints as
 the last line of standard output one JSON object: ``correct``,
-``attempted``, ``failed``, ``metrics``, ``device`` and, traced,
-``breakdown``. With ``--trace 0`` the metrics are the cell's end-to-end
+``attempted``, ``failed``, ``metrics``, ``device``, traced ``breakdown``,
+and last ``check``: every number ``correct`` was decided on beside its
+limit (``<name>_limit``), which are also the last lines on standard error.
+With ``--trace 0`` the metrics are the cell's end-to-end
 metrics, taken with the profiler off and no request traced; with
 ``--trace 1`` they are its per-layer metrics, from a run in which every
 request carries a trace context and a few seconds of the window are
@@ -22,7 +24,7 @@ import argparse  # noqa: E402
 import json  # noqa: E402
 import sys  # noqa: E402
 
-from benchmark import harness  # noqa: E402
+from benchmark import check as check_rules, harness  # noqa: E402
 from benchmark.manifest import Manifest, plugin  # noqa: E402
 from benchmark.runners import RunContext  # noqa: E402
 
@@ -59,6 +61,8 @@ def result_line(manifest: Manifest, ev, device: dict, traced: bool) -> dict:
             "device_ops": ev.trace.top_op_groups(10),
             "idle_gaps": ev.trace.idle_gaps_by_host_activity(10),
         }
+    # last, so that the end of a failed run's line says by how much
+    line["check"] = ev.check
     return line
 
 
@@ -96,6 +100,7 @@ def main(argv: list[str] | None = None) -> int:
         "numbers": {k: v for k, v in sorted(ev.numbers.items())},
     }, default=str), flush=True)
     print(json.dumps(line), flush=True)
+    print("\n".join(check_rules.stderr_lines(line["check"])), file=sys.stderr, flush=True)
     return 0
 
 

@@ -40,9 +40,14 @@ def stratified_uniforms(n: int, rng: np.random.Generator) -> np.ndarray:
 
 
 def draw_lengths(spec: Mapping[str, Any], n: int, rng: np.random.Generator) -> np.ndarray:
+    return lengths_at(spec, stratified_uniforms(n, rng))
+
+
+def lengths_at(spec: Mapping[str, Any], quantiles) -> np.ndarray:
+    """The distribution's lengths at the given quantiles in (0, 1)."""
     if spec["dist"] != "lognormal":
         raise ValueError(f"unknown length distribution {spec['dist']!r}")
-    z = np.array([NORMAL.inv_cdf(x) for x in stratified_uniforms(n, rng)])
+    z = np.array([NORMAL.inv_cdf(x) for x in quantiles])
     raw = np.exp(math.log(spec["median"]) + spec["sigma"] * z)
     return np.clip(np.rint(raw), spec["min"], spec["max"]).astype(np.int64)
 

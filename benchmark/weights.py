@@ -12,7 +12,9 @@ import jax.numpy as jnp
 def seeded_params(abstract, seed: int, dtype):
     """A parameter tree shaped like ``abstract`` (from ``jax.eval_shape`` of
     the model's ``init``): projection kernels and embeddings normal with
-    standard deviation ``fan_in ** -0.5``, norm scales one, biases zero,
+    standard deviation ``fan_in ** -0.5`` (a kernel's fan-in is its
+    second-to-last axis, so a stack of experts ``(E, in, out)`` is scaled
+    like its members ``(in, out)``), norm scales one, biases zero,
     learned positions normal 0.02 — the scales of the program's
     initializers, so activations have the size they have after its
     ``init``. Leaf ``i`` depends on ``(seed, i)`` alone."""
@@ -28,7 +30,7 @@ def seeded_params(abstract, seed: int, dtype):
             elif name == "bias":
                 x = jnp.zeros(leaf.shape, dtype)
             elif name == "kernel":
-                x = jax.random.normal(k, leaf.shape, dtype) * leaf.shape[0] ** -0.5
+                x = jax.random.normal(k, leaf.shape, dtype) * leaf.shape[-2] ** -0.5
             elif name == "embedding":
                 x = jax.random.normal(k, leaf.shape, dtype) * leaf.shape[1] ** -0.5
             else:

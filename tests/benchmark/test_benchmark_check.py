@@ -1,0 +1,261 @@
+"""``benchmark/check.py``: a serving cell's limits as data of the cell, the
+pure ``judge`` on arrays of regrets, and why a routed model needs a 99th
+percentile where a dense one is held to its maximum."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import check
+from benchmark.manifest import Manifest
+
+#: the dense cell's block as PR 22's three constants stated it (``REGRET_MAX``
+#: 0.1, ``REGRET_MEAN`` 0.001, ``CHECKED_REQUESTS`` 3; 15 chip runs: worst
+#: 0.042, mean at most 0.00015)
+GEN_CLOSED = {
+    "requests": 3, "regret_max": 0.1, "regret_mean": 0.001,
+    "measured": {"regret_max": 0.042, "regret_mean": 0.00015}, "seeds": [17, 23, 29, 31, 37],
+}
+#: a block of the kind a routed cell states, from eight seeds of the toy at
+#: the end of this file (CPU; honest worst: p99 0.0394, mean 0.00221, 96.9 %
+#: the reference's choice; maxima 0.035-0.641)
+ROUTED = {
+    "requests": 3, "regret_p99": 0.15, "regret_mean": 0.01, "argmax_share_min": 0.9,
+    "measured": {"regret_p99": 0.04, "regret_mean": 0.0023, "argmax_share_min": 0.969},
+    "seeds": [1, 2, 3, 4, 5, 6, 7, 8],
+}
+
+
+def dense_like(n=1200, seed=0):
+    """What the dense cell reads on the chip: ~1 % near-ties of up to 0.04."""
+    rng = np.random.default_rng(seed)
+    r = np.zeros(n)
+    flips = rng.choice(n, n // 100, replace=False)
+    r[flips] = rng.uniform(0.001, 0.04, len(flips))
+    return r
+
+
+def old_verdict(r):
+    """The two comparisons ``check_outputs`` made before the limits were
+    data (``REGRET_MAX`` 0.1, ``REGRET_MEAN`` 0.001)."""
+    return bool(r.max() <= 0.1 and r.mean() <= 0.001)
+
+
+def test_dense_like_regrets_pass_gen_closeds_block():
+    today = Manifest().traffic("gen-closed")
+    assert check.judge(dense_like(), check.validate(today))["ok"]
+    got = check.judge(dense_like(), check.validate({"check": GEN_CLOSED, "check_why": "x"}))
+    assert got["ok"] and got["failed"] == []
+    n = got["numbers"]
+    assert n["tokens_checked"] == 1200 and n["regret_max"] <= 0.04 < n["regret_max_limit"] == 0.1
+    assert n["regret_mean_limit"] == 0.001 and n["argmax_share"] == 0.99
+    assert "regret_p99_limit" not in n and "argmax_share_limit" not in n
+
+
+def test_one_percent_of_positions_at_three_tenths():
+    r = dense_like(2000)
+    r[:20] = 0.3                                  # a routed model's flipped near-ties
+    got = check.judge(r, check.validate({"check": ROUTED, "check_why": "x"}))
+    assert got["ok"] and got["numbers"]["regret_p99"] < 0.04 and got["numbers"]["regret_max"] == 0.3
+    assert got["numbers"]["tokens_checked_limit"] == 1000
+    held_to_max = check.judge(r, GEN_CLOSED)
+    assert not held_to_max["ok"] and "regret_max 0.3" in held_to_max["failed"][0]
+
+
+def test_a_shifted_mean_fails():
+    got = check.judge(dense_like() + 0.002, GEN_CLOSED)
+    assert not got["ok"] and got["numbers"]["regret_max"] < 0.1
+    assert [f.split()[0] for f in got["failed"]] == ["regret_mean"]
+
+
+def test_a_99th_percentile_of_too_few_tokens_is_not_correct():
+    block = dict(ROUTED)
+    assert check.judge(np.zeros(1000), block)["ok"]
+    got = check.judge(np.zeros(999), block)
+    assert not got["ok"] and "regret_p99 needs 1000" in got["failed"][0]
+    assert got["numbers"]["tokens_checked"] == 999 < got["numbers"]["tokens_checked_limit"]
+
+
+def test_argmax_share_is_a_lower_limit():
+    r = np.zeros(2000)
+    r[:220] = 1e-6                                # 89 % the reference's choice
+    got = check.judge(r, ROUTED)
+    assert not got["ok"] and got["numbers"]["argmax_share"] == 0.89
+    assert got["numbers"]["argmax_share_limit"] == 0.9
+
+
+@pytest.mark.parametrize("case", [
+    "dense", "one flip at 0.09", "one flip at 0.11", "mean at 0.00099", "mean at 0.00101", "all zero",
+])
+def test_judge_returns_what_the_old_two_comparisons_returned(case):
+    r = {
+        "dense": dense_like(1100, 7),
+        "one flip at 0.09": np.r_[np.zeros(900), 0.09],
+        "one flip at 0.11": np.r_[np.zeros(900), 0.11],
+        "mean at 0.00099": np.full(800, 0.00099),
+        "mean at 0.00101": np.full(800, 0.00101),
+        "all zero": np.zeros(30),
+    }[case]
+    got = check.judge(r, GEN_CLOSED)
+    assert got["ok"] == old_verdict(r)
+    assert got["numbers"]["regret_max"] == r.max() and got["numbers"]["regret_mean"] == r.mean()
+
+
+def edited(block, **changes):
+    out = {k: (dict(v) if isinstance(v, dict) else v) for k, v in block.items()}
+    for key, value in changes.items():
+        target, _, leaf = key.rpartition("__")
+        where = out[target] if target else out
+        if value is None:
+            where.pop(leaf)
+        else:
+            where[leaf] = value
+    return out
+
+
+@pytest.mark.parametrize("mix,message", [
+    ({"check_why": "x"}, "missing"),
+    ({"check": GEN_CLOSED}, "check_why"),
+    ({"check": GEN_CLOSED, "check_why": "  "}, "check_why"),
+    ({"check": edited(GEN_CLOSED, regret_p95=0.1), "check_why": "x"}, "unknown key"),
+    ({"check": edited(GEN_CLOSED, requests=None), "check_why": "x"}, "requests"),
+    ({"check": edited(GEN_CLOSED, regret_mean=None, measured__regret_mean=None), "check_why": "x"},
+     "regret_mean is mandatory"),
+    ({"check": edited(GEN_CLOSED, regret_max=None, measured__regret_max=None), "check_why": "x"},
+     "regret_max or regret_p99"),
+    ({"check": edited(GEN_CLOSED, seeds=[1, 2, 3, 4]), "check_why": "x"}, "seeds"),
+    ({"check": edited(GEN_CLOSED, seeds=[1, 1, 2, 3, 4]), "check_why": "x"}, "seeds"),
+    ({"check": edited(GEN_CLOSED, measured=None), "check_why": "x"}, "measured"),
+    ({"check": edited(GEN_CLOSED, measured__regret_max=None), "check_why": "x"}, "measured"),
+    ({"check": edited(GEN_CLOSED, regret_max=0.5), "check_why": "x"}, "looser than 10 times"),
+    ({"check": edited(GEN_CLOSED, regret_mean=0.0031), "check_why": "x"}, "looser than 10 times"),
+    ({"check": edited(ROUTED, argmax_share_min=0.6), "check_why": "x"}, "looser than 10 times"),
+    ({"check": edited(GEN_CLOSED, regret_mean=-0.1), "check_why": "x"}, "outside its range"),
+    ({"check": edited(GEN_CLOSED, regret_mean="0.001"), "check_why": "x"}, "numbers"),
+    # the backstop, whatever was measured
+    ({"check": edited(GEN_CLOSED, regret_mean=0.06, measured__regret_mean=0.02), "check_why": "x"},
+     "backstop 0.05"),
+    ({"check": edited(ROUTED, regret_p99=0.6, measured__regret_p99=0.2), "check_why": "x"},
+     "backstop 0.5"),
+    ({"check": edited(ROUTED, argmax_share_min=0.75, measured__argmax_share_min=0.9), "check_why": "x"},
+     "backstop 0.8"),
+])
+def test_a_block_that_states_too_little_or_too_much_is_refused(mix, message):
+    with pytest.raises(ValueError, match=message):
+        check.validate(mix)
+
+
+def test_the_loosest_block_the_backstop_admits():
+    block = edited(ROUTED, regret_p99=0.5, regret_mean=0.05, argmax_share_min=0.8,
+                   measured__regret_p99=0.05, measured__regret_mean=0.017,
+                   measured__argmax_share_min=0.9)
+    assert check.validate({"check": block, "check_why": "every expert held"}) == block
+    assert check.validate({"check": ROUTED, "check_why": "x"}) == ROUTED
+
+
+def test_stderr_lines_put_each_number_beside_its_limit():
+    numbers = check.judge(dense_like(), GEN_CLOSED)["numbers"]
+    lines = check.stderr_lines(numbers)
+    assert lines[0] == "check: tokens_checked 1200"
+    assert f"check: regret_max {numbers['regret_max']!r} limit 0.1" in lines
+    assert not any("_limit" in line.split()[1] for line in lines)
+
+
+# --------------------------------------------------------------------- #
+# a small routed decoder: why the statistics are what they are
+# --------------------------------------------------------------------- #
+
+H, LAYERS, S, V, EXPERTS, HELD, TOP, WIDTH, HEADS = 256, 4, 1024, 512, 256, 16, 8, 64, 4
+
+
+def routed_weights(seed):
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 8 * LAYERS + 2))
+
+    def normal(*shape):
+        w = jax.random.normal(next(keys), shape, jnp.float32) * shape[-2] ** -0.5
+        return w.astype(jnp.bfloat16)
+
+    layers = [
+        dict(qkv=normal(H, 3 * H), o=normal(H, H), router=normal(H, EXPERTS),
+             up=normal(HELD, H, 2 * WIDTH), down=normal(HELD, WIDTH, H),
+             shared_up=normal(H, 2 * WIDTH), shared_down=normal(WIDTH, H))
+        for _ in range(LAYERS)
+    ]
+    embed = jax.random.normal(next(keys), (V, H), jnp.float32).astype(jnp.bfloat16)
+    return dict(embed=embed, layers=layers, head=normal(H, V))
+
+
+def routed_forward(p, tokens, dtype, scale, normalise):
+    """Causal attention, then sigmoid scores over 256 experts, the top 8
+    normalised over the chosen and scaled by 2.5, of which this chip holds
+    16, plus a shared expert. bf16 weights; activations in ``dtype``."""
+    def mm(a, b, eq="sh,hw->sw"):
+        return jnp.einsum(eq, a, b.astype(dtype), preferred_element_type=jnp.float32).astype(dtype)
+
+    def norm(x):
+        x = x.astype(jnp.float32)
+        return (x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6)).astype(dtype)
+
+    def gated(h, up, down, eq_up="sh,hw->sw", eq_down="sw,wh->sh"):
+        gate, lin = jnp.split(mm(h, up, eq_up), 2, -1)
+        return mm(jax.nn.silu(gate.astype(jnp.float32)).astype(dtype) * lin, down, eq_down)
+
+    x = p["embed"][tokens].astype(dtype)
+    causal = jnp.tril(jnp.ones((S, S), bool))
+    for lp in p["layers"]:
+        q, k, v = (t.reshape(S, HEADS, H // HEADS) for t in jnp.split(mm(norm(x), lp["qkv"]), 3, -1))
+        scores = jnp.einsum("qhd,khd->hqk", q, k, preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(jnp.where(causal, scores * (H // HEADS) ** -0.5, -jnp.inf), -1)
+        a = jnp.einsum("hqk,khd->qhd", probs.astype(dtype), v, preferred_element_type=jnp.float32)
+        x = x + mm(a.astype(dtype).reshape(S, H), lp["o"])
+        h = norm(x)
+        top, chosen = jax.lax.top_k(jax.nn.sigmoid(mm(h, lp["router"]).astype(jnp.float32)), TOP)
+        if normalise:
+            top = top / top.sum(-1, keepdims=True)
+        weight = jnp.zeros((S, EXPERTS), jnp.float32).at[jnp.arange(S)[:, None], chosen].set(top * scale)
+        held = gated(h, lp["up"], lp["down"], "sh,ehw->esw", "esw,ewh->esh")      # (HELD, S, H)
+        routed = jnp.einsum("esh,se->sh", held, weight[:, :HELD].astype(dtype),
+                            preferred_element_type=jnp.float32).astype(dtype)
+        x = x + routed + gated(h, lp["shared_up"], lp["shared_down"])
+    return mm(norm(x), p["head"]).astype(jnp.float32)
+
+
+FORWARD = jax.jit(routed_forward, static_argnums=(2, 3, 4))
+
+
+@pytest.mark.parametrize("seed", [2, 4, 7])
+@pytest.mark.parametrize("program,passes", [
+    ("honest", True), ("the 2.5 scale dropped", False), ("the chosen eight not normalised", False),
+])
+def test_a_routed_decoder_in_bf16_against_float32(program, passes, seed):
+    """The served token is the bf16 pass's choice; the regret is read off
+    the float32 pass over the same weights. A flipped near-tie between the
+    8th and 9th score moves single positions by tenths of a standard
+    deviation: honest maxima 0.035-0.64 over seeds 1-8 (0.16, 0.39, 0.64 on
+    the three here: over the dense cell's limit, and the last no different
+    from the dropped scale's 0.63-1.1), while the 99th percentile (honest at
+    most 0.039, dropped scale at least 0.36) and the mean (0.0022 | 0.023)
+    separate honest from wrong by a factor of ten."""
+    p = routed_weights(seed)
+    tokens = jax.random.randint(jax.random.PRNGKey(seed + 100), (S,), 0, V)
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(FORWARD(p, tokens, jnp.float32, 2.5, True), np.float64)
+    scale = 1.0 if program == "the 2.5 scale dropped" else 2.5
+    served = np.asarray(
+        FORWARD(p, tokens, jnp.bfloat16, scale, program != "the chosen eight not normalised")
+    ).argmax(-1)
+    regrets = (ref.max(-1) - ref[np.arange(S), served]) / ref.std(-1)
+    got = check.judge(regrets, check.validate({"check": ROUTED, "check_why": "the toy's own"}))
+    assert got["ok"] is passes, got
+    n = got["numbers"]
+    if passes:
+        # the maximum alone would have failed it under the dense cell's block
+        assert n["regret_max"] > 0.1 and not check.judge(regrets, GEN_CLOSED)["ok"]
+        assert all(n[k] <= ROUTED["measured"][k] for k in ("regret_p99", "regret_mean"))
+    else:
+        assert n["regret_p99"] > 2 * ROUTED["regret_p99"] and n["regret_mean"] > 2 * ROUTED["regret_mean"]
+    if program == "the chosen eight not normalised":
+        # wrong throughout: what the backstop alone is there to catch
+        assert n["regret_mean"] > 10 * check.BACKSTOP["regret_mean"]
+        assert n["argmax_share"] < check.BACKSTOP["argmax_share_min"] / 2

@@ -110,6 +110,44 @@ def test_every_named_file_exists_and_names_a_plugin(manifest):
         assert 1 <= len(m["layer"]) <= 200 and "\n" not in m["layer"]
 
 
+def test_every_serving_mix_states_the_limits_of_its_check(manifest):
+    """A traffic file whose runner serves a model carries a valid ``check``
+    and the reason for it, whether a cell runs it yet or not; a training
+    mix carries its own two limits."""
+    from benchmark import check
+
+    served = 0
+    for path in sorted((ROOT / "benchmark" / "traffic").glob("*.json")):
+        mix = json.loads(path.read_text())
+        assert len(mix["check_why"]) > 40, path.name
+        if mix["runner"].startswith("serve_"):
+            served += 1
+            assert check.validate(mix) == mix["check"], path.name
+        else:
+            assert set(mix["check"]) == {"nll_err_max", "nll_err_mean"}, path.name
+    assert served >= 2
+    # the accepted serving cell: the same two statistics of the same three
+    # requests, its limits set anew from this PR's readings (PERF.md section 4)
+    block = manifest.traffic("gen-closed")["check"]
+    assert (block["regret_max"], block["regret_mean"], block["requests"]) == (0.2, 0.004, 3)
+    assert "regret_p99" not in block and "argmax_share_min" not in block
+    assert manifest.traffic("docqa-open")["check"] == block
+
+
+def test_serve_model_mfu_is_the_serving_cells_share_of_the_whole_step(manifest):
+    (entry,) = [m for m in manifest.doc["per_layer"] if m["name"] == "serve_model_mfu"]
+    assert entry == {"name": "serve_model_mfu", "unit": "%", "better": "higher",
+                     "source": "program_counter", "layer": "model",
+                     "moves": "output_tokens_per_s", "workloads": ["mistral-7b_gen-closed"]}
+    spec = manifest.layer_metric("serve_model_mfu")
+    assert spec["reader"] == "ratio" and "context.forward_flops_per_token" in spec["num"]
+    # every cell that serves a model has a share of the whole step beside it
+    for w in manifest.doc["workloads"]:
+        runner = manifest.traffic(w["traffic"])["runner"]
+        names = {m["name"].split(".")[0] for m in manifest.metrics_of(w["name"], "per_layer")}
+        assert ("serve_model_mfu" if runner.startswith("serve_") else "model_mfu") in names, w["name"]
+
+
 def test_a_pr_shaped_addition_needs_no_edit(tmp_path):
     """One config file, one traffic file, one layer-metric file and one
     entry each: the harness finds them by name."""

@@ -72,3 +72,45 @@ def test_attention_cost_and_roofline(manifest):
 def test_an_unknown_device_is_an_error_not_a_default():
     with pytest.raises(RuntimeError, match="no published peaks"):
         device_peaks("cpu")
+
+
+@pytest.mark.parametrize("family,config,seq,batch", [
+    ("bert", "bert-base", 512, 32), ("bert", "bert-base", 128, 8),
+    ("mistral", "mistral-7b-l12-x4", 4096, 4), ("mistral", "mistral-7b-l16", 8192, 1),
+])
+def test_the_family_modules_return_what_the_literal_dict_did(manifest, family, config, seq, batch):
+    """``opcount`` asks the family module (``forward_flops_per_sequence``,
+    ``attention_pairs``); before, it looked the family up in a dict and an
+    ``if``. A new family adds a file and edits none."""
+    cfg = manifest.config(config)
+    forward = {"bert": opcount.bert_forward_flops_per_sequence,
+               "mistral": opcount.decoder_forward_flops_per_sequence}[family]
+    assert opcount.train_flops_per_token(family, cfg, seq) == 3.0 * forward(cfg, seq) / seq
+    pairs = seq * seq if family == "bert" else opcount.causal_pairs(seq, cfg["sliding_window"])
+    heads = cfg["num_attention_heads"]
+    d = cfg["hidden_size"] // heads
+    cost = opcount.attention_train_cost(family, cfg, seq, batch)
+    assert cost["flops"] == batch * cfg["num_hidden_layers"] * 3.5 * (4.0 * pairs * d * heads)
+    assert cost["bytes"] == batch * cfg["num_hidden_layers"] * 2.0 * 12 * seq * heads * d
+
+
+def test_serving_counts_one_token_at_the_mixs_mean_context(manifest):
+    """``forward_flops_per_token``: the matrix products of one token once,
+    and attention against the mean number of keys a token of the mix sees."""
+    from benchmark.families import mistral
+
+    cfg = manifest.config("mistral-7b-l16")
+    layer = 4096 * 4096 * 2 + 4096 * 1024 * 2 + 3 * 4096 * 14336
+    products = 2 * (16 * layer + 4096 * 32000)
+    assert opcount.decoder_forward_flops_per_token(cfg, 1) == products + 4 * 16 * 32 * 128
+    assert opcount.decoder_forward_flops_per_token(cfg, 300.5) == products + 4 * 16 * 32 * 128 * 300.5
+    # one prompt length, one output length: 100 + 50 - 1 tokens are fed, the
+    # i-th against i keys, and the window never bites
+    fixed = lambda n: {"dist": "lognormal", "median": n, "sigma": 0.0, "min": n, "max": n}
+    got = mistral.serve_context(cfg, {"prompt_tokens": fixed(100), "output_tokens": fixed(50)}, None)
+    assert got["mean_context_tokens"] == pytest.approx(150 / 2)
+    assert got["forward_flops_per_token"] == opcount.decoder_forward_flops_per_token(cfg, 75.0)
+    # the cell's own mix: a constant of the mix, whatever the seed
+    cell = mistral.serve_context(cfg, manifest.traffic("gen-closed"), cfg["serve"])
+    assert 250 < cell["mean_context_tokens"] < 320
+    assert cell["forward_flops_per_token"] == pytest.approx(7.316e9, rel=1e-3)

@@ -10,9 +10,10 @@ import time
 import jax
 import pytest
 
+from benchmark.check import stderr_lines
 from benchmark.manifest import ROOT, Manifest, plugin
 from benchmark.run import collect_metrics, result_line
-from benchmark.runners import RunContext, serve_common
+from benchmark.runners import RunContext, serve_common, train_fit
 
 #: the peaks table wants a known kind; no device number is read from it here
 FAKE_DEVICE = {"platform": "cpu", "kind": "TPU v5 lite", "count": 1}
@@ -73,8 +74,15 @@ def test_train_fit_bert(manifest):
     # no device trace was taken: its readers found nothing and are left out
     assert "device_idle_share" not in layers and "attn_kernel_time_share" not in layers
     line = result_line(manifest, ev, FAKE_DEVICE, traced=False)
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics", "device", "check"]
     assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
+    # what decided ``correct``, each number beside its limit, comes last
+    limits = dict(mix["check"], loss_rel_err=train_fit.LOSS_RTOL)
+    for name in ("nll_err_max", "nll_err_mean", "loss_rel_err"):
+        assert line["check"][name] == check[name] <= line["check"][f"{name}_limit"] == limits[name]
+    assert line["check"]["positions"] == 32 * chips
+    assert line["check"]["compiles_in_window"] == line["check"]["losses_not_finite"] == 0
+    assert all(isinstance(v, (int, float)) for v in line["check"].values())
     json.dumps(line)
 
 
@@ -117,8 +125,6 @@ def test_the_training_check_fails_wrong_mathematics(manifest, family, fault):
     from kubeflow_tpu.core.mesh import MeshSpec, build_mesh
     from kubeflow_tpu.models.transformer import TransformerLM
     from kubeflow_tpu.train.loop import BATCH_SPEC
-
-    from benchmark.runners import train_fit
 
     name, mixname = {"mistral": ("mistral-7b-l12-x4", "pretrain-x4"),
                      "bert": ("bert-base", "mlm-s512")}[family]
@@ -169,9 +175,17 @@ def test_serve_closed(manifest):
     )
     ev = run(context(manifest, cell, serve_config(manifest), mix, seconds=1.5))
     assert ev.correct and ev.failed == 0 and ev.attempted > 10
-    assert ev.notes["check"]["ok"] and ev.notes["check"]["tokens_checked"] >= 24
+    assert ev.notes["check"]["ok"] and ev.check["tokens_checked"] >= 24
+    assert len(ev.notes["check"]["requests"]) == mix["check"]["requests"] == 3
     # float32 on both sides: the engine's token is the reference's choice
-    assert ev.notes["check"]["regret_max"] <= 1e-3
+    assert ev.check["regret_max"] <= 1e-3
+    # the last line carries what decided ``correct``, each beside its limit
+    line = result_line(manifest, ev, FAKE_DEVICE, traced=False)
+    assert list(line)[-1] == "check" and line["check"] == ev.check
+    assert {k: v for k, v in ev.check.items() if k.endswith("_limit")} == {
+        "regret_max_limit": 0.2, "regret_mean_limit": 0.004, "compiles_in_window_limit": 0}
+    assert {"regret_p99", "argmax_share", "compiles_in_window"} <= set(ev.check)
+    assert "check: regret_mean " in "\n".join(stderr_lines(ev.check))
     assert ev.numbers["xla.compiles_in_window"] == 0
     assert ev.numbers["engine.chunks"] > 0 and ev.numbers["client.output_tokens"] > 0
     got = collect_metrics(manifest, ev, traced=False)
@@ -181,6 +195,15 @@ def test_serve_closed(manifest):
         ev.numbers["client.output_tokens"] / 1.5)
     layers = collect_metrics(manifest, ev, traced=True)
     assert 0 < layers["engine_batch_occupancy"]["value"] <= 100
+    # the whole step's share: tokens the engine computed x the family's count
+    n = ev.numbers
+    assert n["serve.forward_tokens"] == (
+        n["engine.prefill_tokens"] + n["client.output_tokens"] - n["client.first_tokens"]) > 0
+    assert n["context.forward_flops_per_token"] == plugin("families", "mistral").serve_context(
+        serve_config(manifest), mix, None)["forward_flops_per_token"]
+    assert 0 < layers["serve_model_mfu"]["value"] <= 100
+    assert layers["serve_model_mfu"]["value"] == pytest.approx(
+        100 * n["serve.forward_tokens"] * n["context.forward_flops_per_token"] / (1.5 * 197e12))
     assert layers["closed_ttft_ms_p50"]["value"] > 0 and layers["closed_tpot_ms_p90"]["value"] > 0
     # nothing was traced in this run: span readers found nothing
     assert "engine_decode_step_ms" not in layers and "engine_prefill_ms_p50" not in layers
@@ -203,6 +226,7 @@ def test_serve_open_traced(manifest):
     assert ev.correct and ev.failed == 0 and ev.attempted > 10
     # the longest request is checked, and it is longer than the window of 48
     assert max(ev.notes["check"]["lengths"]) > 48
+    assert ev.check["regret_max_limit"] == 0.2 and ev.check["argmax_share"] > 0.9
     assert all(s.trace_id for s in ev.samples) and len(ev.traces) >= ev.attempted
     assert all(s.ttft_s >= s.ttft_from_send_s for s in ev.samples)
     n = ev.numbers
@@ -215,6 +239,166 @@ def test_serve_open_traced(manifest):
     assert span.read({"span": "decode.chunk", "per_number": "context.chunk_steps"}, ev) > 0
     assert span.read({"span": "decode.chunk", "per_number": "context.nope"}, ev) is None
     assert span.read({"span": "no.such.span"}, ev) is None
+
+
+def scratch_family(monkeypatch, name="scratchfam", **members):
+    """A family module a later PR would add as ``benchmark/families/<name>.py``:
+    here an object in ``sys.modules``, which is where ``plugin`` finds one.
+    Everything it does not define is ``families/mistral.py``'s."""
+    import types
+
+    from benchmark.families import mistral
+
+    module = types.ModuleType(f"benchmark.families.{name}")
+    module.__dict__.update({k: v for k, v in vars(mistral).items() if not k.startswith("__")})
+    module.__dict__.update(members)
+    monkeypatch.setitem(sys.modules, module.__name__, module)
+    return name
+
+
+LONG_MIX = dict(
+    clients=6, ramp_s=0.5, max_requests_per_s=400,
+    prompt_tokens={"dist": "lognormal", "median": 24, "sigma": 0.6, "min": 8, "max": 64},
+    output_tokens={"dist": "lognormal", "median": 40, "sigma": 0.3, "min": 32, "max": 64},
+)
+
+
+def test_a_scratch_family_and_a_p99_block_need_no_edit(manifest, monkeypatch):
+    """What the next ``model_config`` PR brings by files alone: a family
+    with a three-line ``serve_context`` (its counts reach the readers as
+    ``context.<key>``) and a traffic file whose ``check`` holds a 99th
+    percentile, through ``serve_closed`` as it stands."""
+    def serve_context(cfg, mix, serve):
+        return {"forward_flops_per_token": 2.0 * 12 * cfg["hidden_size"] ** 2,
+                "latent_read_flops": 3.0e6, "latent_read_bytes": serve["page_size"] * 576.0}
+
+    before = {p: p.read_bytes() for p in (ROOT / "benchmark").rglob("*.py")}
+    cfg = dict(serve_config(manifest), family=scratch_family(monkeypatch, serve_context=serve_context))
+    cfg["serve"] = dict(cfg["serve"], max_new_tokens=64)
+    mix = dict(
+        manifest.traffic("gen-closed"), **LONG_MIX,
+        check={"requests": 40, "regret_p99": 0.01, "regret_mean": 0.001, "argmax_share_min": 0.95,
+               "measured": {"regret_p99": 0.001, "regret_mean": 0.0001, "argmax_share_min": 0.995},
+               "seeds": [3, 5, 7, 11, 13]},
+        check_why="float32 on both sides on the CPU: every served token is the reference's choice",
+    )
+    ev = run(context(manifest, manifest.cell("mistral-7b_gen-closed"), cfg, mix, seconds=3.0))
+    assert ev.correct, ev.notes["check"]
+    assert ev.check["tokens_checked"] >= ev.check["tokens_checked_limit"] == 1000
+    assert ev.check["regret_p99"] <= ev.check["regret_p99_limit"] == 0.01
+    assert ev.check["argmax_share"] >= ev.check["argmax_share_limit"] == 0.95
+    assert "regret_max_limit" not in ev.check and ev.check["regret_max"] <= 1e-3
+    n = ev.numbers
+    assert n["context.forward_flops_per_token"] == 2.0 * 12 * 64 ** 2
+    assert n["context.latent_read_flops"] == 3.0e6 and n["context.latent_read_bytes"] == 16 * 576.0
+    assert 0 < collect_metrics(manifest, ev, traced=True)["serve_model_mfu"]["value"] <= 100
+    # the same run with fewer requests checked: the 99th percentile of fewer
+    # than a thousand tokens is no reading, so the run is not correct
+    few = serve_common.check_outputs(
+        context(manifest, manifest.cell("mistral-7b_gen-closed"), cfg, mix, seconds=3.0),
+        dict(mix["check"], requests=3), None, [], [])
+    assert not few["ok"]
+    assert {p: p.read_bytes() for p in before} == before, "an existing file was edited"
+
+
+@pytest.mark.parametrize("fault", ["no window", "every token one id off"])
+def test_a_broken_serving_path_is_not_correct(manifest, monkeypatch, fault):
+    """The rest of a run with the timed path broken underneath — the model
+    the engine serves has lost its sliding window, or every token is the id
+    after the one computed, altered where the engine's programs choose it — ends with ``correct`` false and the
+    numbers that say by how much on the result line."""
+    from kubeflow_tpu.models.transformer import TransformerLM
+    from kubeflow_tpu.serve import engine
+
+    from benchmark.families import mistral
+
+    cfg = serve_config(manifest)
+    if fault == "no window":
+        def serve_model(cfg):
+            pc = mistral.program_config(cfg, attn_window=None)
+            return TransformerLM(pc), pc
+
+        cfg["family"] = scratch_family(monkeypatch, serve_model=serve_model)
+    else:
+        pick = engine._sample     # where the engine's programs choose a token
+        monkeypatch.setattr(engine, "_sample", lambda *a, **kw: (pick(*a, **kw) + 1) % 512)
+    cfg["serve"] = dict(cfg["serve"], max_new_tokens=64)
+    # outputs of 32 and more on prompts of 24: well past the window of 48
+    mix = dict(manifest.traffic("gen-closed"), **LONG_MIX)
+    ev = run(context(manifest, manifest.cell("mistral-7b_gen-closed"), cfg, mix, seconds=2.0))
+    assert ev.failed == 0 and ev.attempted > 3 and not ev.correct
+    line = result_line(manifest, ev, FAKE_DEVICE, traced=False)
+    assert line["correct"] is False and list(line)[-1] == "check"
+    assert line["check"]["regret_max"] > 1.0 > line["check"]["regret_max_limit"]
+    assert line["check"]["regret_mean"] > 10 * line["check"]["regret_mean_limit"]
+    assert any(f.startswith("regret_max") for f in ev.notes["check"]["failed"])
+
+
+def test_the_control_in_the_precision_below_is_not_correct(manifest):
+    """``benchmark/control.py`` at a size a test holds: the configuration
+    here states float32, so the control is the reference with bfloat16
+    weights, rated at the served positions by the float32 reference. A
+    block set from what float32 reads must fail it, while the program
+    passes in the same run."""
+    from benchmark import control
+
+    cfg = serve_config(manifest)
+    cfg["serve"] = dict(cfg["serve"], max_new_tokens=64)
+    mix = dict(
+        manifest.traffic("gen-closed"), **LONG_MIX,
+        check={"requests": 12, "regret_max": 0.001, "regret_mean": 0.0001,
+               "measured": {"regret_max": 0.0001, "regret_mean": 0.00001}, "seeds": [3, 5, 7, 11, 13]},
+        check_why="float32 on both sides on the CPU: every served token is the reference's choice",
+    )
+    ctx = context(manifest, manifest.cell("mistral-7b_gen-closed"), cfg, mix, seconds=2.0)
+    ev = serve_common.run(ctx, "closed", control=lambda p: control.Lowered(p, "float32"))
+    check = ev.notes["check"]
+    assert ev.correct and check["ok"] and check["numbers"]["regret_max"] <= 1e-4
+    assert not check["control"]["ok"], check["control"]
+    assert check["control"]["numbers"]["tokens_checked"] == check["numbers"]["tokens_checked"] > 400
+    assert check["control"]["numbers"]["regret_max"] > 3 * mix["check"]["regret_max"]
+    # the benchmark's own runs never run it
+    assert "control" not in run(ctx).notes["check"]
+
+
+def test_the_control_quantises_each_output_channel_and_leaves_vectors():
+    import jax.numpy as jnp
+
+    from benchmark import control
+
+    rng = jax.random.PRNGKey(0)
+    tree = {"embed": {"embedding": jax.random.normal(rng, (40, 16), jnp.bfloat16)},
+            "layers_0": {"proj": {"kernel": jax.random.normal(rng, (16, 24), jnp.bfloat16)},
+                         "ln": {"scale": jnp.full((16,), 1.5, jnp.bfloat16)}}}
+    low = control.Lowered(tree, "bfloat16")
+    assert set(low) == set(tree) and len(low) == 2
+    k, w = low["layers_0"]["proj"]["kernel"], tree["layers_0"]["proj"]["kernel"].astype(jnp.float32)
+    scale = jnp.abs(w).max(axis=0) / 127
+    assert k.dtype == jnp.float32 and float(jnp.abs(k - w).max()) <= float(scale.max()) / 2 + 1e-6
+    assert int(jnp.unique(jnp.round(k[:, 0] / scale[0])).size) <= 255
+    assert not bool(jnp.array_equal(k, w))
+    e, v = low["embed"]["embedding"], tree["embed"]["embedding"].astype(jnp.float32)
+    assert float(jnp.abs(e - v).max(axis=1)[3]) <= float(jnp.abs(v[3]).max()) / 254 + 1e-6
+    assert bool(jnp.array_equal(low["layers_0"]["ln"]["scale"], tree["layers_0"]["ln"]["scale"]))
+    half = control.Lowered({"a": {"kernel": jnp.full((4, 4), 1.001, jnp.float32)}}, "float32")
+    assert float(half["a"]["kernel"][0, 0]) == 1.0
+    with pytest.raises(ValueError, match="no precision below"):
+        control.Lowered({"a": {"kernel": jnp.ones((4, 4))}}, "int8")["a"]
+
+
+@pytest.mark.parametrize("why,edit", [
+    ("missing", lambda mix: mix.pop("check")),
+    ("check_why", lambda mix: mix.pop("check_why")),
+    ("unknown key", lambda mix: mix["check"].update(regret_p50=0.1)),
+])
+def test_a_mix_without_a_valid_check_sends_no_request(manifest, why, edit):
+    mix = json.loads(json.dumps(manifest.traffic("gen-closed")))
+    edit(mix)
+    ctx = context(manifest, manifest.cell("mistral-7b_gen-closed"), serve_config(manifest), mix, seconds=1.0)
+    t0 = time.perf_counter()
+    with pytest.raises(ValueError, match=why):
+        run(ctx)
+    assert time.perf_counter() - t0 < 1.0     # no model was built
 
 
 def test_warm_plan_reaches_every_program_of_the_mix(manifest):

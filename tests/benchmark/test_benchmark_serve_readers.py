@@ -115,8 +115,11 @@ def test_counter_share_reads_nothing_from_a_zero_denominator_or_a_missing_counte
 
 def test_the_six_new_entries_read_beside_the_old_ones(manifest, reduction):
     entries = {m["name"]: m for m in manifest.doc["per_layer"]}
-    # appended, in this order, after everything PR 22 had
-    assert tuple(m["name"] for m in manifest.doc["per_layer"][-6:]) == NEW
+    # appended, in this order, after everything PR 22 had (PR 28 appended
+    # ``serve_model_mfu`` after them)
+    names = [m["name"] for m in manifest.doc["per_layer"]]
+    first = names.index(NEW[0])
+    assert first == 18 and tuple(names[first:first + 6]) == NEW
     for name in NEW:
         assert entries[name]["layer"] == "engine"
         assert entries[name]["moves"] == "output_tokens_per_s"
