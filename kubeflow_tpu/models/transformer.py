@@ -267,9 +267,15 @@ class Attention(nn.Module):
         new_cache = None
         if page_table is not None:
             # PAGED decode/prefill (serve/paging.py): the cache is one flat
-            # token axis per layer — (Hkv, pool_tokens, D) — and row b's
-            # logical token j lives at table[b, j//P]*P + j%P. Because a
-            # row's token space is CONTIGUOUS (no quantized gen gap), the
+            # token axis per layer, TOKEN-MAJOR — (pool_tokens, Hkv, D) —
+            # and row b's logical token j lives at index
+            # table[b, j//P]*P + j%P of axis 0. The scatter and the gather
+            # below index that axis, and the TPU compiler wants an indexed
+            # axis outermost: stored (Hkv, pool_tokens, D) it re-laid every
+            # layer's K and V out on entry to and exit from each program
+            # (64 pool-sized copies per program at 16 layers; none now —
+            # tests/test_tpu_compile.py counts them). Because a row's
+            # token space is CONTIGUOUS (no quantized gen gap), the
             # causal + sliding-window mask is just arithmetic on positions;
             # no kv_mask operand exists in this mode.
             P = page_size
@@ -293,11 +299,11 @@ class Attention(nn.Module):
                 # ops/paged_attention.py for why NOT per-page scales)
                 kq, ks = quantize_kv(k)                # codes (B,Hkv,S,D)
                 vq, vs = quantize_kv(v)                # scales (B,Hkv,S)
-                K = layer_cache["k"].at[:, idx, :].set(
-                    kq.transpose(1, 0, 2, 3).reshape(Hkv, B * S, D)
+                K = layer_cache["k"].at[idx].set(
+                    kq.transpose(0, 2, 1, 3).reshape(B * S, Hkv, D)
                 )
-                V = layer_cache["v"].at[:, idx, :].set(
-                    vq.transpose(1, 0, 2, 3).reshape(Hkv, B * S, D)
+                V = layer_cache["v"].at[idx].set(
+                    vq.transpose(0, 2, 1, 3).reshape(B * S, Hkv, D)
                 )
                 Ks = layer_cache["k_scale"].at[:, idx].set(
                     ks.transpose(1, 0, 2).reshape(Hkv, B * S)
@@ -317,13 +323,13 @@ class Attention(nn.Module):
                 den = jnp.sum(jnp.abs(kf)) + jnp.sum(jnp.abs(vf))
                 self.sow("quant_stats", "kv_quant_err", jnp.stack([err, den]))
             elif kv_quant == "none":
-                K = layer_cache["k"].at[:, idx, :].set(
+                K = layer_cache["k"].at[idx].set(
                     k.astype(layer_cache["k"].dtype)
-                    .transpose(1, 0, 2, 3).reshape(Hkv, B * S, D)
+                    .transpose(0, 2, 1, 3).reshape(B * S, Hkv, D)
                 )
-                V = layer_cache["v"].at[:, idx, :].set(
+                V = layer_cache["v"].at[idx].set(
                     v.astype(layer_cache["v"].dtype)
-                    .transpose(1, 0, 2, 3).reshape(Hkv, B * S, D)
+                    .transpose(0, 2, 1, 3).reshape(B * S, Hkv, D)
                 )
                 new_cache = {"k": K, "v": V}
             else:
@@ -353,8 +359,8 @@ class Attention(nn.Module):
                 flat_r = (
                     page_table[:, j // P] * P + (j % P)[None, :]
                 ).reshape(-1)                                      # (B*W,)
-                Kg = K[:, flat_r, :].reshape(Hkv, B, W, D).transpose(1, 0, 2, 3)
-                Vg = V[:, flat_r, :].reshape(Hkv, B, W, D).transpose(1, 0, 2, 3)
+                Kg = K[flat_r].reshape(B, W, Hkv, D).transpose(0, 2, 1, 3)
+                Vg = V[flat_r].reshape(B, W, Hkv, D).transpose(0, 2, 1, 3)
                 if kv_quant == "int8":
                     # dequantize with the SAME broadcast multiply the
                     # kernel uses, so gather/kernel parity holds
@@ -710,22 +716,33 @@ def init_paged_kv_cache(
     dtype: Any | None = None,
     kv_quant: str = "none",
 ) -> dict:
-    """Zeroed PAGED decode cache: one flat (kv_heads, pool_tokens,
-    head_dim) K and V per layer, shared by every row through a block table
-    (serve/paging.py). HBM is billed per resident TOKEN, not per
-    (row × max_seq) rectangle. ``kv_quant="int8"`` stores int8 codes plus
+    """Zeroed PAGED decode cache: one flat, token-major (pool_tokens,
+    kv_heads, head_dim) K and V per layer, shared by every row through a
+    block table (serve/paging.py). HBM is billed per resident TOKEN, not
+    per (row × max_seq) rectangle. The token axis is first because every
+    program scatters and gathers along it and the TPU compiler keeps an
+    indexed axis outermost: in this order the array's default layout is
+    the one the programs compute in, so the donated pool passes through
+    them with no relayout (heads first, each program copied every layer's
+    K and V in and out). ``kv_quant="int8"`` stores int8 codes plus
     per-(kv_head, token) f32 ``k_scale``/``v_scale`` side arrays — the
     pool arrays themselves cost a quarter of f32 (half of bf16), scales
-    add ~1/head_dim on top."""
+    add ~1/head_dim on top. The scale planes stay (kv_heads,
+    pool_tokens): rehearsed in both orders, the compiler keeps an
+    8-wide f32 plane heads-major at a program's boundary and token-major
+    (padded to 128 lanes) inside it either way, so they are still
+    re-laid out per call (PERF.md section 7) and the order that the
+    stored prefix entries and the kernel's view use was kept."""
     dtype = dtype or cfg.dtype
-    shape = (cfg.kv_heads, pool_tokens, cfg.head_dim)
+    shape = (pool_tokens, cfg.kv_heads, cfg.head_dim)
+    scale_shape = (cfg.kv_heads, pool_tokens)
     if kv_quant == "int8":
         return {
             f"layers_{i}": {
                 "k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(shape[:2], jnp.float32),
-                "v_scale": jnp.zeros(shape[:2], jnp.float32),
+                "k_scale": jnp.zeros(scale_shape, jnp.float32),
+                "v_scale": jnp.zeros(scale_shape, jnp.float32),
             }
             for i in range(cfg.n_layers)
         }

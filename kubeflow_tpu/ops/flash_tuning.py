@@ -348,10 +348,10 @@ def sweep_paged_pages(
             kq, (batch, heads, span, head_dim), jnp.bfloat16
         )
         k_pool = jax.random.normal(
-            kk, (kv_heads, pool_tokens, head_dim), jnp.bfloat16
+            kk, (pool_tokens, kv_heads, head_dim), jnp.bfloat16
         )
         v_pool = jax.random.normal(
-            kv, (kv_heads, pool_tokens, head_dim), jnp.bfloat16
+            kv, (pool_tokens, kv_heads, head_dim), jnp.bfloat16
         )
         table_np = (
             1 + np.arange(batch * w, dtype=np.int32).reshape(batch, w)

@@ -1,18 +1,25 @@
 """Pallas paged decode-attention: the vLLM PagedAttention analog, TPU-form.
 
 The engine's paged KV pool (`serve/paging.py`) stores each layer's keys
-and values as ONE flat token axis — ``(kv_heads, pool_tokens, head_dim)``
-— and a row's logical token ``j`` lives at flat slot
-``table[row, j // P] * P + j % P``.  The in-graph read path gathers the
-row's whole pow2-bucketed window back into a dense ``(B, H, W, D)``
-tensor and runs masked softmax attention on it (XLA gather; see
-`models/transformer.py`).  This module is the kernel form of that read:
-the block table rides the grid as a **scalar-prefetch operand**, so each
-kv grid step's BlockSpec index map picks the page to stage —
+and values as ONE flat token axis, token-major —
+``(pool_tokens, kv_heads, head_dim)`` — and a row's logical token ``j``
+lives at flat slot ``table[row, j // P] * P + j % P`` of axis 0.  (Token-
+major because the engine's scatters and gathers index that axis and the
+TPU compiler keeps an indexed axis outermost: stored heads-first, every
+program re-laid the whole pool out on entry and exit —
+`models/transformer.py::init_paged_kv_cache`.)  The in-graph read path
+gathers the row's whole pow2-bucketed window back into a dense
+``(B, H, W, D)`` tensor and runs masked softmax attention on it (XLA
+gather; see `models/transformer.py`).  This module is the kernel form of
+that read: the block table rides the grid as a **scalar-prefetch
+operand**, so each kv grid step's BlockSpec index map picks the page to
+stage —
 
-    ``lambda b, h, i, tbl, pos0: (h, tbl[b, i], 0)``
+    ``lambda b, i, tbl, pos0: (tbl[b, i], 0, 0)``
 
-— and the pallas_call pipeline itself performs the HBM→VMEM page fetch
+— a ``(P, kv_heads, D)`` block: one page of ALL kv heads is one
+contiguous piece of the pool, fetched in one grid step — and the
+pallas_call pipeline itself performs the HBM→VMEM page fetch
 (double-buffered against compute), fused with online-softmax attention
 over the staged page.  One kv block == one pool page, which is why the
 sweepable "block size" for this kernel IS the engine's ``page_size``
@@ -25,9 +32,9 @@ mask that `serve/generate.py:decode_span_kv_mask` builds for the dense
 path falls out of pure position arithmetic inside the tile mask here
 (key position ``i*P + lane`` is visible to query s iff it is ``<=
 pos0 + s`` and inside the sliding window), so speculative verify needs
-no separate program.  GQA: the kv-head grid axis stages each kv head's
-page once and all ``H // kv_heads`` query heads in the group attend to
-it in-tile.
+no separate program.  GQA: a grid step holds the page for every kv head
+and loops over them; all ``H // kv_heads`` query heads of a group attend
+to their kv head's slice in-tile.
 
 int8 KV: ``quantize_kv`` produces per-token-per-head symmetric int8
 codes plus an f32 scale per (kv_head, token) vector; the kernel
@@ -93,16 +100,16 @@ def _paged_attn_kernel(
     tbl_ref,    # (B, W) int32 page table
     pos0_ref,   # (B,) int32 span start positions
     # VMEM blocks
-    q_ref,      # (1, 1, G*S, D) — queries, GQA group folded into the span axis
-    k_ref,      # (1, P, D) — the page picked by the index map
-    v_ref,      # (1, P, D)
-    ks_ref,     # (1, 1, 1, P) f32 or None
-    vs_ref,     # (1, 1, 1, P) f32 or None
-    o_ref,      # (1, 1, G*S, D)
+    q_ref,      # (1, Hkv, G*S, D) — queries, GQA group folded into the span axis
+    k_ref,      # (P, Hkv, D) — the page picked by the index map, every kv head
+    v_ref,      # (P, Hkv, D)
+    ks_ref,     # (Hkv, 1, 1, P) f32 or None
+    vs_ref,     # (Hkv, 1, 1, P) f32 or None
+    o_ref,      # (1, Hkv, G*S, D)
     # VMEM scratch
-    acc_ref,    # (G*S, D) f32
-    m_ref,      # (G*S, 1) f32
-    l_ref,      # (G*S, 1) f32
+    acc_ref,    # (Hkv, G*S, D) f32
+    m_ref,      # (Hkv, G*S, 1) f32
+    l_ref,      # (Hkv, G*S, 1) f32
     *,
     scale: float | None,
     window: int | None,
@@ -112,7 +119,7 @@ def _paged_attn_kernel(
     num_pages: int,
 ):
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
     P, G, S = page_size, groups, span
     GS = G * S
 
@@ -134,20 +141,10 @@ def _paged_attn_kernel(
     @pl.when(run)
     def _body():
         d = q_ref.shape[-1]
-        q = q_ref[0, 0].astype(jnp.float32)  # (GS, D)
-        k = k_ref[0].astype(jnp.float32)  # (P, D)
-        v = v_ref[0].astype(jnp.float32)
-        if ks_ref is not None:
-            k = k * ks_ref[0, 0].T
-            v = v * vs_ref[0, 0].T
         if scale is None:
             mult = 1.0 / jnp.sqrt(jnp.float32(d))  # gather-path spelling
         else:
             mult = jnp.float32(scale)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * mult  # (GS, P)
         # absolute positions: row r of the GS axis is query s = r % S at
         # position pos0 + s; lane j is key position first + j
         kpos = first + jax.lax.broadcasted_iota(jnp.int32, (GS, P), 1)
@@ -155,24 +152,36 @@ def _paged_attn_kernel(
         mask = kpos <= qpos
         if window is not None:
             mask &= kpos > qpos - window
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # masked lanes contribute EXACTLY 0 even when the whole tile is
-        # masked (exp(s - m_cur) would be exp(0)=1 garbage at m==NEG_INF)
-        p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
-        alpha = jnp.exp(m_prev - m_cur)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_cur
+        for h in range(q_ref.shape[1]):  # static: one kv head at a time
+            q = q_ref[0, h].astype(jnp.float32)  # (GS, D)
+            k = k_ref[:, h, :].astype(jnp.float32)  # (P, D)
+            v = v_ref[:, h, :].astype(jnp.float32)
+            if ks_ref is not None:
+                k = k * ks_ref[h, 0].T
+                v = v * vs_ref[h, 0].T
+            s = jax.lax.dot_general(
+                q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * mult  # (GS, P)
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[h]
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # masked lanes contribute EXACTLY 0 even when the whole tile
+            # is masked (exp(s - m_cur) would be exp(0)=1 garbage at
+            # m==NEG_INF)
+            p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+            alpha = jnp.exp(m_prev - m_cur)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot(
+                p, v, preferred_element_type=jnp.float32
+            )
+            m_ref[h] = m_cur
 
     @pl.when(i == num_pages - 1)
     def _finish():
         l = l_ref[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -199,12 +208,14 @@ def paged_attention(
       q: ``(B, H, S, D)`` queries — a contiguous span of S positions per
         row (S=1 plain decode, S=K+1 speculative verify, S=piece for
         chunked prefill).
-      k_pool / v_pool: ``(kv_heads, pool_tokens, D)`` flat pools
-        (int8 codes when quantized).
+      k_pool / v_pool: ``(pool_tokens, kv_heads, D)`` flat token-major
+        pools (int8 codes when quantized) — the engine's own order; a
+        page of all kv heads is one contiguous block.
       page_table: ``(B, W_pages)`` int32 — page ordinal → pool page.
       pos0: ``(B,)`` int32 — absolute position of each row's first query
         (query s sits at ``pos0 + s``).
-      page_size: tokens per page; one kv grid step stages one page.
+      page_size: tokens per page; one kv grid step stages one page of
+        every kv head.
       window: optional sliding-window width (same semantics as the
         gather path's ``attn_window``).
       scale: score multiplier; defaults to ``1/sqrt(D)`` computed in f32
@@ -216,7 +227,7 @@ def paged_attention(
     Returns ``(B, H, S, D)`` in q's dtype.
     """
     B, H, S, D = q.shape
-    Hkv, T, Dk = k_pool.shape
+    T, Hkv, Dk = k_pool.shape
     if Dk != D or v_pool.shape != k_pool.shape:
         raise ValueError(f"pool shapes {k_pool.shape}/{v_pool.shape} vs D={D}")
     if H % Hkv:
@@ -248,21 +259,26 @@ def paged_attention(
     # row g*S + s of the (G*S) query axis for kv head hkv
     qg = q.reshape(B, Hkv, G * S, D)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, G * S, D), lambda b, h, i, tbl, p0: (b, h, 0, 0)),
-        pl.BlockSpec((1, page_size, D), lambda b, h, i, tbl, p0: (h, tbl[b, i], 0)),
-        pl.BlockSpec((1, page_size, D), lambda b, h, i, tbl, p0: (h, tbl[b, i], 0)),
-    ]
+    row_spec = pl.BlockSpec(
+        (1, Hkv, G * S, D), lambda b, i, tbl, p0: (b, 0, 0, 0)
+    )
+    # the block's last two dims equal the pool's, so any kv_heads / D
+    # tiles; the page axis (outermost) is the one the table indexes
+    page_spec = pl.BlockSpec(
+        (page_size, Hkv, D), lambda b, i, tbl, p0: (tbl[b, i], 0, 0)
+    )
+    in_specs = [row_spec, page_spec, page_spec]
     operands = [qg, k_pool, v_pool]
     if quant:
-        # a (1, page) block over the (kv_heads, pool_tokens) scale array
-        # is off Mosaic's (8, 128) tiling; viewed as (kv_heads, pages, 1,
-        # page) the block's last two dims equal the array's. The pool
-        # layout is untouched; XLA makes the view a relayout of the two
-        # scale arrays (4 bytes per token per head) on each call.
+        # a (kv_heads, page) block over the (kv_heads, pool_tokens)
+        # scale array is off Mosaic's (8, 128) tiling for pages under
+        # 128; viewed as (kv_heads, pages, 1, page) the block's last two
+        # dims equal the array's. The pool layout is untouched; XLA makes
+        # the view a relayout of the two scale arrays (4 bytes per token
+        # per head) on each call.
         scale_spec = pl.BlockSpec(
-            (1, 1, 1, page_size),
-            lambda b, h, i, tbl, p0: (h, tbl[b, i], 0, 0),
+            (Hkv, 1, 1, page_size),
+            lambda b, i, tbl, p0: (0, tbl[b, i], 0, 0),
         )
         in_specs += [scale_spec, scale_spec]
         operands += [
@@ -272,15 +288,13 @@ def paged_attention(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, W),
+        grid=(B, W),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, G * S, D), lambda b, h, i, tbl, p0: (b, h, 0, 0)
-        ),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((G * S, D), jnp.float32),
-            pltpu.VMEM((G * S, 1), jnp.float32),
-            pltpu.VMEM((G * S, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G * S, D), jnp.float32),
+            pltpu.VMEM((Hkv, G * S, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G * S, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -288,13 +302,26 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G * S, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(Hkv * G * S, D, q.dtype.itemsize),
         ),
         interpret=interpret,
     )(
         page_table.astype(jnp.int32), pos0.astype(jnp.int32), *operands
     )
     return out.reshape(B, H, S, D)
+
+
+def _vmem_limit(rows: int, d: int, q_bytes: int) -> int | None:
+    """The page block carries every kv head, so a row's queries, output
+    (both double-buffered) and f32 accumulators for ALL heads are
+    resident at once: ``rows`` = kv_heads x group x span of them, the
+    (rows, 1) running max and sum padded to 128 lanes. A decode step or a
+    verify span is far under the compiler's default scoped limit (None
+    leaves it alone); a 512-token prefill piece at 8 kv heads x 4 x 128
+    needs ~45 MB of a v5e's 128 MiB, so the limit is asked for by size."""
+    resident = rows * (4 * d * q_bytes + 4 * d + 2 * 4 * 128)
+    return None if resident < (8 << 20) else 2 * resident
 
 
 def _strip_scale_refs(kernel, tbl_ref, pos0_ref, q_ref, k_ref, v_ref,

@@ -521,13 +521,14 @@ class LMEngine:
                 max_pages_per_row=-(-max_seq // page_size),
             )
             if self._cache_sharding is not None:
-                # pooled layout: heads are axis 0. With int8 KV the tree
-                # mixes rank-3 pools and rank-2 scale arrays, so the
-                # sharding is a per-leaf tree (heads axis sharded in both)
+                # pooled layout: token-major pools (pool_tokens, kv_heads,
+                # D) — heads are axis 1 — and, with int8 KV, rank-2
+                # (kv_heads, pool_tokens) scale arrays, so the sharding is
+                # a per-leaf tree (the heads axis sharded in both)
                 from jax.sharding import NamedSharding
                 from jax.sharding import PartitionSpec as P
 
-                pool_sh = NamedSharding(self.mesh, P("model", None, None))
+                pool_sh = NamedSharding(self.mesh, P(None, "model", None))
                 scale_sh = NamedSharding(self.mesh, P("model", None))
                 self._cache_sharding = jax.tree_util.tree_map(
                     lambda l: scale_sh if l.ndim == 2 else pool_sh,
@@ -714,7 +715,12 @@ class LMEngine:
         # donation every prefill/implant/chunk call copies the entire
         # (max_batch, H, max_seq, D) x layers x 2 KV tree — pure HBM
         # bandwidth waste since the engine always rebinds self.cache to
-        # the result. (A failed donated call kills the buffers; the
+        # the result. Donation alone is not enough: a program that
+        # computes in another layout than its arguments arrive in copies
+        # the donated array in and out all the same, which is why the
+        # paged pool is stored token-major, the order its scatters and
+        # gathers index (models/transformer.py::init_paged_kv_cache).
+        # (A failed donated call kills the buffers; the
         # scheduler's fatal path already fails all requests and the
         # engine is rebuilt on reload.)
         # the spec chunk programs donate the history buffer alongside the
@@ -842,8 +848,9 @@ class LMEngine:
         """Copy row ``row``'s first n16 KV tokens out as a (1, kv_heads,
         n16, D)-per-layer entry (one jit per n16 — the 16-multiple
         quantization bounds this set). Dense mode slices the row; paged
-        mode gathers through the block table. SAME output format either
-        way, so the prefix store is cache-layout-agnostic."""
+        mode gathers through the block table and transposes the n16
+        tokens out of the pool's token-major order. SAME output format
+        either way, so the prefix store is cache-layout-agnostic."""
         fn = self._extract_jits.get(n16)
         if fn is None:
             # the cache holds kv_heads (GQA), NOT n_heads
@@ -857,8 +864,8 @@ class LMEngine:
                     idx = table_row[j // P] * P + j % P
                     out = {
                         name: {
-                            "k": lc["k"][:, idx, :][None],
-                            "v": lc["v"][:, idx, :][None],
+                            "k": lc["k"][idx].transpose(1, 0, 2)[None],
+                            "v": lc["v"][idx].transpose(1, 0, 2)[None],
                         }
                         for name, lc in cache.items()
                     }
@@ -1226,7 +1233,8 @@ class LMEngine:
     def _implant_paged(self, stored, row: int, n16: int):
         """Scatter a stored prefix (1, kv_heads, n16, D per layer — the
         SAME entry format as dense mode, so the prefix store is layout-
-        agnostic) into row ``row``'s pages at token indices [0, n16)."""
+        agnostic; transposed here into the pool's token-major order) into
+        row ``row``'s pages at token indices [0, n16)."""
         fn = self._implant_jits.get(n16)
         if fn is None:
             P = self.page_size
@@ -1237,13 +1245,13 @@ class LMEngine:
                 idx = table_row[j // P] * P + j % P
                 out = {
                     name: {
-                        "k": cache[name]["k"].at[:, idx, :].set(
-                            stored[name]["k"][0].astype(
+                        "k": cache[name]["k"].at[idx].set(
+                            stored[name]["k"][0].transpose(1, 0, 2).astype(
                                 cache[name]["k"].dtype
                             )
                         ),
-                        "v": cache[name]["v"].at[:, idx, :].set(
-                            stored[name]["v"][0].astype(
+                        "v": cache[name]["v"].at[idx].set(
+                            stored[name]["v"][0].transpose(1, 0, 2).astype(
                                 cache[name]["v"].dtype
                             )
                         ),

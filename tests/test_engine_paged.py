@@ -359,8 +359,10 @@ def test_tp_paged_engine_matches_unsharded():
         kv_pool_tokens=16 * 16, page_size=16,
     ).start()
     try:
+        # token-major pool: (pool_tokens, kv_heads, D), heads on "model"
         k0 = next(iter(sharded.cache.values()))["k"]
-        assert "model" in str(k0.sharding.spec)
+        assert k0.shape == (16 * 16, cfg.kv_heads, cfg.head_dim)
+        assert tuple(k0.sharding.spec) == (None, "model", None)
         rng = np.random.default_rng(31)
         for _ in range(3):
             ids = [int(x) for x in rng.integers(2, 96, size=rng.integers(4, 20))]

@@ -76,7 +76,7 @@ def _oracle(q, k_pool, v_pool, table, pos0, P, window=None,
     """Straight-line numpy paged attention: gather the whole horizon
     through the block table, mask, softmax in f64-free f32."""
     B, H, S, D = q.shape
-    Hkv = k_pool.shape[0]
+    Hkv = k_pool.shape[1]                       # token-major pools
     G = H // Hkv
     W = table.shape[1] * P
     j = np.arange(W)
@@ -84,12 +84,12 @@ def _oracle(q, k_pool, v_pool, table, pos0, P, window=None,
     kf = np.asarray(k_pool, np.float32)
     vf = np.asarray(v_pool, np.float32)
     if k_scale is not None:
-        kf = kf * np.asarray(k_scale)[:, :, None]
-        vf = vf * np.asarray(v_scale)[:, :, None]
+        kf = kf * np.asarray(k_scale).T[:, :, None]
+        vf = vf * np.asarray(v_scale).T[:, :, None]
     for b in range(B):
         flat = np.asarray(table)[b, j // P] * P + j % P
-        K = kf[:, flat, :]
-        V = vf[:, flat, :]
+        K = kf[flat].transpose(1, 0, 2)         # (Hkv, W, D)
+        V = vf[flat].transpose(1, 0, 2)
         for h in range(H):
             hk = h // G
             for s in range(S):
@@ -127,8 +127,8 @@ def test_kernel_matches_oracle(name, kw):
     n_pages, W_pages = 8, 4
     T = n_pages * P
     q = jnp.asarray(rng.normal(size=(B, H, S, D)), jnp.float32)
-    kp = rng.normal(size=(Hkv, T, D)).astype(np.float32)
-    vp = rng.normal(size=(Hkv, T, D)).astype(np.float32)
+    kp = rng.normal(size=(T, Hkv, D)).astype(np.float32)
+    vp = rng.normal(size=(T, Hkv, D)).astype(np.float32)
     # random distinct non-scratch pages per row; row 1 ends mid-page so
     # the partial-last-page mask is exercised every run
     table = np.zeros((B, W_pages), np.int32)
@@ -138,9 +138,9 @@ def test_kernel_matches_oracle(name, kw):
     pos0 = np.array([W_pages * P - S, (W_pages - 1) * P - S], np.int32)
     ks = vs = None
     if quant:
-        kq, ks = quantize_kv(jnp.asarray(kp))
+        kq, ks = quantize_kv(jnp.asarray(kp))    # scales (T, Hkv) ...
         vq, vs = quantize_kv(jnp.asarray(vp))
-        kpo, vpo = kq, vq
+        kpo, vpo, ks, vs = kq, vq, ks.T, vs.T    # ... stored (Hkv, T)
     else:
         kpo, vpo = jnp.asarray(kp), jnp.asarray(vp)
     out = paged_attention(
@@ -157,8 +157,8 @@ def test_kernel_first_token_pos0_zero():
     not poison the accumulator (the exp(0)=1 garbage-tile hazard)."""
     rng = np.random.default_rng(3)
     q = jnp.asarray(rng.normal(size=(1, 2, 1, 32)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(1, 64, 32)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(1, 64, 32)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(64, 1, 32)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(64, 1, 32)), jnp.float32)
     tb = np.array([[1, 0]], np.int32)
     pos0 = np.array([0], np.int32)
     out = paged_attention(

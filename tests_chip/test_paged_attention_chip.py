@@ -1,7 +1,7 @@
 """Compiled (Mosaic, not interpret) paged decode-attention kernel on the
 real chip — the CPU suite runs it only under the Pallas interpreter, which
 proves semantics but not that Mosaic accepts the scalar-prefetch block-
-table index maps, the (1, page, D) kv tiling, or the int8 load + f32
+table index maps, the (page, kv_heads, D) kv tiling, or the int8 load + f32
 dequant-in-kernel path. Mirrors test_attention_chip.py: bf16 parity
 against an XLA gather oracle, then a page-size sweep whose winner is
 persisted and picked back up through the tuning table.
@@ -22,20 +22,20 @@ def _gather_oracle(q, k_pool, v_pool, table, pos0, P, k_scale=None,
     """XLA reference: gather the horizon through the block table, masked
     softmax in f32 — the engine's paged gather path, standalone."""
     B, H, S, D = q.shape
-    Hkv = k_pool.shape[0]
+    Hkv = k_pool.shape[1]                               # token-major pools
     G = H // Hkv
     W = table.shape[1] * P
     j = jnp.arange(W)
     flat = table[:, j // P] * P + j % P                 # (B, W)
-    k = jnp.take(k_pool, flat.reshape(-1), axis=1)      # (Hkv, B*W, D)
-    v = jnp.take(v_pool, flat.reshape(-1), axis=1)
-    if k_scale is not None:
+    k = jnp.take(k_pool, flat.reshape(-1), axis=0)      # (B*W, Hkv, D)
+    v = jnp.take(v_pool, flat.reshape(-1), axis=0)
+    if k_scale is not None:                             # (Hkv, pool_tokens)
         k = k.astype(jnp.float32) * jnp.take(
-            k_scale, flat.reshape(-1), axis=1)[..., None]
+            k_scale, flat.reshape(-1), axis=1).T[..., None]
         v = v.astype(jnp.float32) * jnp.take(
-            v_scale, flat.reshape(-1), axis=1)[..., None]
-    k = k.reshape(Hkv, B, W, D).transpose(1, 0, 2, 3)   # (B, Hkv, W, D)
-    v = v.reshape(Hkv, B, W, D).transpose(1, 0, 2, 3)
+            v_scale, flat.reshape(-1), axis=1).T[..., None]
+    k = k.reshape(B, W, Hkv, D).transpose(0, 2, 1, 3)   # (B, Hkv, W, D)
+    v = v.reshape(B, W, Hkv, D).transpose(0, 2, 1, 3)
     qf = q.astype(jnp.float32).reshape(B, Hkv, G * S, D)
     s = jnp.einsum("bhqd,bhkd->bhqk", qf, k.astype(jnp.float32))
     s = s / jnp.sqrt(jnp.float32(D))
@@ -56,8 +56,8 @@ def _case(seed, *, B=4, Hkv=4, G=2, S=1, D=64, P=64, pages_per_row=8,
     n_pages = 1 + B * pages_per_row
     T = n_pages * P
     q = jnp.asarray(rng.randn(B, H, S, D) / np.sqrt(D), dtype)
-    kp = jnp.asarray(rng.randn(Hkv, T, D) / np.sqrt(D), dtype)
-    vp = jnp.asarray(rng.randn(Hkv, T, D) / np.sqrt(D), dtype)
+    kp = jnp.asarray(rng.randn(T, Hkv, D) / np.sqrt(D), dtype)
+    vp = jnp.asarray(rng.randn(T, Hkv, D) / np.sqrt(D), dtype)
     perm = 1 + rng.permutation(B * pages_per_row).astype(np.int32)
     table = jnp.asarray(perm.reshape(B, pages_per_row))
     # staggered fills: every row ends at a different offset in its page
@@ -88,6 +88,7 @@ def test_paged_compiled_int8_parity():
     q, kp, vp, table, pos0 = _case(1)
     kq, ks = quantize_kv(kp.astype(jnp.float32))
     vq, vs = quantize_kv(vp.astype(jnp.float32))
+    ks, vs = ks.T, vs.T                 # stored (kv_heads, pool_tokens)
     out = paged_attention(q, kq, vq, table, pos0, page_size=64,
                           k_scale=ks, v_scale=vs)
     ref = _gather_oracle(q, kq, vq, table, pos0, 64, k_scale=ks, v_scale=vs)
