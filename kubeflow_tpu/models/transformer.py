@@ -433,12 +433,12 @@ class Attention(nn.Module):
                 # position (continuous-batching gen regions start at a
                 # quantized slot), so the window can only be applied by the
                 # caller, who owns the slot→position mapping. generate.py
-                # and serve/engine.py both do; anything else must too.
+                # does (decode_kv_mask); anything else must too.
                 # (B, T) masks every query position the same way (classic
                 # one-token decode); (B, S, T) gives each query its own
-                # slot bound — the speculative multi-token verify step,
-                # where query j must not see the span's future draft keys
-                # (decode_span_kv_mask).
+                # slot bound — a multi-token step where query j must not
+                # see the span's later keys (no caller since the engine
+                # serves from the paged pool only: ROADMAP D4).
                 kvm = kv_mask if kv_mask.ndim == 3 else kv_mask[:, None, :]
                 mask = jnp.broadcast_to(kvm, (B, S, T))
             o = _grouped_cache_attention(q, K, V, mask, groups)

@@ -28,8 +28,8 @@ sweepable "block size" for this kernel IS the engine's ``page_size``
 Span support: queries are a contiguous (K+1)-position speculative verify
 span (or a prefill piece) starting at per-row position ``pos0[b]`` —
 query s sits at absolute position ``pos0[b] + s``.  The in-span causal
-mask that `serve/generate.py:decode_span_kv_mask` builds for the dense
-path falls out of pure position arithmetic inside the tile mask here
+mask (query s must not see the span's later keys)
+falls out of pure position arithmetic inside the tile mask here
 (key position ``i*P + lane`` is visible to query s iff it is ``<=
 pos0 + s`` and inside the sliding window), so speculative verify needs
 no separate program.  GQA: a grid step holds the page for every kv head

@@ -9,9 +9,11 @@ never decodes dead rows.
 TPU-first shape of the same idea (no per-token host hops, no dynamic
 shapes):
 
-- **One persistent KV cache** of (max_batch, max_seq) rows lives in HBM.
-  A request is admitted by prefilling into a FREE ROW (per-row
-  ``cache_index`` vectors — rows sit at different progress points).
+- **One persistent KV cache**, a paged pool (serve/paging.py: the vLLM
+  block-table analog), lives in HBM. A request is admitted by claiming
+  a FREE ROW and the pages of its worst case (prompt + max_new_tokens)
+  and prefilling through the row's block table; a row's tokens are
+  contiguous, so position == token index everywhere.
 - **Decode runs in fixed-size chunks**: one jitted ``lax.scan`` of
   ``chunk_steps`` decode steps for ALL rows (inactive rows are masked and
   emit pads). The host syncs once per chunk — admission, completion, and
@@ -60,7 +62,7 @@ import numpy as np
 from kubeflow_tpu.models.transformer import (
     TransformerConfig,
     TransformerLM,
-    init_kv_cache,
+    init_paged_kv_cache,
 )
 from kubeflow_tpu.obs import names, prom
 from kubeflow_tpu.obs.headers import (
@@ -85,12 +87,11 @@ from kubeflow_tpu.serve.deadline import (
 )
 from kubeflow_tpu.serve.generate import (
     LMRuntimeModel,
-    decode_kv_mask,
-    decode_span_kv_mask,
     sample_logits as _sample,
 )
 from kubeflow_tpu.serve.kv_codec import decode_kv_entries
 from kubeflow_tpu.serve.kv_tier import HostKVTier
+from kubeflow_tpu.serve.paging import PageAllocator
 
 #: idle park bound — every waker (submit, stream-cancel, stop) sets
 #: ``_work``, so this timeout is only a belt-and-braces sweep, not a poll
@@ -155,9 +156,19 @@ class LMEngineConfig:
     step program runs, byte-compatible with the pre-spec engine. Greedy
     decoding is byte-identical either way; K only changes how many
     forwards the same token stream costs. ``spec_ngram``: the match
-    window the drafter keys on (>= 1). Dense mode reserves K scratch
-    slots of KV headroom per row, so admission requires
-    ``layout + max_new_tokens + K <= max_seq`` when spec is on.
+    window the drafter keys on (>= 1). Speculation needs no KV
+    head-room: span positions past a row's budgeted region write to the
+    pool's scratch page.
+
+    ``kv_pool_tokens``: the KV cache is ONE paged pool of this many
+    tokens, billed per resident token; a request that finds no pages
+    waits (FIFO) for completions to free some. ``None`` (default) sizes
+    the pool so that every row can hold ``max_seq`` tokens at once —
+    ``max_batch × ceil(max_seq / page_size)`` pages beside the
+    allocator's scratch page — and admission is then never held back by
+    pages. A deployer names a smaller number to pack mixed-length
+    traffic into less HBM than that rectangle. Admission is by the
+    prompt's own length: ``len(ids) + max_new_tokens <= max_seq``.
 
     ``paged_attn_impl``: how the paged read path runs — ``"gather"``
     (default, in-graph XLA gather + masked softmax) or ``"kernel"``
@@ -168,9 +179,9 @@ class LMEngineConfig:
     ``kv_quant``: ``"none"`` (default, byte-exact with the pre-quant
     engine) or ``"int8"`` — per-(kv_head, token) symmetric int8 pool
     with f32 scale side arrays, quantize-on-write / dequantize-on-read;
-    pool bytes per resident token halve vs bf16 (quarter vs f32). Both
-    knobs require paged mode (``kv_pool_tokens``). ``page_size=None``
-    selects the measured page size from ops/flash_tuning.py's table
+    pool bytes per resident token halve vs bf16 (quarter vs f32).
+    ``page_size=None`` selects the measured page size from
+    ops/flash_tuning.py's table
     (``paged:{head_dim}`` section, written by ``sweep_paged_pages``)."""
 
     max_batch: int = 8
@@ -268,7 +279,6 @@ class _Request:
     seed: int | None = None
     # set on admission:
     row: int = -1
-    gen_start: int = 0
     # request tracing (obs/trace.py) — only populated for requests whose
     # submit carried a trace context; warmup and untraced callers pay
     # nothing on this path. ``espan`` is the engine-stage span, qspan /
@@ -422,15 +432,7 @@ class LMEngine:
             raise ValueError(
                 f"kv_quant must be 'none' or 'int8'; got {config.kv_quant!r}"
             )
-        if kv_pool_tokens is None and (
-            config.paged_attn_impl != "gather" or config.kv_quant != "none"
-        ):
-            raise ValueError(
-                "paged_attn_impl='kernel' / kv_quant='int8' require paged "
-                "mode (set kv_pool_tokens)"
-            )
-        #: paged read path (gather | kernel) and KV pool precision
-        self.paged_attn_impl = config.paged_attn_impl
+        #: KV pool precision
         self.kv_quant = config.kv_quant
         if page_size is None:
             # measured page size from the on-chip sweep table (falls back
@@ -449,15 +451,10 @@ class LMEngine:
         #: label for engine-stage spans and the TTFT/TPOT histograms;
         #: LMEngineModel stamps its serving-model name here
         self.model_name = "engine"
-        #: paged KV mode (the vLLM block-table analog, serve/paging.py):
-        #: HBM holds kv_pool_tokens tokens TOTAL instead of a
-        #: (max_batch, max_seq) rectangle — admission is bounded by pages,
-        #: not rows, so mixed-length traffic packs denser.
-        self.paged = kv_pool_tokens is not None
         self.page_size = page_size
         if mesh is not None:
             # tensor-parallel serving: params laid out by the SAME rules as
-            # training (parallel/sharding.py) and the KV cache sharded over
+            # training (parallel/sharding.py) and the KV pool sharded over
             # heads on the model axis — GSPMD then compiles every engine
             # program (prefill/implant/chunk) with the right collectives.
             from jax.sharding import NamedSharding
@@ -469,7 +466,7 @@ class LMEngine:
             specs = rules(params)
             mesh_shape = dict(zip(mesh.axis_names, mesh.devices.shape))
             rules.validate_divisibility(params, mesh_shape)
-            # the KV cache shards its head axis P(None,'model',..) over
+            # the KV pool shards its head axis P(None,'model',None) over
             # kv_heads — validate_divisibility only sees PARAMS, so a GQA
             # config with kv_heads % model-size != 0 would otherwise die
             # later inside the jitted cache init with an opaque GSPMD error
@@ -484,12 +481,8 @@ class LMEngine:
                 lambda p, s: jax.device_put(p, NamedSharding(mesh, s)),
                 params, specs,
             )
-            self._cache_sharding = NamedSharding(
-                mesh, P(None, "model", None, None)
-            )
         else:
             self.params = jax.device_put(params)
-            self._cache_sharding = None
         self.max_batch, self.max_seq = max_batch, max_seq
         self.chunk_steps = chunk_steps
         self.prefill_buckets = tuple(sorted(prefill_buckets))
@@ -510,56 +503,45 @@ class LMEngine:
         # device state: the persistent cache. Everything per-row and small
         # (lengths, last tokens, activity) lives host-side as numpy — it
         # rides into each chunk call and costs nothing next to the cache.
-        if self.paged:
-            from kubeflow_tpu.models.transformer import init_paged_kv_cache
-            from kubeflow_tpu.serve.paging import PageAllocator
-
-            self.pager = PageAllocator(
-                pool_tokens=kv_pool_tokens,
-                page_size=page_size,
-                max_batch=max_batch,
-                max_pages_per_row=-(-max_seq // page_size),
+        pages_per_row = -(-max_seq // page_size)
+        if kv_pool_tokens is None:
+            # no size named: every row can hold max_seq tokens at once —
+            # the bytes of a (max_batch, max_seq) rectangle — so pages
+            # never hold an admission back
+            kv_pool_tokens = page_size * (
+                max_batch * pages_per_row + PageAllocator.RESERVED_PAGES
             )
-            if self._cache_sharding is not None:
-                # pooled layout: token-major pools (pool_tokens, kv_heads,
-                # D) — heads are axis 1 — and, with int8 KV, rank-2
-                # (kv_heads, pool_tokens) scale arrays, so the sharding is
-                # a per-leaf tree (the heads axis sharded in both)
-                from jax.sharding import NamedSharding
-                from jax.sharding import PartitionSpec as P
+        self.pager = PageAllocator(
+            pool_tokens=kv_pool_tokens,
+            page_size=page_size,
+            max_batch=max_batch,
+            max_pages_per_row=pages_per_row,
+        )
 
-                pool_sh = NamedSharding(self.mesh, P(None, "model", None))
-                scale_sh = NamedSharding(self.mesh, P("model", None))
-                self._cache_sharding = jax.tree_util.tree_map(
-                    lambda l: scale_sh if l.ndim == 2 else pool_sh,
-                    jax.eval_shape(
-                        lambda: init_paged_kv_cache(
-                            cfg, kv_pool_tokens, kv_quant=self.kv_quant
-                        )
-                    ),
-                )
-                self.cache = jax.jit(
-                    lambda: init_paged_kv_cache(
-                        cfg, kv_pool_tokens, kv_quant=self.kv_quant
-                    ),
-                    out_shardings=self._cache_sharding,
-                )()
-            else:
-                self.cache = init_paged_kv_cache(
-                    cfg, kv_pool_tokens, kv_quant=self.kv_quant
-                )
-        elif self._cache_sharding is not None:
-            # allocate DIRECTLY in the sharded layout: materialising the
-            # full tree on one device first would OOM exactly the
-            # deployments TP serving exists for
+        def init_cache():
+            return init_paged_kv_cache(
+                cfg, kv_pool_tokens, kv_quant=self.kv_quant
+            )
+
+        if mesh is not None:
+            # token-major pools (pool_tokens, kv_heads, D) — heads are
+            # axis 1 — and, with int8 KV, rank-2 (kv_heads, pool_tokens)
+            # scale arrays, so the sharding is a per-leaf tree (the heads
+            # axis sharded in both). Allocated DIRECTLY in the sharded
+            # layout: materialising the full tree on one device first
+            # would OOM exactly the deployments TP serving exists for
+            pool_sh = NamedSharding(mesh, P(None, "model", None))
+            scale_sh = NamedSharding(mesh, P("model", None))
             self.cache = jax.jit(
-                lambda: init_kv_cache(cfg, max_batch, max_seq),
-                out_shardings=self._cache_sharding,
+                init_cache,
+                out_shardings=jax.tree_util.tree_map(
+                    lambda l: scale_sh if l.ndim == 2 else pool_sh,
+                    jax.eval_shape(init_cache),
+                ),
             )()
         else:
-            self.cache = init_kv_cache(cfg, max_batch, max_seq)
+            self.cache = init_cache()
         self.real_len = np.zeros((max_batch,), np.int32)   # prompt length
-        self.gen_start = np.zeros((max_batch,), np.int32)  # first gen slot
         self.gen_count = np.zeros((max_batch,), np.int32)  # tokens so far
         self.budget = np.zeros((max_batch,), np.int32)     # max_new_tokens
         self.last_tok = np.zeros((max_batch,), np.int32)
@@ -576,8 +558,8 @@ class LMEngine:
         self._carry_seeded = False
         self._slots: list[_Request | None] = [None] * max_batch
         # speculative decoding: the host mirror of the per-row token
-        # history (prompt + generated, TOKEN-POSITION indexed — identical
-        # for dense and paged layouts). The device copy rides the carry
+        # history (prompt + generated, TOKEN-POSITION indexed). The device
+        # copy rides the carry
         # and is rewritten in-graph each decode step; this mirror (fed at
         # admission and from drained tokens) rebuilds it on every epoch
         # re-upload. Width max_seq + K + 1 gives the in-graph span write
@@ -633,6 +615,8 @@ class LMEngine:
             "kv_ship_bytes": 0, "kv_ship_fallbacks": 0,
             # host-RAM KV tier: sessions swapped out on finish / back in
             "kv_offload_out": 0, "kv_offload_in": 0,
+            # most pool pages owned by resident rows at any admission
+            "kv_pages_used_peak": 0,
             # the scheduler thread's wall time, once per loop iteration, and
             # under it each phase's self seconds and entries (_phase)
             "sched_loop_s": 0.0,
@@ -648,9 +632,9 @@ class LMEngine:
         self._carry: dict[str, Any] | None = None
         self._carry_dirty = True
         self._carry_chunks = 0   # chunks dispatched since last upload
-        self._carry_h0 = 0       # paged: max(real_len+gen_count) at upload
-        self._carry_hcap = 0     # paged: max(real_len+budget) at upload
-        self._carry_pages_w = 0  # paged: uploaded table width (pages)
+        self._carry_h0 = 0       # max(real_len+gen_count) at upload
+        self._carry_hcap = 0     # max(real_len+budget) at upload
+        self._carry_pages_w = 0  # uploaded table width (pages)
         self._last_dispatch: float | None = None
         self.overlap = {
             "decode_gap_ms": 0.0,   # EWMA host time between chunk dispatches
@@ -659,10 +643,6 @@ class LMEngine:
             "slot_occupancy": 0.0,  # EWMA occupied-row fraction at dispatch
             "spec_acceptance": 0.0,  # EWMA accepted/proposed draft ratio
         }
-        if self.paged:
-            # pre-initialized: /metrics iterates this dict from another
-            # thread; a first-admission key INSERT would race it
-            self.stats["kv_pages_used_peak"] = 0
         if self.kv_quant == "int8":
             # EWMA of mean-abs relative KV quantization error, measured by
             # the suffix-prefill program (kft_engine_kv_quant_error)
@@ -713,7 +693,7 @@ class LMEngine:
         # offset 0 (same mask, same rope coordinates) — no second copy to
         # keep in sync. The cache argument is DONATED everywhere: without
         # donation every prefill/implant/chunk call copies the entire
-        # (max_batch, H, max_seq, D) x layers x 2 KV tree — pure HBM
+        # (pool_tokens, kv_heads, D) x layers x 2 KV tree — pure HBM
         # bandwidth waste since the engine always rebinds self.cache to
         # the result. Donation alone is not enough: a program that
         # computes in another layout than its arguments arrive in copies
@@ -738,30 +718,19 @@ class LMEngine:
         # compiles — and only runs — when a seeded row is actually in the
         # batch; pure-unseeded traffic stays on programs byte-identical to
         # the pre-resume engine.
-        if self.paged:
-            self._suffix_prefill = jax.jit(
-                self._suffix_prefill_paged_impl, donate_argnums=(1,),
-                static_argnames=("seeded",),
-            )
-            self._chunk = jax.jit(
-                self._chunk_spec_paged_impl if self.spec_k
-                else self._chunk_paged_impl,
-                donate_argnums=chunk_donate, static_argnames=("seeded",),
-            )
-            self._implant_jits: dict[int, Any] = {}
-            #: a request held back by page backpressure (FIFO preserved:
-            #: nothing admits past it until its pages free up)
-            self._held: "_Request | None" = None
-        else:
-            self._suffix_prefill = jax.jit(
-                self._suffix_prefill_impl, donate_argnums=(1,),
-                static_argnames=("seeded",),
-            )
-            self._implant = jax.jit(self._implant_impl, donate_argnums=(0,))
-            self._chunk = jax.jit(
-                self._chunk_spec_impl if self.spec_k else self._chunk_impl,
-                donate_argnums=chunk_donate, static_argnames=("seeded",),
-            )
+        self._suffix_prefill = jax.jit(
+            self._suffix_prefill_paged_impl, donate_argnums=(1,),
+            static_argnames=("seeded",),
+        )
+        self._chunk = jax.jit(
+            self._chunk_spec_paged_impl if self.spec_k
+            else self._chunk_paged_impl,
+            donate_argnums=chunk_donate, static_argnames=("seeded",),
+        )
+        self._implant_jits: dict[int, Any] = {}
+        #: a request held back by page backpressure (FIFO preserved:
+        #: nothing admits past it until its pages free up)
+        self._held: "_Request | None" = None
         self._extract_jits: dict[int, Any] = {}
 
     # -- device programs ---------------------------------------------------- #
@@ -785,171 +754,39 @@ class LMEngine:
         seeded = jnp.where(temperature <= 0.0, greedy, drawn)
         return jnp.where(seed >= 0, seeded, legacy.astype(drawn.dtype))
 
-    def _suffix_prefill_impl(
-        self, params, cache, suffix, slen, offset, row, temperature, seed,
-        pos, rng, *, seeded=False,
-    ):
-        """Prefill only the SUFFIX of a prompt whose first ``offset`` slots
-        of row ``row`` already hold reused prefix KV. ``cache_index=offset``
-        gives the default causal mask and rope positions the right absolute
-        coordinates, so this is bit-for-bit the tail of a full prefill."""
-        row_cache = {
-            name: {
-                "k": jax.lax.dynamic_slice_in_dim(lc["k"], row, 1, axis=0),
-                "v": jax.lax.dynamic_slice_in_dim(lc["v"], row, 1, axis=0),
-            }
-            for name, lc in cache.items()
-        }
-        logits, row_cache = self.model.apply(
-            {"params": params}, suffix, cache=row_cache,
-            cache_index=offset,
-        )
-        last = jnp.take_along_axis(
-            logits, (slen - 1)[:, None, None], axis=1
-        )[:, 0]
-        tok = _sample(last, rng, temperature[None])
-        if seeded:  # static: unseeded programs carry zero PRNG-fold ops
-            tok = self._seeded_sample(
-                last, jnp.asarray(seed, jnp.int32)[None],
-                jnp.asarray(pos, jnp.int32)[None], temperature[None], tok,
-            )
-        tok = tok[0]
-        cache = {
-            name: {
-                "k": jax.lax.dynamic_update_slice_in_dim(
-                    cache[name]["k"], row_cache[name]["k"], row, axis=0
-                ),
-                "v": jax.lax.dynamic_update_slice_in_dim(
-                    cache[name]["v"], row_cache[name]["v"], row, axis=0
-                ),
-            }
-            for name in cache
-        }
-        # trailing (2,) zero matches the paged twin's quant-error output so
-        # _advance_prefill unpacks one arity for both layouts
-        return cache, tok, tok != self.eos_id, jnp.zeros((2,), jnp.float32)
-
-    def _implant_impl(self, cache, stored, row):
-        """Copy a stored prefix's KV (1, kv_heads, n16, D per layer) into
-        the FRONT of cache row ``row``."""
-        return {
-            name: {
-                "k": jax.lax.dynamic_update_slice(
-                    cache[name]["k"], stored[name]["k"], (row, 0, 0, 0)
-                ),
-                "v": jax.lax.dynamic_update_slice(
-                    cache[name]["v"], stored[name]["v"], (row, 0, 0, 0)
-                ),
-            }
-            for name in cache
-        }
-
     def _extract_prefix(self, row: int, n16: int):
         """Copy row ``row``'s first n16 KV tokens out as a (1, kv_heads,
         n16, D)-per-layer entry (one jit per n16 — the 16-multiple
-        quantization bounds this set). Dense mode slices the row; paged
-        mode gathers through the block table and transposes the n16
-        tokens out of the pool's token-major order. SAME output format
-        either way, so the prefix store is cache-layout-agnostic."""
+        quantization bounds this set): gathered through the block table
+        and transposed out of the pool's token-major order. This entry
+        format is the prefix store's, the KV span's and the host tier's."""
         fn = self._extract_jits.get(n16)
         if fn is None:
-            # the cache holds kv_heads (GQA), NOT n_heads
-            H, D = self.cfg.kv_heads, self.cfg.head_dim
-            if self.paged:
-                P = self.page_size
-                quant = self.kv_quant == "int8"
+            P = self.page_size
+            quant = self.kv_quant == "int8"
 
-                def impl(cache, table_row):
-                    j = jnp.arange(n16)
-                    idx = table_row[j // P] * P + j % P
-                    out = {
-                        name: {
-                            "k": lc["k"][idx].transpose(1, 0, 2)[None],
-                            "v": lc["v"][idx].transpose(1, 0, 2)[None],
-                        }
-                        for name, lc in cache.items()
+            def impl(cache, table_row):
+                j = jnp.arange(n16)
+                idx = table_row[j // P] * P + j % P
+                out = {
+                    name: {
+                        "k": lc["k"][idx].transpose(1, 0, 2)[None],
+                        "v": lc["v"][idx].transpose(1, 0, 2)[None],
                     }
-                    if quant:
-                        # int8 entries carry their per-token scales —
-                        # (1, kv_heads, n16) alongside the (1, kv_heads,
-                        # n16, D) codes — so an imported prefix dequants
-                        # identically on the receiving engine
-                        for name, lc in cache.items():
-                            out[name]["k_scale"] = lc["k_scale"][:, idx][None]
-                            out[name]["v_scale"] = lc["v_scale"][:, idx][None]
-                    return out
-            else:
-
-                def impl(cache, row):
-                    return {
-                        name: {
-                            "k": jax.lax.dynamic_slice(
-                                lc["k"], (row, 0, 0, 0), (1, H, n16, D)
-                            ),
-                            "v": jax.lax.dynamic_slice(
-                                lc["v"], (row, 0, 0, 0), (1, H, n16, D)
-                            ),
-                        }
-                        for name, lc in cache.items()
-                    }
+                    for name, lc in cache.items()
+                }
+                if quant:
+                    # int8 entries carry their per-token scales —
+                    # (1, kv_heads, n16) alongside the (1, kv_heads,
+                    # n16, D) codes — so an imported prefix dequants
+                    # identically on the receiving engine
+                    for name, lc in cache.items():
+                        out[name]["k_scale"] = lc["k_scale"][:, idx][None]
+                        out[name]["v_scale"] = lc["v_scale"][:, idx][None]
+                return out
 
             fn = self._extract_jits[n16] = jax.jit(impl)
-        if self.paged:
-            return fn(self.cache, jnp.asarray(self.pager.table[row].copy()))
-        return fn(self.cache, row)
-
-    def _chunk_impl(
-        self, params, cache, last_tok, real_len, gen_start, gen_count,
-        active, budget, temperature, seed, rng, *, seeded=False,
-    ):
-        """``chunk_steps`` decode steps for ALL rows. Inactive and
-        over-budget rows still step (SPMD: no dynamic batch) but never
-        advance their cache pointers or emit valid tokens — a row whose
-        budget runs out mid-chunk cannot write past its cache region."""
-        kpos = jnp.arange(self.max_seq)
-
-        def step(carry, _):
-            cache, tok, gen_count, active, rng = carry
-            rng, sub = jax.random.split(rng)
-            live = active & (gen_count < budget)  # (B,)
-            # the carry token is the LAST EMITTED one (gen index
-            # gen_count-1): its KV lands at that slot, its rope position is
-            # that absolute index, and attention sees everything up to it
-            slot = gen_start + gen_count - 1      # (B,) per-row write slot
-            positions = (real_len + gen_count - 1)[:, None]
-            kv_mask = decode_kv_mask(
-                kpos, real_len, gen_start, slot, self.cfg.attn_window
-            )
-            lg, cache = self.model.apply(
-                {"params": params},
-                tok[:, None],
-                cache=cache,
-                cache_index=slot,
-                positions=positions,
-                kv_mask=kv_mask,
-            )
-            nxt = _sample(lg[:, 0], sub, temperature)
-            if seeded:
-                # new token's absolute position is real_len + gen_count
-                # (gen_count is the pre-increment carry value)
-                nxt = self._seeded_sample(
-                    lg[:, 0], seed, real_len + gen_count, temperature, nxt
-                )
-            valid = live & (nxt != self.eos_id)
-            out = jnp.where(valid, nxt, self.pad_id)
-            # dead rows must NOT advance their cache pointers: their slot
-            # writes land at a frozen index and are simply re-overwritten
-            gen_count = jnp.where(live, gen_count + 1, gen_count)
-            tok = jnp.where(valid, out, tok)
-            return (cache, tok, gen_count, valid, rng), (out, valid)
-
-        (cache, tok, gen_count, active, _), (toks, valid) = jax.lax.scan(
-            step,
-            (cache, last_tok, gen_count, active, rng),
-            None,
-            length=self.chunk_steps,
-        )
-        return cache, tok, gen_count, active, toks.T, valid.T  # (B, T)
+        return fn(self.cache, jnp.asarray(self.pager.table[row].copy()))
 
     # -- speculative decoding (serve/speculative.py) ------------------------- #
 
@@ -1022,29 +859,32 @@ class LMEngine:
 
         return jax.vmap(upd)(hist, hist_len, emitted, live_i)
 
-    def _chunk_spec_impl(
-        self, params, cache, hist, last_tok, real_len, gen_start,
-        gen_count, active, budget, temperature, seed, rng, *, seeded=False,
+    def _chunk_spec_paged_impl(
+        self, params, cache, hist, last_tok, real_len, gen_count, active,
+        budget, temperature, seed, rng, table, *, seeded=False,
     ):
-        """Speculative twin of _chunk_impl: each scan step drafts up to K
-        tokens by prompt-lookup against the row's device-resident history
-        and verifies them in ONE (K+1)-position forward (per-position
-        logits + in-span causal masking via decode_span_kv_mask — the
-        suffix-prefill machinery's mask, lifted per query). Accepted
-        drafts' KV is already correct (they were the forward's inputs);
-        rejected positions' KV lands beyond the accepted pointer where
-        later steps re-overwrite it before it is ever attended — the same
-        frozen-slot trick dead rows use. Rows with no match draft length
-        0 and degrade to the classic one-token step."""
+        """Speculative form of _chunk_paged_impl: each scan step drafts up
+        to K tokens by prompt-lookup against the row's device-resident
+        history and verifies them in ONE (K+1)-position forward through
+        the block table, positions (L-1 .. L-1+K) per row — masking is
+        position arithmetic, already per query. Accepted drafts' KV is
+        already correct (they were the forward's inputs); rejected
+        positions' KV lands beyond the accepted pointer where later steps
+        re-overwrite it before it is ever attended. Span positions past
+        the row's budgeted region route to the scratch page (their page
+        ordinal may sit past the read window, where a clamped gather
+        would otherwise redirect the write INTO the row's real pages).
+        Rows with no match draft length 0 and degrade to the classic
+        one-token step."""
         from kubeflow_tpu.serve.speculative import propose_draft, spec_accept
 
         K = self.spec_k
-        kpos = jnp.arange(self.max_seq)
 
         def step(carry, _):
             cache, hist, tok, gen_count, active, rng = carry
             rng, sub = jax.random.split(rng)
-            L = real_len + gen_count                  # (B,) history length
+            live0 = active & (gen_count < budget)
+            L = real_len + gen_count
             draft, draft_len = propose_draft(
                 hist, L, ngram=self.spec_ngram, k=K
             )
@@ -1057,18 +897,17 @@ class LMEngine:
             if seeded:
                 seeded_t = (seed >= 0) & (temperature > 0.0)
                 draft_len = jnp.where(seeded_t, 0, draft_len)
-            # x_0 is the carry token (its KV is written now, at its slot,
-            # exactly as the one-token step does); x_{i+1} = draft i
             x = jnp.concatenate([tok[:, None], draft], axis=1)
-            slot0 = gen_start + gen_count - 1
             positions = (L - 1)[:, None] + jnp.arange(K + 1)[None, :]
-            kv_mask = decode_span_kv_mask(
-                kpos, real_len, gen_start, slot0, K + 1,
-                self.cfg.attn_window,
+            write_ok = live0[:, None] & (
+                positions < (real_len + budget)[:, None]
             )
             lg, cache = self.model.apply(
-                {"params": params}, x, cache=cache, cache_index=slot0,
-                positions=positions, kv_mask=kv_mask,
+                {"params": params}, x, cache=cache,
+                positions=positions, page_table=table,
+                page_size=self.page_size, page_write_ok=write_ok,
+                paged_attn_impl=self.engine_config.paged_attn_impl,
+                kv_quant=self.kv_quant,
             )
             emitted, n_emit, n_acc = spec_accept(
                 lg, draft, draft_len, sub, temperature
@@ -1101,81 +940,11 @@ class LMEngine:
         toks, valid, eos, prop, acc = outs
         return (
             cache, hist, tok, gen_count, active,
-            jnp.moveaxis(toks, 0, 1), jnp.moveaxis(valid, 0, 1),  # (B,T,K+1)
-            eos.T, prop.T, acc.T,                                 # (B, T)
-        )
-
-    def _chunk_spec_paged_impl(
-        self, params, cache, hist, last_tok, real_len, gen_count, active,
-        budget, temperature, seed, rng, table, *, seeded=False,
-    ):
-        """Paged twin of _chunk_spec_impl: the (K+1)-position verify runs
-        through the block table with positions (L-1 .. L-1+K) per row —
-        masking is position arithmetic, already per query. Span positions
-        past the row's budgeted region route to the scratch page (their
-        page ordinal may sit past the read window, where a clamped gather
-        would otherwise redirect the write INTO the row's real pages)."""
-        from kubeflow_tpu.serve.speculative import propose_draft, spec_accept
-
-        K = self.spec_k
-
-        def step(carry, _):
-            cache, hist, tok, gen_count, active, rng = carry
-            rng, sub = jax.random.split(rng)
-            live0 = active & (gen_count < budget)
-            L = real_len + gen_count
-            draft, draft_len = propose_draft(
-                hist, L, ngram=self.spec_ngram, k=K
-            )
-            # resume-determinism contract: see _chunk_spec_impl
-            if seeded:
-                seeded_t = (seed >= 0) & (temperature > 0.0)
-                draft_len = jnp.where(seeded_t, 0, draft_len)
-            x = jnp.concatenate([tok[:, None], draft], axis=1)
-            positions = (L - 1)[:, None] + jnp.arange(K + 1)[None, :]
-            write_ok = live0[:, None] & (
-                positions < (real_len + budget)[:, None]
-            )
-            lg, cache = self.model.apply(
-                {"params": params}, x, cache=cache,
-                positions=positions, page_table=table,
-                page_size=self.page_size, page_write_ok=write_ok,
-                paged_attn_impl=self.paged_attn_impl,
-                kv_quant=self.kv_quant,
-            )
-            emitted, n_emit, n_acc = spec_accept(
-                lg, draft, draft_len, sub, temperature
-            )
-            if seeded:
-                emitted = emitted.at[:, 0].set(self._seeded_sample(
-                    lg[:, 0], seed, L, temperature, emitted[:, 0]
-                ))
-            (
-                out, valid_i, live_i, eos_step, tok, gen_count, active,
-                prop, acc,
-            ) = self._spec_emit(
-                emitted, n_emit, draft_len, n_acc, tok, gen_count, active,
-                budget,
-            )
-            hist = self._spec_hist_update(hist, L, emitted, live_i)
-            return (cache, hist, tok, gen_count, active, rng), (
-                out, valid_i, eos_step, prop, acc,
-            )
-
-        (cache, hist, tok, gen_count, active, _), outs = jax.lax.scan(
-            step,
-            (cache, hist, last_tok, gen_count, active, rng),
-            None,
-            length=self.chunk_steps,
-        )
-        toks, valid, eos, prop, acc = outs
-        return (
-            cache, hist, tok, gen_count, active,
             jnp.moveaxis(toks, 0, 1), jnp.moveaxis(valid, 0, 1),
             eos.T, prop.T, acc.T,
         )
 
-    # -- paged device programs (serve/paging.py block-table mode) ----------- #
+    # -- device programs through the block table (serve/paging.py) ---------- #
 
     def _pages_w(self, tokens: int) -> int:
         """Read-window width in pages: pow2-rounded so the compiled
@@ -1190,17 +959,20 @@ class LMEngine:
         self, params, cache, suffix, slen, offset, table, temperature,
         seed, pos, rng, *, seeded=False,
     ):
-        """Paged twin of _suffix_prefill_impl: one row's prefill piece
-        writes tokens [offset, offset+S) through its block table. Pad
-        positions (>= slen) route to the scratch page. The read window is
-        ``table`` width × page_size (pow2-bucketed by the caller)."""
+        """One row's prefill piece: writes tokens [offset, offset+S)
+        through its block table, the first ``offset`` tokens being a
+        reused prefix or earlier pieces (a full prefill is the piece at
+        offset 0). Pad positions (>= slen) route to the scratch page. The
+        read window is ``table`` width × page_size (pow2-bucketed by the
+        caller)."""
         S = suffix.shape[1]
         positions = offset + jnp.arange(S)[None, :]          # (1, S)
         write_ok = (jnp.arange(S) < slen[:, None])           # (1, S)
         kw = dict(
             positions=positions, page_table=table,
             page_size=self.page_size, page_write_ok=write_ok,
-            paged_attn_impl=self.paged_attn_impl, kv_quant=self.kv_quant,
+            paged_attn_impl=self.engine_config.paged_attn_impl,
+            kv_quant=self.kv_quant,
         )
         if self.kv_quant == "int8":
             # the ONLY program that materializes the quantization-error
@@ -1231,10 +1003,10 @@ class LMEngine:
         return cache, tok, tok != self.eos_id, qerr
 
     def _implant_paged(self, stored, row: int, n16: int):
-        """Scatter a stored prefix (1, kv_heads, n16, D per layer — the
-        SAME entry format as dense mode, so the prefix store is layout-
-        agnostic; transposed here into the pool's token-major order) into
-        row ``row``'s pages at token indices [0, n16)."""
+        """Scatter a stored prefix (1, kv_heads, n16, D per layer —
+        _extract_prefix's entry format, transposed here into the pool's
+        token-major order) into row ``row``'s pages at token indices
+        [0, n16)."""
         fn = self._implant_jits.get(n16)
         if fn is None:
             P = self.page_size
@@ -1283,11 +1055,14 @@ class LMEngine:
         self, params, cache, last_tok, real_len, gen_count, active, budget,
         temperature, seed, rng, table, *, seeded=False,
     ):
-        """Paged twin of _chunk_impl. A row's token space is CONTIGUOUS
-        (gen token g sits at token index real_len + g — no quantized gap),
-        so position == token index and the model's paged branch derives
-        causal/window masking from positions alone. Dead rows still step
-        (SPMD) but their writes route to the scratch page — their pages
+        """``chunk_steps`` decode steps for ALL rows. A row's token space
+        is CONTIGUOUS (gen token g sits at token index real_len + g), so
+        position == token index and the model's paged branch derives
+        causal/window masking from positions alone. The carry token is
+        the LAST EMITTED one: its KV lands at its own index and attention
+        sees everything up to it. Inactive and over-budget rows still
+        step (SPMD: no dynamic batch) but never advance or emit valid
+        tokens, and their writes route to the scratch page — their pages
         may already belong to another row."""
 
         def step(carry, _):
@@ -1303,7 +1078,7 @@ class LMEngine:
                 page_table=table,
                 page_size=self.page_size,
                 page_write_ok=live[:, None],
-                paged_attn_impl=self.paged_attn_impl,
+                paged_attn_impl=self.engine_config.paged_attn_impl,
                 kv_quant=self.kv_quant,
             )
             nxt = _sample(lg[:, 0], sub, temperature)
@@ -1357,7 +1132,7 @@ class LMEngine:
                 self._slots[row] = None
                 req.error = err
                 req.finish()
-        if self.paged and self._held is not None:
+        if self._held is not None:
             self._held.error = err
             self._held.finish()
             self._held = None
@@ -1383,7 +1158,7 @@ class LMEngine:
             self.active.any()
             or self._pending.qsize()
             or self._prefilling
-            or (self.paged and self._held is not None)
+            or self._held is not None
         )
 
     def poison(self, err: Exception) -> None:
@@ -1401,7 +1176,7 @@ class LMEngine:
                 self._slots[row] = None
                 req.error = err
                 req.finish()
-        if self.paged and self._held is not None:
+        if self._held is not None:
             self._held.error = err
             self._held.finish()
             self._held = None
@@ -1432,7 +1207,7 @@ class LMEngine:
         span = self._chunk_span
         decode_s = -(-max_new_tokens // span) * gap_s
         queued = self._pending.qsize() + (
-            1 if self.paged and self._held is not None else 0
+            1 if self._held is not None else 0
         )
         free = sum(s is None for s in self._slots)
         if queued < free:
@@ -1495,7 +1270,7 @@ class LMEngine:
         # beyond max_batch + max_queue is shed — an unbounded tail would
         # wait longer than any client timeout
         occupied = sum(s is not None for s in self._slots)
-        held = 1 if self.paged and self._held is not None else 0
+        held = 1 if self._held is not None else 0
         if (
             self._pending.qsize() + occupied + held
             >= self.max_batch + self.max_queue
@@ -1506,52 +1281,24 @@ class LMEngine:
                     f"{self._pending.qsize() + held} queued, "
                     f"max_queue={self.max_queue})"
                 )
-        if self.paged:
-            # token space is contiguous in paged mode (no bucket-padding
-            # gap), so the layout IS the prompt itself
-            layout = len(ids)
-        elif kv_inject is not None:
-            # an injected span occupies exactly its ceil-16 window; no
-            # prefill ever runs here, so bucket/chunk layouts don't apply
-            layout = kv_inject.n16
-        elif self.prefill_chunk is not None:
-            # chunked prefill frees prompts from the bucket bound: the only
-            # limit is the piece layout fitting max_seq
-            C = self.prefill_chunk
-            layout = -(-len(ids) // C) * C
-        else:
-            layout = self._bucket(len(ids))
-        # max_seq FIRST: a request over the per-row bound must say so —
-        # "raise kv_pool_tokens" would be a lie when no pool size can fit
-        # it in the page-table width
-        if layout + max_new_tokens > self.max_seq:
+        # a row's token space is contiguous (no bucket- or piece-padding
+        # gap: padding writes to the scratch page), so admission is by the
+        # prompt's own length. max_seq FIRST: a request over the per-row
+        # bound must say so — "raise kv_pool_tokens" would be a lie when no
+        # pool size can fit it in the page-table width
+        if len(ids) + max_new_tokens > self.max_seq:
             raise ValueError(
-                f"prompt layout {layout} + max_new_tokens {max_new_tokens} "
-                f"exceeds engine max_seq {self.max_seq}"
+                f"prompt length {len(ids)} + max_new_tokens "
+                f"{max_new_tokens} exceeds engine max_seq {self.max_seq}"
             )
-        if self.spec_k and not self.paged and (
-            layout + max_new_tokens + self.spec_k > self.max_seq
-        ):
-            # dense speculative decode writes rejected-draft KV up to K
-            # slots past the row's budgeted region (re-overwritten, never
-            # attended) — the row must physically hold that headroom.
-            # Paged mode needs none: overflow writes route to the scratch
-            # page.
+        need = self.pager.pages_for(len(ids) + max_new_tokens)
+        if need > self.pager.usable_pages:
             raise ValueError(
-                f"prompt layout {layout} + max_new_tokens {max_new_tokens} "
-                f"+ spec_draft_tokens {self.spec_k} exceeds engine "
-                f"max_seq {self.max_seq} (speculative decode reserves K "
-                f"scratch slots per row)"
+                f"request needs {need} pages; pool has "
+                f"{self.pager.usable_pages} — raise kv_pool_tokens"
             )
-        if self.paged:
-            need = self.pager.pages_for(len(ids) + max_new_tokens)
-            if need > self.pager.num_pages - 1:
-                raise ValueError(
-                    f"request needs {need} pages; pool has "
-                    f"{self.pager.num_pages - 1} — raise kv_pool_tokens"
-                )
-            if self.prefill_chunk is None and kv_inject is None:
-                self._bucket(len(ids))  # reject over-bucket prompts now
+        if self.prefill_chunk is None and kv_inject is None:
+            self._bucket(len(ids))  # reject over-bucket prompts now
         req = _Request(
             list(ids), max_new_tokens, temperature,
             live=queue.Queue() if live else None,
@@ -1781,12 +1528,9 @@ class LMEngine:
         counter a pure prefill replica ever moves."""
         n16 = -(-len(ids) // 16) * 16
         # the generation budget is a LAYOUT reservation only — it sizes
-        # the paged allocation so the whole ceil-16 extract window is
-        # backed by real pages; no decode chunk ever runs against it.
-        # Dense cache rows are max_seq wide regardless of bucket, so the
-        # extract window is always backed and budget 1 keeps small
-        # bucket+max_seq configs admissible
-        budget = max(1, n16 - len(ids) + 1) if self.paged else 1
+        # the page allocation so the whole ceil-16 extract window is
+        # backed by real pages; no decode chunk ever runs against it
+        budget = max(1, n16 - len(ids) + 1)
         if deadline is None:
             deadline = time.monotonic() + timeout_s
         req = self._enqueue(
@@ -1848,7 +1592,7 @@ class LMEngine:
             free = [i for i, s in enumerate(self._slots) if s is None]
             if not free:
                 return
-            if self.paged and self._held is not None:
+            if self._held is not None:
                 req, self._held = self._held, None
             else:
                 try:
@@ -1871,15 +1615,13 @@ class LMEngine:
             if req.cancelled.is_set():
                 req.finish()  # consumer already gone: never admit
                 continue
-            if self.paged:
-                need = self.pager.pages_for(
-                    len(req.ids) + req.max_new_tokens
-                )
-                if not self.pager.can_alloc(need):
-                    # page backpressure: hold THIS request (FIFO — nothing
-                    # admits past it) until completions free pages
-                    self._held = req
-                    return
+            if not self.pager.can_alloc(
+                self.pager.pages_for(len(req.ids) + req.max_new_tokens)
+            ):
+                # page backpressure: hold THIS request (FIFO — nothing
+                # admits past it) until completions free pages
+                self._held = req
+                return
             row = free[0]
             try:
                 self._admit(req, row)
@@ -1976,49 +1718,25 @@ class LMEngine:
             # (same machinery, different store) and continues
             # byte-identically
             hit = self._take_swapped(req)
-        implanted = None
+        # claim pages FIRST: _admit_all verified availability; implant
+        # needs the table row populated
+        self.pager.alloc(
+            row, self.pager.pages_for(len(req.ids) + req.max_new_tokens)
+        )
         if hit is not None:
             key, stored = hit
-            n16 = len(key)
-            suffix_ids = req.ids[n16:]
+            base, rest = len(key), req.ids[len(key):]
             # suffixes bucket at the 16-token prefix quantum, NOT the full
             # prefill buckets — padding a 4-token tail to a 128 bucket
-            # would waste cache slots and blow the max_seq layout check
-            C = self.prefill_chunk or ((len(suffix_ids) + 15) // 16) * 16
-            n_pieces = -(-len(suffix_ids) // C)
-            # paged rows have no quantized layout: contiguous tokens
-            # (len + max_new <= max_seq, enforced at enqueue) always fit —
-            # piece padding routes to the scratch page. Dense rows must
-            # fit the padded layout.
-            if self.paged or (
-                n16 + n_pieces * C + req.max_new_tokens + self.spec_k
-                <= self.max_seq
-            ):
-                implanted = (n16, stored, suffix_ids, C, n_pieces)
-        if self.paged:
-            # claim pages FIRST: _admit_all verified availability; implant
-            # needs the table row populated
-            self.pager.alloc(
-                row, self.pager.pages_for(len(req.ids) + req.max_new_tokens)
-            )
-        if implanted is not None:
-            n16, stored, rest, C, n_pieces = implanted
-            if self.paged:
-                self._implant_paged(stored, row, n16)
-            else:
-                self.cache = self._implant(self.cache, stored, row)
-            base = n16
+            # would waste a whole piece on pad slots
+            C = self.prefill_chunk or ((len(rest) + 15) // 16) * 16
+            self._implant_paged(stored, row, base)
             self.stats["prefix_hits"] += 1
-            self.stats["prefix_tokens_reused"] += n16
+            self.stats["prefix_tokens_reused"] += base
         else:
-            # layout vs max_seq was already enforced by _enqueue (same
-            # formula) — no recheck needed here
             C = self.prefill_chunk or self._bucket(len(rest))
-            n_pieces = -(-len(rest) // C)
-        # paged rows have NO quantized gap: generation continues at the
-        # next token index, so position == token index throughout
-        gen_start = len(req.ids) if self.paged else base + n_pieces * C
-        req.row, req.gen_start = row, gen_start
+        n_pieces = -(-len(rest) // C)
+        req.row = row
         self._slots[row] = req
         self.real_len[row] = len(req.ids)
         if self.spec_k:
@@ -2026,7 +1744,6 @@ class LMEngine:
             # costs nothing and the next carry upload ships it
             self.hist_host[row, :] = self.pad_id
             self.hist_host[row, : len(req.ids)] = req.ids
-        self.gen_start[row] = gen_start
         self.gen_count[row] = 0
         self.budget[row] = req.max_new_tokens
         self.temp[row] = req.temperature
@@ -2035,10 +1752,9 @@ class LMEngine:
         self.stats["max_concurrent"] = max(
             self.stats["max_concurrent"], sum(s is not None for s in self._slots)
         )
-        if self.paged:
-            self.stats["kv_pages_used_peak"] = max(
-                self.stats["kv_pages_used_peak"], self.pager.used_pages
-            )
+        self.stats["kv_pages_used_peak"] = max(
+            self.stats["kv_pages_used_peak"], self.pager.used_pages
+        )
         if req.qspan is not None:
             req.qspan.end()
             req.qspan = None
@@ -2056,7 +1772,7 @@ class LMEngine:
             "req": req, "rest": rest, "base": base, "C": C,
             "n_pieces": n_pieces, "piece": 0,
         }
-        # admission epoch: the per-row mirrors (and paged table) changed —
+        # admission epoch: the per-row mirrors and the block table changed —
         # the next dispatch must merge+re-upload the carry
         self._carry_dirty = True
         if n_pieces == 1:
@@ -2071,29 +1787,23 @@ class LMEngine:
         that this engine NEVER computes a prefill chunk for it (on a pure
         decode-pool replica ``prefill_pieces`` stays zero). The span's
         first sampled token rides the meta, so the request starts exactly
-        where the prefill replica left it: dense rows mask the
-        [real_len, n16) junk gap via ``decode_kv_mask``; paged rows
-        overwrite [real_len, ...) with real decode KV before any query
-        position reaches it."""
+        where the prefill replica left it: decode overwrites the span's
+        junk [real_len, n16) with real KV before any query position
+        reaches it."""
         span = req.kv_inject
         tree, meta, n16 = span.tree, span.meta, span.n16
-        if self.paged:
-            # claim pages FIRST (availability verified by _admit_all);
-            # the allocation covers len + max_new >= the implant window
-            self.pager.alloc(
-                row, self.pager.pages_for(len(req.ids) + req.max_new_tokens)
-            )
-            self._implant_paged(tree, row, n16)
-        else:
-            self.cache = self._implant(self.cache, tree, row)
-        gen_start = len(req.ids) if self.paged else n16
-        req.row, req.gen_start = row, gen_start
+        # claim pages FIRST (availability verified by _admit_all); the
+        # allocation covers len + max_new >= the implant window
+        self.pager.alloc(
+            row, self.pager.pages_for(len(req.ids) + req.max_new_tokens)
+        )
+        self._implant_paged(tree, row, n16)
+        req.row = row
         self._slots[row] = req
         self.real_len[row] = len(req.ids)
         if self.spec_k:
             self.hist_host[row, :] = self.pad_id
             self.hist_host[row, : len(req.ids)] = req.ids
-        self.gen_start[row] = gen_start
         self.gen_count[row] = 0
         self.budget[row] = req.max_new_tokens
         self.temp[row] = req.temperature
@@ -2104,10 +1814,9 @@ class LMEngine:
             self.stats["max_concurrent"],
             sum(s is not None for s in self._slots),
         )
-        if self.paged:
-            self.stats["kv_pages_used_peak"] = max(
-                self.stats["kv_pages_used_peak"], self.pager.used_pages
-            )
+        self.stats["kv_pages_used_peak"] = max(
+            self.stats["kv_pages_used_peak"], self.pager.used_pages
+        )
         if req.qspan is not None:
             req.qspan.end()
             req.qspan = None
@@ -2168,35 +1877,20 @@ class LMEngine:
             # this equals len(req.ids) — the first generated position)
             seed = -1 if req.seed is None else req.seed
             pos = base + i * C + len(piece_ids)
-            if self.paged:
-                pages_w = self._pages_w(base + i * C + C)
-                self.cache, tok, valid, qerr = self._suffix_prefill(
-                    self.params,
-                    self.cache,
-                    jnp.asarray(piece),
-                    jnp.asarray([len(piece_ids)], np.int32),
-                    base + i * C,
-                    jnp.asarray(self.pager.table[row : row + 1, :pages_w].copy()),
-                    jnp.float32(req.temperature),
-                    seed,
-                    pos,
-                    sub,
-                    seeded=req.seed is not None,
-                )
-            else:
-                self.cache, tok, valid, qerr = self._suffix_prefill(
-                    self.params,
-                    self.cache,
-                    jnp.asarray(piece),
-                    jnp.asarray([len(piece_ids)], np.int32),
-                    base + i * C,
-                    row,
-                    jnp.float32(req.temperature),
-                    seed,
-                    pos,
-                    sub,
-                    seeded=req.seed is not None,
-                )
+            pages_w = self._pages_w(base + i * C + C)
+            self.cache, tok, valid, qerr = self._suffix_prefill(
+                self.params,
+                self.cache,
+                jnp.asarray(piece),
+                jnp.asarray([len(piece_ids)], np.int32),
+                base + i * C,
+                jnp.asarray(self.pager.table[row : row + 1, :pages_w].copy()),
+                jnp.float32(req.temperature),
+                seed,
+                pos,
+                sub,
+                seeded=req.seed is not None,
+            )
         if self.kv_quant == "int8":
             # same inline sync budget as the final piece's int(tok) below:
             # prefill is synchronous by design (one row, host-driven)
@@ -2276,8 +1970,7 @@ class LMEngine:
             # swap-out must extract BEFORE the pages free (the block
             # table row is still this request's)
             self._swap_out(req, row)
-        if self.paged:
-            self.pager.free(row)
+        self.pager.free(row)
         # ``carry_stale=False`` is the drain's EOS/budget retirement: the
         # device carry already gates the row in-graph (active=False after
         # EOS; gen_count==budget masks it live=False), so no re-upload is
@@ -2295,16 +1988,11 @@ class LMEngine:
     def _swap_out(self, req: _Request, row: int) -> None:
         """Queue a finished sessioned row's KV span for the host tier.
         KV is written for the first ``real_len + emitted - 1`` context
-        positions in PAGED mode (contiguous token space); DENSE rows only
-        have contiguous real KV over the prompt (generated KV sits past
-        the bucket gap), so they store the prompt window only. The
+        positions (the last emitted token's KV is never computed). The
         extract here is device handles (async); the D2H + encode runs on
         the offload worker thread."""
         ctx_tokens = list(req.ids) + list(req.tokens)
-        if self.paged:
-            written = len(req.ids) + max(0, len(req.tokens) - 1)
-        else:
-            written = len(req.ids)
+        written = len(req.ids) + max(0, len(req.tokens) - 1)
         n16 = (min(written, self.max_seq) // 16) * 16
         if n16 < 16:
             return
@@ -2391,7 +2079,7 @@ class LMEngine:
                     req.error = e
                     self._slots[row] = None
                     req.finish()
-            if self.paged and self._held is not None:
+            if self._held is not None:
                 self._held.error = e
                 self._held.finish()
                 self._held = None
@@ -2530,23 +2218,20 @@ class LMEngine:
             # epoch rebuilds it from the host mirror (current: epochs
             # always drain first) — one small int32 H2D per epoch
             c["hist"] = jnp.asarray(self.hist_host.copy())
-        if self.paged:
-            act = self.active
-            if act.any():
-                reach = self.real_len + self.gen_count
-                self._carry_h0 = int(reach[act].max())
-                self._carry_hcap = int((self.real_len + self.budget)[act].max())
-            else:
-                self._carry_h0 = self._carry_hcap = 0
-            w = self._pages_w(
-                max(min(self._carry_h0 + self._chunk_span,
-                        self._carry_hcap), 1)
-            )
-            # memoized device mirror: unchanged table + same width = no H2D
-            c["table"] = self.pager.device_table(w)
-            self._carry_pages_w = w
+        act = self.active
+        if act.any():
+            reach = self.real_len + self.gen_count
+            self._carry_h0 = int(reach[act].max())
+            self._carry_hcap = int((self.real_len + self.budget)[act].max())
         else:
-            c["gen_start"] = jnp.asarray(self.gen_start.copy())
+            self._carry_h0 = self._carry_hcap = 0
+        w = self._pages_w(
+            max(min(self._carry_h0 + self._chunk_span,
+                    self._carry_hcap), 1)
+        )
+        # memoized device mirror: unchanged table + same width = no H2D
+        c["table"] = self.pager.device_table(w)
+        self._carry_pages_w = w
         self._carry = c
         self._carry_dirty = False
         self._carry_chunks = 0
@@ -2574,48 +2259,29 @@ class LMEngine:
         c = self._carry
         active_in = c["active"]
         eos = prop = acc = None
-        if self.paged:
-            # page-horizon growth across speculative chunks: active rows
-            # advance ≤ chunk_span tokens per chunk (chunk_steps × up to
-            # K+1 under speculation), so this bound covers every
-            # write/read this chunk can reach; when it crosses a pow2 page
-            # bucket, widen the device table (the host table is constant
-            # within an epoch, so widening mid-flight is safe)
-            horizon = min(
-                self._carry_h0 + (self._carry_chunks + 1) * self._chunk_span,
-                self._carry_hcap,
-            )
-            w = self._pages_w(max(horizon, 1))
-            if w > self._carry_pages_w:
-                c["table"] = self.pager.device_table(w)
-                self._carry_pages_w = w
-                self.overlap["carry_uploads"] += 1
-            if self.spec_k:
-                (
-                    self.cache, c["hist"], tok, gen_count, active,
-                    toks, valid, eos, prop, acc,
-                ) = self._chunk(
-                    self.params, self.cache, c["hist"], c["last_tok"],
-                    c["real_len"], c["gen_count"], c["active"], c["budget"],
-                    c["temp"], c["seed"], sub, c["table"],
-                    seeded=self._carry_seeded,
-                )
-            else:
-                (
-                    self.cache, tok, gen_count, active, toks, valid
-                ) = self._chunk(
-                    self.params, self.cache, c["last_tok"], c["real_len"],
-                    c["gen_count"], c["active"], c["budget"], c["temp"],
-                    c["seed"], sub, c["table"], seeded=self._carry_seeded,
-                )
-        elif self.spec_k:
+        # page-horizon growth across speculative chunks: active rows
+        # advance ≤ chunk_span tokens per chunk (chunk_steps × up to K+1
+        # under speculation), so this bound covers every write/read this
+        # chunk can reach; when it crosses a pow2 page bucket, widen the
+        # device table (the host table is constant within an epoch, so
+        # widening mid-flight is safe)
+        horizon = min(
+            self._carry_h0 + (self._carry_chunks + 1) * self._chunk_span,
+            self._carry_hcap,
+        )
+        w = self._pages_w(max(horizon, 1))
+        if w > self._carry_pages_w:
+            c["table"] = self.pager.device_table(w)
+            self._carry_pages_w = w
+            self.overlap["carry_uploads"] += 1
+        if self.spec_k:
             (
                 self.cache, c["hist"], tok, gen_count, active,
                 toks, valid, eos, prop, acc,
             ) = self._chunk(
                 self.params, self.cache, c["hist"], c["last_tok"],
-                c["real_len"], c["gen_start"], c["gen_count"], c["active"],
-                c["budget"], c["temp"], c["seed"], sub,
+                c["real_len"], c["gen_count"], c["active"], c["budget"],
+                c["temp"], c["seed"], sub, c["table"],
                 seeded=self._carry_seeded,
             )
         else:
@@ -2623,8 +2289,8 @@ class LMEngine:
                 self.cache, tok, gen_count, active, toks, valid
             ) = self._chunk(
                 self.params, self.cache, c["last_tok"], c["real_len"],
-                c["gen_start"], c["gen_count"], c["active"], c["budget"],
-                c["temp"], c["seed"], sub, seeded=self._carry_seeded,
+                c["gen_count"], c["active"], c["budget"], c["temp"],
+                c["seed"], sub, c["table"], seeded=self._carry_seeded,
             )
         c["last_tok"], c["gen_count"], c["active"] = tok, gen_count, active
         self._carry_chunks += 1
@@ -3091,12 +2757,8 @@ class LMEngineModel(LMRuntimeModel):
         self._engine_spec_ngram = spec_ngram
         self._engine_paged_attn_impl = paged_attn_impl
         self._engine_kv_quant = kv_quant
-        # dense speculative decode reserves K scratch KV slots per row —
-        # the default max_seq must include them or the largest bucket's
-        # requests would be rejected at enqueue
         self._engine_max_seq = max_seq or (
             self.buckets.seq_lens[-1] + self.max_new_tokens
-            + (spec_draft_tokens if kv_pool_tokens is None else 0)
         )
         self.engine: LMEngine | None = None
         self._executor = None
@@ -3243,7 +2905,7 @@ class LMEngineModel(LMRuntimeModel):
             # once — budget > K so a full accepted span fits (clamped to
             # the engine's per-row layout bound)
             s0 = self.buckets.seq_lens[0]
-            cap = eng.max_seq - s0 - (0 if eng.paged else eng.spec_k)
+            cap = eng.max_seq - s0
             if cap >= 2:
                 eng.submit(
                     ([3, 5, 7] * s0)[:s0],

@@ -65,31 +65,6 @@ def decode_kv_mask(kpos, prompt_len, gen_start, slot, window=None):
     return prompt_keep | gen_keep
 
 
-def decode_span_kv_mask(kpos, prompt_len, gen_start, slot0, span, window=None):
-    """(B, span, T) cache-slot mask for a SPAN of decode queries sitting
-    at gen slots ``slot0 .. slot0+span-1`` — the multi-token speculative
-    verify step (serve/engine.py). Query j attends the prompt slots plus
-    gen slots ``[gen_start, slot0+j]``: in-span causality matters because
-    the verify forward writes all span positions' KV before attending, so
-    without the per-query bound position j would see future draft keys.
-    Same slot→position mapping (and window math) as
-    :func:`decode_kv_mask`, lifted to a per-query axis."""
-    pl = jnp.atleast_1d(jnp.asarray(prompt_len))[:, None, None]
-    gs = jnp.atleast_1d(jnp.asarray(gen_start))[:, None, None]
-    sl = (
-        jnp.atleast_1d(jnp.asarray(slot0))[:, None, None]
-        + jnp.arange(span)[None, :, None]
-    )
-    k = kpos[None, None, :]
-    prompt_keep = k < pl
-    gen_keep = (k >= gs) & (k <= sl)
-    if window is not None:
-        qpos = pl + sl - gs
-        prompt_keep &= k > qpos - window
-        gen_keep &= k > sl - window
-    return prompt_keep | gen_keep
-
-
 def sample_logits(logits, rng, temperature):
     """Per-row greedy/temperature sampling over (B, V) logits.
 

@@ -44,6 +44,10 @@ class PageAllocator:
     never read because the token mask stops at each row's length).
     """
 
+    #: pages of the pool no row can own (page 0, the scratch page): what a
+    #: caller sizing a pool adds to the pages its rows need
+    RESERVED_PAGES = 1
+
     def __init__(
         self, *, pool_tokens: int, page_size: int, max_batch: int,
         max_pages_per_row: int,
@@ -59,7 +63,7 @@ class PageAllocator:
             )
         self.page_size = page_size
         self.num_pages = pool_tokens // page_size
-        if self.num_pages < 2:
+        if self.num_pages <= self.RESERVED_PAGES:
             raise ValueError("pool must hold at least 2 pages (1 is scratch)")
         self.max_pages_per_row = max_pages_per_row
         #: pages 1..N-1 allocatable; 0 is the scratch page
@@ -84,8 +88,12 @@ class PageAllocator:
         return len(self._free)
 
     @property
+    def usable_pages(self) -> int:
+        return self.num_pages - self.RESERVED_PAGES
+
+    @property
     def used_pages(self) -> int:
-        return self.num_pages - 1 - len(self._free)
+        return self.usable_pages - len(self._free)
 
     def can_alloc(self, n_pages: int) -> bool:
         return n_pages <= len(self._free)
@@ -141,7 +149,7 @@ class PageAllocator:
     def stats(self) -> dict:
         return {
             "page_size": self.page_size,
-            "pages_total": self.num_pages - 1,
+            "pages_total": self.usable_pages,
             "pages_used": self.used_pages,
             "rows_resident": len(self._owned),
         }

@@ -60,3 +60,34 @@ def wait_for_job_step(cluster, uid, step, timeout=240):
     raise TimeoutError(
         f"step {step} not reached; log:\n" + cluster.logs(uid, "worker", 0)
     )
+
+
+class GenerateOracle:
+    """``serve/generate.py::make_generate_fn`` on the given weights, batch 1,
+    greedy: the same-numerics reference every engine stream is pinned to.
+    ``submit`` reads like the engine's, so a parity test states one
+    prompt and one budget for both sides. One jit per budget."""
+
+    def __init__(self, model, cfg, params, *, eos_id):
+        self.model, self.cfg, self.params, self.eos_id = model, cfg, params, eos_id
+        self._gens: dict = {}
+
+    def submit(self, ids, max_new_tokens):
+        import jax
+        import numpy as np
+
+        from kubeflow_tpu.serve.generate import make_generate_fn
+
+        gen = self._gens.get(max_new_tokens)
+        if gen is None:
+            gen = self._gens[max_new_tokens] = jax.jit(make_generate_fn(
+                self.model, self.cfg, max_new_tokens=max_new_tokens,
+                eos_id=self.eos_id,
+            ))
+        prompt = np.zeros((1, -(-len(ids) // 32) * 32), np.int32)
+        prompt[0, : len(ids)] = ids
+        toks, n_valid = gen(
+            self.params, prompt, np.asarray([len(ids)], np.int32),
+            jax.random.PRNGKey(7), np.zeros((1,), np.float32),
+        )
+        return [int(t) for t in np.asarray(toks)[0, : int(n_valid[0])]]

@@ -13,6 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import GenerateOracle
+
 from kubeflow_tpu.models.transformer import TransformerConfig, TransformerLM
 from kubeflow_tpu.serve.engine import LMEngine
 from kubeflow_tpu.serve.generate import make_generate_fn
@@ -197,12 +199,97 @@ def test_bad_request_fails_fast_without_killing_engine(model_and_params):
         with pytest.raises(ValueError, match="empty prompt"):
             eng.submit([])
         with pytest.raises(ValueError, match="exceeds engine max_seq"):
-            eng.submit([3, 4, 5], max_new_tokens=32)  # 32+32 > 40
+            eng.submit([3, 4, 5], max_new_tokens=38)  # 3+38 > 40
         # engine still serves afterwards
         out = eng.submit([3, 4, 5], max_new_tokens=4)
         assert out == _reference_completion(model, params, [3, 4, 5], 4)
     finally:
         eng.stop()
+
+
+def test_admission_is_by_prompt_length_not_bucket(model_and_params):
+    """A row's tokens are contiguous — bucket and piece padding write to
+    the scratch page — so a prompt is admitted by its own length: one whose
+    padded layout (bucket 32 + 32 new) would not fit max_seq is served,
+    and one token over max_seq still says so."""
+    model, params = model_and_params
+    # eos outside the vocabulary: the row runs to max_seq's last token
+    oracle = GenerateOracle(model, CFG, params, eos_id=97)
+    for chunked in (None, 16):
+        eng = LMEngine(
+            model, CFG, params, max_batch=2, max_seq=40, chunk_steps=4,
+            prefill_buckets=(32,), eos_id=97, prefill_chunk=chunked,
+        ).start()
+        try:
+            for ids, new in (([3, 4, 5], 37), ([7] * 20, 20)):
+                got = eng.submit(ids, max_new_tokens=new)
+                assert len(got) == new
+                assert got == oracle.submit(ids, new), (chunked, ids)
+                with pytest.raises(ValueError, match="exceeds engine max_seq"):
+                    eng.submit(ids, max_new_tokens=new + 1)
+        finally:
+            eng.stop()
+
+
+@pytest.mark.parametrize("through", ["engine", "model"])
+def test_engine_given_no_pool_size_holds_max_seq_in_every_row(
+    model_and_params, through
+):
+    """No ``kv_pool_tokens``: the pool is sized for ``max_batch`` rows of
+    ``max_seq`` tokens, so ``max_batch`` requests that each fill a row to
+    its last token are resident at once and none is ever held for pages —
+    built directly and through ``LMEngineModel``'s defaults."""
+    from kubeflow_tpu.serve.engine import LMEngineModel
+    from kubeflow_tpu.serve.model import BucketSpec
+
+    model, params = model_and_params
+    if through == "engine":
+        max_batch, max_seq, page, new = 3, 48, 16, 28
+        eng = LMEngine(
+            model, CFG, params, max_batch=max_batch, max_seq=max_seq,
+            page_size=page, chunk_steps=4, prefill_buckets=(32,), eos_id=97,
+        )
+        unload = eng.stop
+    else:
+        max_batch, max_seq, page, new = 2, 32 + 16, 64, 16
+        m = LMEngineModel(
+            "lm", None, config=CFG, max_batch=max_batch, chunk_steps=2,
+            buckets=BucketSpec(batch_sizes=(1,), seq_lens=(32,)),
+            max_new_tokens=new, eos_id=97, watchdog=False,
+        )
+        m.load()
+        m._params = jax.device_put(params)
+        m.engine.stop()
+        eng = m.engine = m._make_engine()       # not started yet
+        unload = m.unload
+    pages_per_row = -(-max_seq // page)
+    assert eng.max_seq == max_seq
+    assert eng.pager.stats()["pages_total"] == max_batch * pages_per_row
+    held = []
+    admit_all = eng._admit_all
+
+    def watched():                # _held is only ever set inside _admit_all
+        admit_all()
+        held.append(eng._held)
+
+    eng._admit_all = watched
+    prompts = [[2 + r, 9, 33] * 20 for r in range(max_batch)]
+    prompts = [p[: max_seq - new] for p in prompts]
+    # queued before the loop starts: one admission pass sees them all
+    reqs = [eng._enqueue(p, new, 0.0, live=False) for p in prompts]
+    eng.start()
+    try:
+        for req in reqs:
+            assert req.done.wait(180) and req.error is None, req.error
+    finally:
+        unload()
+    oracle = GenerateOracle(model, CFG, params, eos_id=97)
+    for p, req in zip(prompts, reqs):
+        assert len(req.tokens) == new                 # the row's last token
+        assert req.tokens == oracle.submit(p, new)
+    assert held and all(h is None for h in held)
+    assert eng.stats["max_concurrent"] == max_batch
+    assert eng.stats["kv_pages_used_peak"] == max_batch * pages_per_row
 
 
 def test_rest_concurrent_requests_share_engine(model_and_params):
@@ -661,12 +748,13 @@ def test_prefix_cache_lru_eviction(model_and_params):
         eng.stop()
 
 
-def test_prefix_cache_respects_max_seq_fallback(model_and_params):
-    """A hit whose reuse layout would overflow max_seq must fall back to a
-    full prefill and still answer correctly."""
+def test_prefix_hit_needs_no_layout_room(model_and_params):
+    """A hit whose implant + padded suffix piece would overflow max_seq is
+    still a hit: only the real tokens take room in the row (the piece's
+    padding writes to the scratch page), and the answer is exact."""
     model, params = model_and_params
-    # a non-16-multiple bucket (20) makes the reuse layout (16 + 16 + 10 =
-    # 42) exceed max_seq=40 while the full-prefill layout (20 + 10) fits
+    # a non-16-multiple bucket (20): prefix 16 + suffix piece 16 + 10 new
+    # = 42 slots if padding took room, over max_seq=40; 18 + 10 tokens fit
     eng = LMEngine(
         model, CFG, params, max_batch=1, max_seq=40, chunk_steps=4,
         prefill_buckets=(20,), eos_id=EOS, prefix_cache_entries=2,
@@ -677,7 +765,7 @@ def test_prefix_cache_respects_max_seq_fallback(model_and_params):
         eng.submit(base, max_new_tokens=4)  # stores base[:16]
         ids = base[:16] + [3, 4]
         got = eng.submit(ids, max_new_tokens=10)
-        assert eng.stats["prefix_hits"] == 0  # fallback, not a broken hit
+        assert eng.stats["prefix_hits"] == 1
         # reference path uses bucket 32; engine used 20 — same numerics
         assert got == _reference_completion(model, params, ids, 10)
     finally:
@@ -1293,9 +1381,8 @@ def test_engine_resume_validation_errors(model_and_params):
 
 
 @pytest.mark.parametrize("spec", [0, 3], ids=["plain", "spec"])
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
 def test_program_names_match_the_benchmarks_module_patterns(
-    model_and_params, paged, spec
+    model_and_params, spec
 ):
     """``engine_decode_device_ms`` and the two ``engine_prefill_device_*``
     metrics find the engine's programs on the trace's module line by name
@@ -1318,7 +1405,6 @@ def test_program_names_match_the_benchmarks_module_patterns(
     eng = LMEngine(
         model, CFG, params, max_batch=2, max_seq=64, chunk_steps=4,
         prefill_buckets=(32,), eos_id=EOS, spec_draft_tokens=spec,
-        **(dict(kv_pool_tokens=16 * 8, page_size=16) if paged else {}),
     )
 
     def _probe_impl(x):
@@ -1328,9 +1414,8 @@ def test_program_names_match_the_benchmarks_module_patterns(
     assert "module @jit__probe_impl" in jax.jit(_probe_impl).lower(1.0).as_text()
     chunk = "jit_" + eng._chunk.__name__ + "(12345)"
     prefill = "jit_" + eng._suffix_prefill.__name__ + "(12345)"
-    assert chunk == (
-        f"jit__chunk{'_spec' if spec else ''}{'_paged' if paged else ''}_impl(12345)"
-    )
+    assert chunk == f"jit__chunk{'_spec' if spec else ''}_paged_impl(12345)"
+    assert prefill == "jit__suffix_prefill_paged_impl(12345)"
     assert decode.search(chunk) and not decode.search(prefill)
     for rx in prefills:
         assert rx.search(prefill) and not rx.search(chunk)

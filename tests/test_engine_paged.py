@@ -1,8 +1,9 @@
-"""Paged KV cache (serve/paging.py + engine paged mode): the vLLM
-block-table analog. The invariant everywhere: PAGING IS A LAYOUT, NOT A
-NUMERICS CHANGE — every completion must equal the dense engine's (which
-is itself pinned to the whole-batch generate path), while HBM is billed
-per resident token instead of per (row × max_seq) rectangle."""
+"""Paged KV cache (serve/paging.py + the engine's one cache layout): the
+vLLM block-table analog. The invariant everywhere: PAGING IS A LAYOUT,
+NOT A NUMERICS CHANGE — every completion must equal the whole-batch
+generate path's (``make_generate_fn`` on a dense rectangle, the oracle),
+while HBM is billed per resident token instead of per (row × max_seq)
+rectangle. Pools here are named small on purpose, so that pages bite."""
 
 from __future__ import annotations
 
@@ -13,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import GenerateOracle
 
 from kubeflow_tpu.models.transformer import TransformerConfig, TransformerLM
 from kubeflow_tpu.serve.engine import LMEngine
@@ -95,41 +97,36 @@ def test_device_table_memo_evicts_stale_widths():
 # ------------------------------------------------------------------ parity
 
 
-def _dense_and_paged(model, params, *, prefix=0, chunked=None, cfg=CFG,
-                     pool_tokens=16 * 20, max_batch=4):
-    dense = LMEngine(
-        model, cfg, params, max_batch=max_batch, max_seq=64, chunk_steps=4,
-        prefill_buckets=(32,), eos_id=EOS, prefix_cache_entries=prefix,
-        prefill_chunk=chunked,
-    ).start()
+def _oracle_and_paged(model, params, *, prefix=0, chunked=None, cfg=CFG,
+                      pool_tokens=16 * 20, max_batch=4):
+    oracle = GenerateOracle(model, cfg, params, eos_id=EOS)
     paged = LMEngine(
         model, cfg, params, max_batch=max_batch, max_seq=64, chunk_steps=4,
         prefill_buckets=(32,), eos_id=EOS, prefix_cache_entries=prefix,
         prefill_chunk=chunked, kv_pool_tokens=pool_tokens, page_size=16,
     ).start()
-    return dense, paged
+    return oracle, paged
 
 
 def test_paged_matches_dense_exactly(model_and_params):
     model, params = model_and_params
-    dense, paged = _dense_and_paged(model, params)
+    oracle, paged = _oracle_and_paged(model, params)
     try:
         rng = np.random.default_rng(0)
         for ids in _prompts(rng, 8):
-            want = dense.submit(ids, max_new_tokens=12)
+            want = oracle.submit(ids, max_new_tokens=12)
             got = paged.submit(ids, max_new_tokens=12)
             assert got == want, (ids, got, want)
         assert paged.pager.used_pages == 0  # everything freed
     finally:
-        dense.stop()
         paged.stop()
 
 
 def test_paged_concurrent_staggered(model_and_params):
     """Continuous batching on the paged cache: staggered arrivals share
-    the running batch and still match the dense engine."""
+    the running batch and still match the dense whole-batch path."""
     model, params = model_and_params
-    dense, paged = _dense_and_paged(model, params, max_batch=3)
+    oracle, paged = _oracle_and_paged(model, params, max_batch=3)
     rng = np.random.default_rng(1)
     prompts = _prompts(rng, 7)
     want = {}
@@ -146,13 +143,12 @@ def test_paged_concurrent_staggered(model_and_params):
     threads = [threading.Thread(target=worker, args=(i,)) for i in range(7)]
     try:
         for i, ids in enumerate(prompts):
-            want[i] = dense.submit(ids, max_new_tokens=16)
+            want[i] = oracle.submit(ids, max_new_tokens=16)
         for t in threads:
             t.start()
         for t in threads:
             t.join(120)
     finally:
-        dense.stop()
         paged.stop()
     assert not errors, errors
     assert results == want
@@ -161,37 +157,34 @@ def test_paged_concurrent_staggered(model_and_params):
 
 def test_paged_prefix_cache_parity_and_reuse(model_and_params):
     """Automatic prefix caching on the paged cache: exact same tokens,
-    real reuse, and the stored-entry format interchangeable with dense
-    mode (extract gathers through the table, implant scatters)."""
+    real reuse (extract gathers through the table, implant scatters)."""
     model, params = model_and_params
-    dense, paged = _dense_and_paged(model, params, prefix=4)
+    oracle, paged = _oracle_and_paged(model, params, prefix=4)
     try:
         shared = [7] * 20
         tails = [[11, 12], [13, 14, 15], [16]]
         for tail in tails:
-            want = dense.submit(shared + tail, max_new_tokens=10)
+            want = oracle.submit(shared + tail, max_new_tokens=10)
             got = paged.submit(shared + tail, max_new_tokens=10)
             assert got == want, (tail, got, want)
         assert paged.stats["prefix_hits"] >= 2
         assert paged.stats["prefix_tokens_reused"] >= 32
     finally:
-        dense.stop()
         paged.stop()
 
 
 def test_paged_chunked_prefill_parity(model_and_params):
     model, params = model_and_params
-    dense, paged = _dense_and_paged(model, params, chunked=16,
-                                    pool_tokens=16 * 24)
+    oracle, paged = _oracle_and_paged(model, params, chunked=16,
+                                     pool_tokens=16 * 24)
     try:
         rng = np.random.default_rng(3)
         for ids in _prompts(rng, 4, lo=20, hi=45):
-            want = dense.submit(ids, max_new_tokens=8)
+            want = oracle.submit(ids, max_new_tokens=8)
             got = paged.submit(ids, max_new_tokens=8)
             assert got == want, (len(ids), got, want)
         assert paged.stats["prefill_pieces"] > 4  # really chunked
     finally:
-        dense.stop()
         paged.stop()
 
 
@@ -207,15 +200,14 @@ def test_paged_sliding_window_and_gqa(model_and_params):
         params = model.init(
             jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
         )["params"]
-        dense, paged = _dense_and_paged(model, params, cfg=variant)
+        oracle, paged = _oracle_and_paged(model, params, cfg=variant)
         try:
             rng = np.random.default_rng(5)
             for ids in _prompts(rng, 4, lo=6, hi=20):
-                want = dense.submit(ids, max_new_tokens=10)
+                want = oracle.submit(ids, max_new_tokens=10)
                 got = paged.submit(ids, max_new_tokens=10)
                 assert got == want, (variant.attn_window, got, want)
         finally:
-            dense.stop()
             paged.stop()
 
 
@@ -233,10 +225,7 @@ def test_page_backpressure_queues_and_completes(model_and_params):
         prefill_buckets=(32,), eos_id=EOS,
         kv_pool_tokens=16 * 9, page_size=16,
     ).start()
-    ref = LMEngine(
-        model, CFG, params, max_batch=8, max_seq=64, chunk_steps=4,
-        prefill_buckets=(32,), eos_id=EOS,
-    ).start()
+    ref = GenerateOracle(model, CFG, params, eos_id=EOS)
     rng = np.random.default_rng(7)
     prompts = _prompts(rng, 8, lo=17, hi=21)
     results: dict[int, list[int]] = {}
@@ -265,7 +254,6 @@ def test_page_backpressure_queues_and_completes(model_and_params):
         assert eng.stats["max_concurrent"] <= 4
     finally:
         eng.stop()
-        ref.stop()
 
 
 def test_paged_density_vs_dense_rectangle(model_and_params):
@@ -331,7 +319,7 @@ def test_request_larger_than_pool_fails_fast(model_and_params):
 
 def test_tp_paged_engine_matches_unsharded():
     """TP serving + paged cache compose: pooled KV sharded over kv heads
-    on the model axis, same tokens as the unsharded dense engine."""
+    on the model axis, same tokens as the unsharded whole-batch path."""
     from jax.sharding import Mesh
 
     from kubeflow_tpu.parallel.sharding import transformer_rules
@@ -348,10 +336,7 @@ def test_tp_paged_engine_matches_unsharded():
     devs = np.asarray(jax.devices()[:4]).reshape(2, 2)
     mesh = Mesh(devs, ("data", "model"))
 
-    plain = LMEngine(
-        model, cfg, params, max_batch=2, max_seq=64, chunk_steps=2,
-        prefill_buckets=(32,), eos_id=EOS,
-    ).start()
+    plain = GenerateOracle(model, cfg, params, eos_id=EOS)
     sharded = LMEngine(
         model, cfg, params, max_batch=2, max_seq=64, chunk_steps=2,
         prefill_buckets=(32,), eos_id=EOS,
@@ -370,7 +355,6 @@ def test_tp_paged_engine_matches_unsharded():
             b = sharded.submit(ids, max_new_tokens=10)
             assert a == b, (ids, a, b)
     finally:
-        plain.stop()
         sharded.stop()
 
 

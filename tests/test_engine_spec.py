@@ -1,8 +1,8 @@
 """In-graph speculative decoding (serve/speculative.py + engine spec mode):
 SPECULATION IS A SCHEDULING OPTIMIZATION, NEVER A NUMERICS CHANGE. Greedy
-decode with ``spec_draft_tokens=K`` must be byte-identical to K=0 (which is
-itself pinned to the whole-batch generate path) across dense/paged ×
-inline/pipelined, under admission churn, chunked prefill, prefix caching
+decode with ``spec_draft_tokens=K`` must be byte-identical to K=0 and to
+the whole-batch generate path, on the derived pool and on a small named
+one × inline/pipelined, under admission churn, chunked prefill, prefix caching
 and cancellation; temperature>0 must be seed-deterministic via the
 distribution-preserving rejection rule."""
 
@@ -15,10 +15,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import GenerateOracle
 
 from kubeflow_tpu.models.transformer import TransformerConfig, TransformerLM
 from kubeflow_tpu.serve.engine import LMEngine
-from kubeflow_tpu.serve.generate import make_generate_fn
 
 CFG = TransformerConfig(
     vocab_size=89, d_model=32, n_layers=2, n_heads=4, d_ff=64,
@@ -43,13 +43,15 @@ def _prompts(rng, n, lo=3, hi=20):
     ]
 
 
-def _mk(model, params, *, spec=4, paged=False, depth=1, **kw):
+def _mk(model, params, *, spec=4, small_pool=False, depth=1, **kw):
+    """``small_pool``: 16-token pages in a pool under max_batch × max_seq,
+    so spans cross pages and rows wait for pages; else the derived pool."""
     base = dict(
         max_batch=3, max_seq=96, chunk_steps=4, prefill_buckets=(32,),
         eos_id=EOS, pipeline_depth=depth, spec_draft_tokens=spec, seed=7,
     )
     base.update(kw)
-    if paged:
+    if small_pool:
         base.setdefault("kv_pool_tokens", 16 * 20)
         base.setdefault("page_size", 16)
     return LMEngine(model, CFG, params, **base).start()
@@ -100,24 +102,30 @@ def test_propose_draft_prefers_recent_full_window():
 
 def test_spec_greedy_byte_identical_all_modes(model_and_params):
     """The tentpole contract: spec_draft_tokens=4 produces byte-identical
-    greedy token streams to spec_draft_tokens=0 across dense/paged ×
-    inline/pipelined — including prompts engineered to draft heavily
-    (repetitive) and prompts that rarely match."""
+    greedy token streams to spec_draft_tokens=0 and to the whole-batch
+    path, on the derived and on a small pool × inline/pipelined —
+    including prompts engineered to draft heavily (repetitive) and
+    prompts that rarely match."""
     model, params = model_and_params
     rng = np.random.default_rng(0)
     prompts = _prompts(rng, 4) + [[7, 8, 9] * 6, [11, 12] * 9]
+    oracle = GenerateOracle(model, CFG, params, eos_id=EOS)
+    want = {i: oracle.submit(p, max_new_tokens=12) for i, p in enumerate(prompts)}
     base = _mk(model, params, spec=0)
     try:
-        want = {i: base.submit(p, max_new_tokens=12) for i, p in enumerate(prompts)}
+        for i, p in enumerate(prompts):
+            assert base.submit(p, max_new_tokens=12) == want[i], i
     finally:
         base.stop()
-    for paged in (False, True):
+    for small_pool in (False, True):
         for depth in (0, 1):
-            eng = _mk(model, params, spec=4, paged=paged, depth=depth)
+            eng = _mk(
+                model, params, spec=4, small_pool=small_pool, depth=depth
+            )
             try:
                 for i, p in enumerate(prompts):
                     got = eng.submit(p, max_new_tokens=12)
-                    assert got == want[i], (paged, depth, i, got, want[i])
+                    assert got == want[i], (small_pool, depth, i, got, want[i])
                 assert eng.stats["spec_proposed"] >= 0
             finally:
                 eng.stop()
@@ -127,20 +135,12 @@ def test_spec_matches_whole_batch_reference(model_and_params):
     """Speculative completions equal the pinned make_generate_fn path —
     not just the non-spec engine (no shared-bug blind spot)."""
     model, params = model_and_params
-    gen = jax.jit(
-        make_generate_fn(model, CFG, max_new_tokens=12, eos_id=EOS)
-    )
+    oracle = GenerateOracle(model, CFG, params, eos_id=EOS)
     eng = _mk(model, params, spec=4)
     try:
         rng = np.random.default_rng(3)
         for ids in _prompts(rng, 5):
-            prompt = np.zeros((1, 32), np.int32)
-            prompt[0, : len(ids)] = ids
-            toks, n_valid = gen(
-                params, prompt, np.asarray([len(ids)], np.int32),
-                jax.random.PRNGKey(7), np.zeros((1,), np.float32),
-            )
-            want = [int(t) for t in np.asarray(toks)[0, : int(n_valid[0])]]
+            want = oracle.submit(ids, max_new_tokens=12)
             assert eng.submit(ids, max_new_tokens=12) == want, ids
     finally:
         eng.stop()
@@ -357,21 +357,29 @@ def test_spec_config_validation(model_and_params):
     assert eng.spec_k == 0
 
 
-def test_spec_dense_headroom_enforced_at_enqueue(model_and_params):
-    """Dense spec reserves K scratch KV slots: a request that fits without
-    them but not with them must fail fast at submit."""
+def test_spec_needs_no_headroom(model_and_params):
+    """A row may fill max_seq to its last token with speculation on: span
+    positions past the budgeted region write to the scratch page, so no K
+    slots are reserved and the stream is the one K=0 gives. The eos id
+    is outside the vocabulary: every row runs its whole budget, so the
+    last spans really do reach past max_seq."""
     model, params = model_and_params
-    eng = _mk(
-        model, params, spec=4, max_batch=1, max_seq=40,
-        prefill_buckets=(32,),
-    )
-    try:
-        with pytest.raises(ValueError, match="spec_draft_tokens"):
-            eng.submit([3, 4, 5], max_new_tokens=8)  # 32+8+4 > 40
-        out = eng.submit([3, 4, 5], max_new_tokens=4)  # 32+4+4 ≤ 40
-        assert isinstance(out, list)
-    finally:
-        eng.stop()
+    ids, max_seq = [3, 4, 5] * 3, 40
+    budget = max_seq - len(ids)
+    want = GenerateOracle(model, CFG, params, eos_id=97).submit(ids, budget)
+    assert len(want) == budget
+    for spec in (0, 4):
+        eng = _mk(
+            model, params, spec=spec, max_batch=1, max_seq=max_seq,
+            page_size=16, eos_id=97,
+        )
+        try:
+            assert eng.submit(ids, max_new_tokens=budget) == want, spec
+            with pytest.raises(ValueError, match="exceeds engine max_seq"):
+                eng.submit(ids, max_new_tokens=budget + 1)
+            assert eng.pager.used_pages == 0
+        finally:
+            eng.stop()
 
 
 def test_spec_engine_model_warmup_resets_spec_metrics(model_and_params):

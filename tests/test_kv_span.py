@@ -71,7 +71,8 @@ PAGED_INT8 = {**PAGED, "kv_quant": "int8"}
 
 
 @pytest.mark.parametrize(
-    "mode", [{}, PAGED, PAGED_INT8], ids=["dense", "paged", "paged-int8"]
+    "mode", [{}, PAGED, PAGED_INT8],
+    ids=["derived-pool", "small-pool", "small-pool-int8"],
 )
 def test_disagg_parity_decode_runs_zero_prefill(model_and_params, mode):
     ref = _engine(model_and_params, **mode)

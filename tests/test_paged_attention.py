@@ -298,18 +298,24 @@ def test_kernel_parity_churn_chunked_prefix_cancel(model_and_params):
     assert got_stats["prefix_hits"] >= 1  # the resubmit hit the cache
 
 
-def test_kernel_requires_paged_cache(model_and_params):
+def test_kernel_and_int8_need_no_pool_size_named(model_and_params):
+    """One cache layout: the kernel read path and the int8 pool run on the
+    pool the engine derives when no ``kv_pool_tokens`` is given (they
+    used to be refused there); unknown values are still refused."""
     model, params = model_and_params
-    with pytest.raises(ValueError, match="paged"):
-        LMEngine(
-            model, CFG, params, max_batch=2, max_seq=64, chunk_steps=4,
-            eos_id=EOS, paged_attn_impl="kernel",
-        )
-    with pytest.raises(ValueError, match="paged"):
-        LMEngine(
-            model, CFG, params, max_batch=2, max_seq=64, chunk_steps=4,
-            eos_id=EOS, kv_quant="int8",
-        )
+    kernel = LMEngine(
+        model, CFG, params, max_batch=2, max_seq=64, chunk_steps=4,
+        eos_id=EOS, paged_attn_impl="kernel",
+    )
+    assert kernel.engine_config.paged_attn_impl == "kernel"
+    int8 = LMEngine(
+        model, CFG, params, max_batch=2, max_seq=64, chunk_steps=4,
+        eos_id=EOS, kv_quant="int8",
+    )
+    layer = next(iter(int8.cache.values()))
+    assert layer["k"].dtype == jnp.int8 and "k_scale" in layer
+    for eng in (kernel, int8):
+        assert eng.pager.stats()["pages_total"] == 2  # one 64-token page a row
     with pytest.raises(ValueError, match="paged_attn_impl"):
         LMEngine(
             model, CFG, params, max_batch=2, max_seq=64, chunk_steps=4,

@@ -67,6 +67,31 @@ def _engine(model, params, **kw):
     return LMEngine(model, CFG, params, **kw).start()
 
 
+def _wedge(eng, hold_s=30.0):
+    """``wedge_engine`` plus an event that is set once the scheduler thread
+    stands in the stall — what a test waits for before it counts on the
+    loop being wedged. Returns ``(release, caught)``."""
+    from kubeflow_tpu.chaos.injectors import wedge_engine
+
+    release = wedge_engine(eng, hold_s=hold_s)
+    stall = eng._fault_hooks["pre_chunk"]
+    caught = threading.Event()
+
+    def hook(e):
+        caught.set()
+        stall(e)
+
+    eng._fault_hooks["pre_chunk"] = hook
+    return release, caught
+
+
+def _wait_until(cond, timeout_s=30.0):
+    deadline = time.monotonic() + timeout_s
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    return cond()
+
+
 def _metric(name, **labels):
     m = REGISTRY._metrics.get(name)
     if m is None:
@@ -135,11 +160,11 @@ def test_stream_deadline_is_end_to_end_not_per_item(model_and_params):
 def test_queued_past_deadline_never_admitted(model_and_params):
     """A request whose deadline expires while it waits in the admission
     queue is retired there — it must never cost a decode slot."""
-    from kubeflow_tpu.chaos.injectors import wedge_engine
-
     model, params = model_and_params
-    eng = _engine(model, params, max_batch=1)
-    release = wedge_engine(eng, hold_s=30.0)
+    # eos_id outside the vocab: the blocker cannot EOS-retire in its
+    # prefill and leave the loop with no chunk to wedge on
+    eng = _engine(model, params, max_batch=1, eos_id=97)
+    release, caught = _wedge(eng)
     try:
         q0 = _metric("kft_engine_deadline_expired_total", stage="queued")
         # occupy the single row, then wedge the next chunk
@@ -153,15 +178,9 @@ def test_queued_past_deadline_never_admitted(model_and_params):
 
         t = threading.Thread(target=blocker, daemon=True)
         t.start()
-        # wait until the wedge hook has actually caught the loop
-        deadline = time.monotonic() + 10
-        while eng._fault_hooks and time.monotonic() < deadline:
-            if not eng.busy():
-                time.sleep(0.01)
-                continue
-            break
-        time.sleep(0.2)  # let the loop run into the wedge
+        assert caught.wait(120)  # the loop stands in the wedge
         admitted0 = eng.stats["admitted"]
+        assert admitted0 == 1
         victim_err: list = []
 
         def victim():
@@ -172,12 +191,18 @@ def test_queued_past_deadline_never_admitted(model_and_params):
 
         tv = threading.Thread(target=victim, daemon=True)
         tv.start()
-        time.sleep(0.5)  # victim's deadline passes while queued
-        release()
-        tv.join(30)
+        tv.join(30)  # the victim's own wait gives up at its deadline
+        assert not tv.is_alive()
+        release()    # only now can the loop look at the queue again
         t.join(60)
         assert victim_err and isinstance(victim_err[0], DeadlineExceeded)
         assert not blocker_err, blocker_err
+        # the loop polls the queue once the blocker's row is free — after
+        # the blocker's submit has returned: wait for its verdict
+        assert _wait_until(
+            lambda: eng.stats["deadline_expired_queued"]
+            or eng.stats["admitted"] > admitted0
+        )
         # the victim was never admitted: no decode slot consumed
         assert eng.stats["admitted"] == admitted0
         assert eng.stats["deadline_expired_queued"] == 1
@@ -272,11 +297,10 @@ def test_priority_evicts_lowest_queued_under_overload(model_and_params):
     """Sustained overload sheds the lowest-priority QUEUED request to
     admit a higher-priority one; equal/lower priority newcomers still get
     EngineOverloaded."""
-    from kubeflow_tpu.chaos.injectors import wedge_engine
-
     model, params = model_and_params
-    eng = _engine(model, params, max_batch=1, max_queue=2)
-    release = wedge_engine(eng, hold_s=30.0)
+    # eos_id outside the vocab: see test_queued_past_deadline_never_admitted
+    eng = _engine(model, params, max_batch=1, max_queue=2, eos_id=97)
+    release, caught = _wedge(eng)
     results: dict[str, Exception | list] = {}
 
     def bg(key, ids, prio):
@@ -294,13 +318,13 @@ def test_priority_evicts_lowest_queued_under_overload(model_and_params):
 
     try:
         t1 = bg("active", [5, 6, 7], 0)   # takes the single row
-        time.sleep(0.3)                   # loop admits it, then wedges
+        assert caught.wait(120)           # loop admitted it, then wedged
         t2 = bg("low", [8, 9], 0)         # queued, priority 0
         t3 = bg("mid", [9, 10], 1)        # queued, priority 1 → capacity full
-        time.sleep(0.2)
+        assert _wait_until(lambda: eng._pending.qsize() == 2)
         # a priority-3 newcomer evicts the LOWEST queued (priority 0)
         t4 = bg("high", [11, 12], 3)
-        time.sleep(0.3)
+        assert _wait_until(lambda: "low" in results)
         assert isinstance(results.get("low"), AdmissionShed)
         assert results["low"].reason == "priority_evict"
         # equal-priority newcomer has no one below it: bare overload
@@ -357,10 +381,12 @@ def _loaded_engine_model(model, params, name="lm", **kw):
     from kubeflow_tpu.serve.engine import LMEngineModel
     from kubeflow_tpu.serve.model import BucketSpec
 
+    # eos_id outside the vocab: every completion has its whole budget of
+    # tokens, so "the rebuilt engine serves" is never an empty stream
     m = LMEngineModel(
         name, None, config=CFG, max_batch=2, chunk_steps=2,
         buckets=BucketSpec(batch_sizes=(1,), seq_lens=(32,)),
-        max_new_tokens=8, eos_id=EOS, **kw,
+        max_new_tokens=8, eos_id=97, **kw,
     )
     m.load()
     m._params = jax.device_put(params)
@@ -373,8 +399,6 @@ def test_watchdog_trips_on_wedged_chunk_and_restarts(model_and_params):
     """Fake-clock trip: a wedged chunk (stale heartbeat + work pending)
     flips readiness, fails the in-flight request with the RETRYABLE
     EngineRestarting, rebuilds the engine, and restores readiness."""
-    from kubeflow_tpu.chaos.injectors import wedge_engine
-
     model, params = model_and_params
     m = _loaded_engine_model(model, params, name="wd-wedge", watchdog=False)
     now = [0.0]
@@ -394,7 +418,7 @@ def test_watchdog_trips_on_wedged_chunk_and_restarts(model_and_params):
     )
     r0 = _metric("kft_engine_restarts_total", model="wd-wedge")
     old_engine = m.engine
-    release = wedge_engine(old_engine, hold_s=20.0)
+    release, caught = _wedge(old_engine, hold_s=20.0)
     errs: list = []
 
     def caller():
@@ -406,14 +430,10 @@ def test_watchdog_trips_on_wedged_chunk_and_restarts(model_and_params):
     t = threading.Thread(target=caller, daemon=True)
     t.start()
     try:
-        # wait (bounded) for the loop to be demonstrably wedged: work
-        # exists and the heartbeat has stopped advancing
-        spin = time.monotonic() + 20
-        while time.monotonic() < spin:
-            beat = old_engine.heartbeat()
-            time.sleep(0.1)
-            if old_engine.busy() and old_engine.heartbeat() == beat:
-                break
+        # the loop is demonstrably wedged: it stands in the stall with
+        # work pending, so the heartbeat has stopped advancing
+        assert caught.wait(120)
+        assert old_engine.busy()
         # below threshold: no trip
         now[0] = old_engine.heartbeat() + 1.0
         assert wd.tick() is None

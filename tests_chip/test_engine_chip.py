@@ -48,9 +48,10 @@ def test_flash_s512_fwd_bwd_parity_bf16():
         )
 
 
-def test_engine_on_chip_matches_batch_generate():
-    """Continuous batching end-to-end on the real chip: bf16 flash model,
-    engine answers equal the whole-batch path, prefix reuse included."""
+def _bf16_flash_model_and_reference():
+    """The bf16 flash model both engine tests serve, and the whole-batch
+    path (``make_generate_fn`` on a dense rectangle) on the same weights:
+    the reference an engine's greedy stream must equal."""
     import jax
     import jax.numpy as jnp
 
@@ -58,7 +59,6 @@ def test_engine_on_chip_matches_batch_generate():
         TransformerConfig,
         TransformerLM,
     )
-    from kubeflow_tpu.serve.engine import LMEngine
     from kubeflow_tpu.serve.generate import make_generate_fn
 
     cfg = TransformerConfig(
@@ -81,6 +81,16 @@ def test_engine_on_chip_matches_batch_generate():
         )
         return [int(t) for t in np.asarray(toks)[0, : int(n_valid[0])]]
 
+    return model, cfg, params, reference
+
+
+def test_engine_on_chip_matches_batch_generate():
+    """Continuous batching end-to-end on the real chip: bf16 flash model,
+    engine (no pool size named: the derived pool) answers equal the
+    whole-batch path, prefix reuse included."""
+    from kubeflow_tpu.serve.engine import LMEngine
+
+    model, cfg, params, reference = _bf16_flash_model_and_reference()
     eng = LMEngine(
         model, cfg, params, max_batch=4, max_seq=256, chunk_steps=4,
         prefill_buckets=(128,), eos_id=1, prefix_cache_entries=4,
@@ -100,31 +110,13 @@ def test_engine_on_chip_matches_batch_generate():
 
 def test_paged_engine_on_chip_matches_dense():
     """Paged KV (block-table scatter/gather) compiled for real TPU — the
-    path CPU interpret mode cannot exercise. Paged completions must equal
-    the dense engine's on the same bf16 flash model, prefix reuse and
-    page backpressure included."""
-    import jax
-    import jax.numpy as jnp
-
-    from kubeflow_tpu.models.transformer import (
-        TransformerConfig,
-        TransformerLM,
-    )
+    path CPU interpret mode cannot exercise — on a pool named small enough
+    that pages bite. Completions must equal the whole-batch path's (the
+    dense rectangle of ``make_generate_fn``) on the same bf16 flash model,
+    prefix reuse and page backpressure included."""
     from kubeflow_tpu.serve.engine import LMEngine
 
-    cfg = TransformerConfig(
-        vocab_size=512, d_model=256, n_layers=2, n_heads=8, d_ff=512,
-        attn_impl="flash", dtype=jnp.bfloat16,
-    )
-    model = TransformerLM(cfg)
-    params = model.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
-    )["params"]
-
-    dense = LMEngine(
-        model, cfg, params, max_batch=4, max_seq=256, chunk_steps=4,
-        prefill_buckets=(128,), eos_id=1, prefix_cache_entries=4,
-    ).start()
+    model, cfg, params, reference = _bf16_flash_model_and_reference()
     # pool sized so 4 concurrent (40+12)-token rows force real paging
     paged = LMEngine(
         model, cfg, params, max_batch=4, max_seq=256, chunk_steps=4,
@@ -138,12 +130,10 @@ def test_paged_engine_on_chip_matches_dense():
             ids = base[:32] + [
                 int(x) for x in rng.integers(2, 512, size=tail_len)
             ]
-            want = dense.submit(ids, max_new_tokens=12)
             got = paged.submit(ids, max_new_tokens=12)
-            assert got == want, (tail_len, got, want)
+            assert got == reference(ids), (tail_len, got)
         assert paged.stats["prefix_hits"] >= 1
         assert paged.stats["kv_pages_used_peak"] >= 1
         assert paged.pager.used_pages == 0  # all freed
     finally:
-        dense.stop()
         paged.stop()
