@@ -606,11 +606,14 @@ async def _serve(cfg: ServePhaseConfig) -> dict[str, Any]:
             raise RuntimeError(f"/metrics shows no engine work: {served}")
 
         # ---- read paths: kernel == gather, int8 kernel == int8 gather -- #
+        # the engine chooses the read path itself: the Pallas kernel for
+        # the decode step on this backend, the gather under a mesh — here
+        # a mesh of one device, which shards nothing
         pprompts = _prompts(
             cfg.parity_prompt_lens, cfg.lm.vocab_size, cfg.seed + 1
         )
-        gather = await off_loop(
-            _engine_answers, lm.engine, pprompts, cfg.max_new_tokens
+        one_device = jax.sharding.Mesh(
+            np.asarray(jax.devices()[:1]).reshape(1, 1), ("data", "model")
         )
 
         def parity_engine(**kw):
@@ -631,13 +634,18 @@ async def _serve(cfg: ServePhaseConfig) -> dict[str, Any]:
 
         answers = {}
         for name, kw in (
-            ("kernel", dict(paged_attn_impl="kernel")),
-            ("gather_int8", dict(kv_quant="int8")),
-            ("kernel_int8", dict(paged_attn_impl="kernel", kv_quant="int8")),
+            ("kernel", dict()),
+            ("gather", dict(mesh=one_device)),
+            ("kernel_int8", dict(kv_quant="int8")),
+            ("gather_int8", dict(mesh=one_device, kv_quant="int8")),
         ):
+            eng = parity_engine(**kw)
+            if eng.kernel_read != name.startswith("kernel"):
+                raise RuntimeError(
+                    f"{name}: the engine chose kernel_read={eng.kernel_read}"
+                )
             answers[name] = await off_loop(
-                _engine_answers, parity_engine(**kw), pprompts,
-                cfg.max_new_tokens,
+                _engine_answers, eng, pprompts, cfg.max_new_tokens,
             )
         cmp = dict(
             lm=cfg.lm, params=lm._params, prompts=pprompts,
@@ -645,7 +653,8 @@ async def _serve(cfg: ServePhaseConfig) -> dict[str, Any]:
         )
         read_paths = {
             "kernel_vs_gather": compare_streams(
-                "kernel-vs-gather", got=answers["kernel"], want=gather, **cmp
+                "kernel-vs-gather", got=answers["kernel"],
+                want=answers["gather"], **cmp
             ),
             "int8_kernel_vs_int8_gather": compare_streams(
                 "int8-kernel-vs-int8-gather", got=answers["kernel_int8"],
@@ -847,8 +856,14 @@ def _tensor_parallel_engine(cfg: ShardedPhaseConfig) -> dict[str, Any]:
         lambda eng, before: {
             "params": placement_report(eng.params, n, before),
             "kv_pool": placement_report(eng.cache, n, before),
+            "kernel_read": eng.kernel_read,
         },
     )
+    if placement.pop("kernel_read"):
+        raise RuntimeError(
+            "an engine under a mesh chose the Pallas read path, which is "
+            "not partitioned"
+        )
     # no mesh on a multi-device host: everything on the default device —
     # by design, one replica per chip
     one_device, where = answers_of(
@@ -863,7 +878,7 @@ def _tensor_parallel_engine(cfg: ShardedPhaseConfig) -> dict[str, Any]:
         tie_tol=cfg.tie_tol, pad_to=cfg.max_seq,
     )
     return {
-        "mesh": {"model": n}, "read_path": "gather",
+        "mesh": {"model": n}, "read_path": "gather",  # checked above
         "tokens": tokens, "placement": placement,
         "engine_without_mesh_uses_devices": where,
     }

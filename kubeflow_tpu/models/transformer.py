@@ -143,6 +143,10 @@ def _act_constraint(x: jax.Array, *, seq_dim: int = 1) -> jax.Array:
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or Axis.DATA not in mesh.axis_names:
         return x
+    if not {Axis.FSDP, Axis.SEQ} <= set(mesh.axis_names):
+        # not `build_mesh`'s mesh, which lays every axis (a serving
+        # engine's ("data", "model"), traced with it in force): no hint
+        return x
     spec = [None] * x.ndim
     spec[0] = (Axis.DATA, Axis.FSDP)
     spec[seq_dim] = Axis.SEQ
@@ -226,6 +230,70 @@ def _grouped_cache_attention(q, K, V, mask, groups):
     return o.reshape(B, H, S, D)
 
 
+#: the longest query span the paged branch reads through the Pallas
+#: kernel when the call holds more than one row — the decode step (1) and
+#: the speculative verify span (K + 1) of ``max_batch`` rows. Measured on
+#: a v5e at `mistral-7b_gen-closed`'s shape, kernel against gather
+#: (PERF.md section 6, PR 33): spans 1 to 16 of 32 rows read 3.5 to 9
+#: times faster through the kernel — the gather reads every row's share
+#: of the widest row's window, which mostly does not exist.
+PAGED_KERNEL_MAX_SPAN = 16
+#: the same for a call of one row — a prefill piece, or an engine of one
+#: row. One row's window is its own, so the gather wastes nothing: at 480
+#: and 1,000 keys the two read within 15 % of each other up to 8 queries
+#: (a table of one page: the kernel, 1.5 to 3.6 times), at 16 the gather
+#: is 1.2 to 1.35 times faster and at 32 and over 1.7 to 3 times.
+PAGED_KERNEL_MAX_SPAN_ONE_ROW = 8
+
+
+def paged_kernel_read(cfg: TransformerConfig, rows: int, span: int) -> bool:
+    """Whether the paged branch reads K and V through the Pallas kernel
+    (`ops/paged_attention.py`) or gathers the rows' windows: decided by
+    what the code can observe. The kernel where the backend is a TPU (or
+    the configuration asks for the interpreter, which is how the CPU
+    suites run it), no mesh is in force (a Mosaic kernel is not
+    partitioned automatically) and the call is a decode or verify shape:
+    a short span of several rows, or a shorter one of one row. The engine
+    asks the same question for its counter
+    (`stats["decode_chunks_kernel_read"]`)."""
+    limit = PAGED_KERNEL_MAX_SPAN if rows > 1 else PAGED_KERNEL_MAX_SPAN_ONE_ROW
+    return (
+        span <= limit
+        and (cfg.interpret_kernels or jax.default_backend() == "tpu")
+        and jax.sharding.get_abstract_mesh().empty
+    )
+
+
+def paged_gather_attention(q, cache, page_table, positions, *, page_size,
+                           window):
+    """The paged branch's XLA read: gather each row's first W logical
+    tokens (W = table width x page size) out of the token-major pool
+    ``cache`` (a layer's ``k`` / ``v``, with ``k_scale`` / ``v_scale``
+    when int8), mask by position and run grouped attention over all of
+    it. q (B, H, S, D), positions (B, S)."""
+    B, H, S, D = q.shape
+    P = page_size
+    Hkv = cache["k"].shape[1]
+    W = page_table.shape[1] * P
+    j = jnp.arange(W)
+    flat_r = (
+        page_table[:, j // P] * P + (j % P)[None, :]
+    ).reshape(-1)                                              # (B*W,)
+    Kg = cache["k"][flat_r].reshape(B, W, Hkv, D).transpose(0, 2, 1, 3)
+    Vg = cache["v"][flat_r].reshape(B, W, Hkv, D).transpose(0, 2, 1, 3)
+    if "k_scale" in cache:
+        # dequantize with the SAME broadcast multiply the kernel uses,
+        # so gather/kernel parity holds
+        Ksg = cache["k_scale"][:, flat_r].reshape(Hkv, B, W).transpose(1, 0, 2)
+        Vsg = cache["v_scale"][:, flat_r].reshape(Hkv, B, W).transpose(1, 0, 2)
+        Kg = dequantize_kv(Kg, Ksg)
+        Vg = dequantize_kv(Vg, Vsg)
+    mask = j[None, None, :] <= positions[:, :, None]           # (B,S,W)
+    if window is not None:
+        mask &= j[None, None, :] > positions[:, :, None] - window
+    return _grouped_cache_attention(q, Kg, Vg, mask, H // Hkv)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -241,7 +309,6 @@ class Attention(nn.Module):
         page_table=None,
         page_size=None,
         page_write_ok=None,
-        paged_attn_impl="gather",
         kv_quant="none",
     ):
         cfg = self.cfg
@@ -279,8 +346,6 @@ class Attention(nn.Module):
             # causal + sliding-window mask is just arithmetic on positions;
             # no kv_mask operand exists in this mode.
             P = page_size
-            n_pages_w = page_table.shape[1]
-            W = n_pages_w * P
             # scatter this call's keys/values into the pool. Pad positions
             # and dead rows route to the scratch page (0) via page_write_ok.
             wpage = jnp.take_along_axis(page_table, positions // P, axis=1)
@@ -334,13 +399,14 @@ class Attention(nn.Module):
                 new_cache = {"k": K, "v": V}
             else:
                 raise ValueError(f"unknown kv_quant {kv_quant!r}")
-            if paged_attn_impl == "kernel":
+            if paged_kernel_read(cfg, B, S):
                 # Pallas kernel read: the block table rides the grid as a
-                # scalar-prefetch operand and the pallas_call pipeline
-                # stages pages HBM→VMEM. Assumes contiguous span
-                # positions (positions[b] == positions[b, 0] + arange(S)),
-                # which holds for every engine caller — decode steps, the
-                # speculative verify span, and chunked-prefill pieces.
+                # scalar-prefetch operand and only the pages a row holds
+                # leave HBM, once, in the pool's own layout. Assumes
+                # contiguous span positions (positions[b] ==
+                # positions[b, 0] + arange(S)), which holds for every
+                # engine caller — decode steps, the speculative verify
+                # span, and chunked-prefill pieces.
                 o = paged_attention(
                     q,
                     new_cache["k"],
@@ -353,30 +419,10 @@ class Attention(nn.Module):
                     v_scale=new_cache.get("v_scale"),
                     interpret=cfg.interpret_kernels,
                 )
-            elif paged_attn_impl == "gather":
-                # gather each row's first W logical tokens back out
-                j = jnp.arange(W)
-                flat_r = (
-                    page_table[:, j // P] * P + (j % P)[None, :]
-                ).reshape(-1)                                      # (B*W,)
-                Kg = K[flat_r].reshape(B, W, Hkv, D).transpose(0, 2, 1, 3)
-                Vg = V[flat_r].reshape(B, W, Hkv, D).transpose(0, 2, 1, 3)
-                if kv_quant == "int8":
-                    # dequantize with the SAME broadcast multiply the
-                    # kernel uses, so gather/kernel parity holds
-                    Ksg = Ks[:, flat_r].reshape(Hkv, B, W).transpose(1, 0, 2)
-                    Vsg = Vs[:, flat_r].reshape(Hkv, B, W).transpose(1, 0, 2)
-                    Kg = dequantize_kv(Kg, Ksg)
-                    Vg = dequantize_kv(Vg, Vsg)
-                mask = j[None, None, :] <= positions[:, :, None]   # (B,S,W)
-                if cfg.attn_window is not None:
-                    mask &= j[None, None, :] > (
-                        positions[:, :, None] - cfg.attn_window
-                    )
-                o = _grouped_cache_attention(q, Kg, Vg, mask, groups)
             else:
-                raise ValueError(
-                    f"unknown paged_attn_impl {paged_attn_impl!r}"
+                o = paged_gather_attention(
+                    q, new_cache, page_table, positions, page_size=P,
+                    window=cfg.attn_window,
                 )
         elif layer_cache is not None:
             # Autoregressive decode path (SURVEY.md §2.2 "vLLM backend"
@@ -584,7 +630,6 @@ class Block(nn.Module):
         page_table=None,
         page_size=None,
         page_write_ok=None,
-        paged_attn_impl="gather",
         kv_quant="none",
     ):
         cfg = self.cfg
@@ -596,7 +641,7 @@ class Block(nn.Module):
                 layer_cache=layer_cache, cache_index=cache_index,
                 kv_mask=kv_mask, page_table=page_table,
                 page_size=page_size, page_write_ok=page_write_ok,
-                paged_attn_impl=paged_attn_impl, kv_quant=kv_quant,
+                kv_quant=kv_quant,
             )
         else:
             h = Attention(cfg, name="attn")(attn_in, positions, segment_ids)
@@ -632,7 +677,6 @@ class TransformerLM(nn.Module):
         page_table=None,
         page_size=None,
         page_write_ok=None,
-        paged_attn_impl="gather",
         kv_quant="none",
     ):
         """Training/scoring: ``(tokens) -> logits``. Autoregressive serving:
@@ -681,7 +725,6 @@ class TransformerLM(nn.Module):
                     page_table=page_table,
                     page_size=page_size,
                     page_write_ok=page_write_ok,
-                    paged_attn_impl=paged_attn_impl,
                     kv_quant=kv_quant,
                 )
             else:

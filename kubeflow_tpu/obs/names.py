@@ -192,9 +192,6 @@ ENGINE_SPEC_ACCEPTANCE = "kft_engine_spec_acceptance"
 #: int8 KV-cache quantization (ops/paged_attention.py): EWMA of the
 #: mean-abs relative quantization error measured at prefill writes
 ENGINE_KV_QUANT_ERROR = "kft_engine_kv_quant_error"
-#: gauge — 1 while the engine's paged read path runs the Pallas kernel
-#: (LMEngineConfig paged_attn_impl="kernel"), 0 for the XLA gather
-ENGINE_PAGED_ATTN_KERNEL = "kft_engine_paged_attn_kernel"
 #: disaggregated prefill/decode (serve/engine.py prefill_span / inject):
 #: counter{model,direction} — bytes of per-request KV spans shipped over
 #: the wire (direction: export on the prefill replica, import on decode)

@@ -1,5 +1,6 @@
 """Flash-attention tile geometry (a rule on the observed shape) and the
-sweep tool that measured it; plus the paged kernel's page-size table.
+sweep tool that measured it; the paged kernel's geometry, its sweep tool
+and its page-size table.
 
 The three flash kernels (forward, dq, dkv) each take a :class:`Tile`: how
 many q and kv rows one grid step stages, the width of the sub-tile its
@@ -20,8 +21,12 @@ and the VPU, not the step, set the time.
 ``block_k`` are honoured as the staged block of every kernel
 (:func:`geometry_from_blocks`), which is what the ring hops pass.
 
-The table file (``ops/flash_blocks_v5e.json``, override
-``KFT_FLASH_BLOCKS_FILE``) now serves the paged kernel's page size only.
+The paged decode kernel (`ops/paged_attention.py`) takes a
+:class:`PagedTile` from :func:`select_paged_geometry` the same way — pages
+a grid step, pages a softmax update — measured with
+:func:`sweep_paged_geometry` at the serving cell's shape (PERF.md section
+6, PR 33). The table file (``ops/flash_blocks_v5e.json``, override
+``KFT_FLASH_BLOCKS_FILE``) serves the paged kernel's page size only.
 """
 
 from __future__ import annotations
@@ -192,16 +197,16 @@ def resolve_blocks(q, k, block_q, block_k) -> tuple[int, int]:
     return block_q, block_k
 
 
-def kernel_ms_from_trace(xplane_path: str, steps: int) -> dict[str, float]:
-    """Device milliseconds per step of each flash kernel in a profile:
-    ``{"flash_fwd_q512_...": ms, ...}`` — the durations of the device's
-    ``XLA Ops`` events whose text names a flash kernel, summed over the
-    trace and divided by ``steps``."""
+def device_ms_from_trace(xplane_path: str, steps: int) -> dict[str, float]:
+    """Device milliseconds per step in a profile, by operation: the
+    durations of the first TPU's ``XLA Ops`` events, summed over the
+    trace and divided by ``steps``, keyed by each instruction's own name
+    (not its operands': a copy of a kernel's result names the kernel too)
+    with its trailing ``.N`` dropped."""
     import re
 
     from jax.profiler import ProfileData
 
-    name = re.compile(r"flash_(?:fwd|dq|dkv)_q\d+_k\d+_t\d+_h\d+")
     total: dict[str, float] = {}
     for plane in ProfileData.from_file(xplane_path).planes:
         if not plane.name.startswith("/device:TPU:0"):
@@ -210,14 +215,25 @@ def kernel_ms_from_trace(xplane_path: str, steps: int) -> dict[str, float]:
             if line.name != "XLA Ops":
                 continue
             for e in line.events:
-                # the instruction's own name, not its operands' (a copy
-                # of a kernel's result names the kernel too)
-                m = name.search(e.name.partition(" = ")[0])
-                if m:
-                    total[m.group(0)] = (
-                        total.get(m.group(0), 0.0) + e.duration_ns * 1e-6
-                    )
+                name = re.sub(
+                    r"\.\d+$", "", e.name.partition(" = ")[0].lstrip("%")
+                )
+                total[name] = total.get(name, 0.0) + e.duration_ns * 1e-6
     return {k: v / steps for k, v in sorted(total.items())}
+
+
+def kernel_ms_from_trace(xplane_path: str, steps: int) -> dict[str, float]:
+    """Device milliseconds per step of each flash kernel in a profile:
+    ``{"flash_fwd_q512_...": ms, ...}``."""
+    import re
+
+    name = re.compile(r"flash_(?:fwd|dq|dkv)_q\d+_k\d+_t\d+_h\d+")
+    total: dict[str, float] = {}
+    for op, ms in device_ms_from_trace(xplane_path, steps).items():
+        m = name.search(op)
+        if m:
+            total[m.group(0)] = total.get(m.group(0), 0.0) + ms
+    return dict(sorted(total.items()))
 
 
 def sweep_blocks(
@@ -291,8 +307,188 @@ def sweep_blocks(
 
 
 # --------------------------------------------------------------------- #
-# paged decode-attention page size (ops/paged_attention.py)
+# paged decode-attention (ops/paged_attention.py): geometry, page size
 # --------------------------------------------------------------------- #
+
+class PagedTile(NamedTuple):
+    """The paged kernel's work per grid step."""
+
+    pages: int  #: pool pages (of every kv head) one step stages and computes
+    #: pages that share one softmax update, each read in one product over
+    #: all kv heads (the page as (page * kv_heads, D) rows, scores masked
+    #: to the query's own head); 0 = a product per head, page by page.
+    #: Divides ``pages``. The group is computed if any of its pages is
+    #: live, so it is also the granularity at which dead pages are skipped
+    fold: int
+
+
+#: K and V pages a step may stage, in bytes, both double-buffered: half
+#: of the 16 MiB a kernel gets without asking
+_PAGED_STAGED_BYTES = 8 << 20
+#: page operands a step: each is a block of the pool with an index map of
+#: its own, traced on its own when the program is built (sixteen pages a
+#: step cost a v5e's host about a second of every start)
+_PAGED_MAX_PAGES = 16
+#: score elements one softmax update of the folded body takes on by
+#: choice: query rows x pages x (page x kv_heads) keys. Four pages an
+#: update while they fit, else two (ms a layer at the cell's shape, 32
+#: rows, pages an update 1 | 2 | 4: one query 0.123 | 0.098 | 0.088, two
+#: 0.125 | 0.105 | 0.098, four 0.156 | 0.131 | 0.125, six 0.184 | 0.162 |
+#: 0.168, eight 0.200 | 0.173 | 0.185, sixteen 0.324 | 0.280 | 0.309; a
+#: product per head: one 0.259, four 0.295, eight 0.261, sixteen 0.336)
+_PAGED_SCORE_ELEMS = 1 << 18
+#: and the most a two-page update takes on before the body goes a head at
+#: a time: 2 MB of f32 scores — a span of 16 at the cell's heads, the
+#: longest measured and the longest the engine sends here
+#: (`transformer.paged_kernel_read`); a direct call with a longer one (a
+#: 512-token piece) still compiles, a head at a time
+_PAGED_FOLD_MAX_ELEMS = 1 << 19
+#: products a step of the per-head body unrolls (pages x kv heads): each is
+#: traced and compiled on its own — sixteen pages of eight heads of a
+#: 512-token piece took the compiler seven minutes — and more buy little
+#: (ms a layer, 16 pages against 1: decode 0.259 | 0.288, a 512-token
+#: piece 0.407 | 0.346)
+_PAGED_HEAD_PRODUCTS = 16
+
+
+def select_paged_geometry(
+    *,
+    table_pages: int,
+    page_size: int,
+    kv_heads: int,
+    groups: int,
+    span: int,
+    head_dim: int,
+    itemsize: int = 2,
+    quant: bool = False,
+) -> PagedTile:
+    """Geometry of the paged kernel for a call's shape (measured on a v5e
+    at 32 rows x 16 pages of 64 x 8 kv heads x 4 x 128, bf16, with
+    :func:`sweep_paged_geometry`: PERF.md section 6, PR 33). A step takes
+    as many pages as fit: the table's width, a power of two, up to
+    ``_PAGED_MAX_PAGES`` operands and ``_PAGED_STAGED_BYTES`` of VMEM
+    (sixteen a step 0.094 ms a layer, eight 0.127, a page a step 0.176).
+    The kv heads are folded into one product a page, four pages sharing a
+    softmax update while their score tile stays within
+    ``_PAGED_SCORE_ELEMS`` and two beyond — every span the engine reads
+    through the kernel. A product per head, ``_PAGED_HEAD_PRODUCTS`` of
+    them a step, is for int8 pools (the scale planes lie heads-major, so
+    a page is dequantized a head at a time), a single kv head (nothing to
+    fold) and spans whose folded scores would not fit. The rows do not
+    enter: every row is a grid step of its own."""
+    page_bytes = 4 * page_size * kv_heads * head_dim * itemsize
+    cap = max(1, min(_PAGED_MAX_PAGES, _PAGED_STAGED_BYTES // page_bytes))
+    pages = 1
+    while pages * 2 <= min(cap, table_pages):
+        pages *= 2
+    page_scores = kv_heads * groups * span * page_size * kv_heads
+    if quant or kv_heads == 1 or 2 * page_scores > _PAGED_FOLD_MAX_ELEMS:
+        return PagedTile(
+            min(pages, max(1, _PAGED_HEAD_PRODUCTS // kv_heads)), 0
+        )
+    fold = 4 if 4 * page_scores <= _PAGED_SCORE_ELEMS else 2
+    return PagedTile(pages, min(pages, fold))
+
+
+def sweep_paged_geometry(
+    *,
+    contexts: tuple[int, ...],
+    table_pages: int,
+    gather,
+    page_size: int = 64,
+    kv_heads: int = 8,
+    groups: int = 4,
+    head_dim: int = 128,
+    span: int = 1,
+    window: int | None = None,
+    pool_pages: int | None = None,
+    candidates: tuple[PagedTile | None | str, ...] = (None, "gather"),
+    steps: int = 20,
+    logdir: str,
+) -> list[dict]:
+    """Time one layer's paged read — a bare jitted call, bf16 — on the
+    LIVE backend for each candidate: a :class:`PagedTile`, ``None`` (the
+    rule's choice) or ``"gather"``, the XLA read path, handed in as
+    ``gather`` (`models/transformer.py::paged_gather_attention`: this
+    package does not import the models). One row per entry of ``contexts``
+    (the keys the row holds, the span's own among them), pages drawn
+    without replacement from a pool of ``pool_pages``. Device
+    milliseconds per call come from a profile (a host timer would add the
+    dispatch); a kernel candidate after a ``"gather"`` one also reports
+    how far its result lies from the gather's. Run this on the chip; a
+    candidate the compiler refuses is reported with its error."""
+    import glob
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kubeflow_tpu.ops.paged_attention import paged_attention
+
+    rows = len(contexts)
+    pool_pages = pool_pages or 1 + rows * table_pages
+    pool_tokens = pool_pages * page_size
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(
+        kq, (rows, kv_heads * groups, span, head_dim), jnp.bfloat16
+    )
+    cache = {
+        "k": jax.random.normal(kk, (pool_tokens, kv_heads, head_dim), jnp.bfloat16),
+        "v": jax.random.normal(kv, (pool_tokens, kv_heads, head_dim), jnp.bfloat16),
+    }
+    table = jnp.asarray(
+        1 + np.random.default_rng(0).permutation(pool_pages - 1)[
+            : rows * table_pages
+        ].reshape(rows, table_pages).astype(np.int32)
+    )
+    pos0 = jnp.asarray(np.asarray(contexts, np.int32) - span)
+    positions = pos0[:, None] + jnp.arange(span)[None, :]
+    results, want = [], None
+    for i, cand in enumerate(candidates):
+        if cand == "gather":
+            fn = jax.jit(lambda q, cache: gather(
+                q, cache, table, positions, page_size=page_size, window=window,
+            ))
+            row = {"read": "gather"}
+        else:
+            tile = cand or select_paged_geometry(
+                table_pages=table_pages, page_size=page_size,
+                kv_heads=kv_heads, groups=groups, span=span,
+                head_dim=head_dim,
+            )
+            fn = jax.jit(lambda q, cache, tile=tile: paged_attention(
+                q, cache["k"], cache["v"], table, pos0, page_size=page_size,
+                window=window, tile=tile,
+            ))
+            row = {"read": "kernel", "tile": list(tile)}
+        try:
+            out = jax.block_until_ready(fn(q, cache))  # compile + warm
+            if cand == "gather":
+                want = out
+            elif want is not None:
+                row["max_abs_err_vs_gather"] = float(jnp.max(jnp.abs(
+                    out.astype(jnp.float32) - want.astype(jnp.float32)
+                )))
+            os.makedirs(logdir, exist_ok=True)
+            run_dir = tempfile.mkdtemp(prefix=f"cand{i}_", dir=logdir)
+            with jax.profiler.trace(run_dir):
+                for _ in range(steps):
+                    out = fn(q, cache)
+                jax.block_until_ready(out)
+            (path,) = glob.glob(
+                os.path.join(run_dir, "plugins", "profile", "*", "*.xplane.pb")
+            )
+            ms = device_ms_from_trace(path, steps)
+            row["ms"] = sum(ms.values())
+            row["ops"] = dict(
+                sorted(ms.items(), key=lambda kv: -kv[1])[:4]
+            )
+        except Exception as e:  # noqa: BLE001 — a refused tile is a result
+            row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+        results.append(row)
+    return results
+
 
 def select_paged_page_size(head_dim: int, default: int = 64) -> int:
     """Measured page size for the paged decode-attention kernel. One kv

@@ -12,29 +12,41 @@ gathers the row's whole pow2-bucketed window back into a dense
 ``(B, H, W, D)`` tensor and runs masked softmax attention on it (XLA
 gather; see `models/transformer.py`).  This module is the kernel form of
 that read: the block table rides the grid as a **scalar-prefetch
-operand**, so each kv grid step's BlockSpec index map picks the page to
+operand**, and each page operand's BlockSpec index map picks the page to
 stage —
 
-    ``lambda b, i, tbl, pos0: (tbl[b, i], 0, 0)``
+    ``lambda b, i, tbl, *_: (tbl[b, i * n + j], 0, 0)``
 
 — a ``(P, kv_heads, D)`` block: one page of ALL kv heads is one
-contiguous piece of the pool, fetched in one grid step — and the
-pallas_call pipeline itself performs the HBM→VMEM page fetch
-(double-buffered against compute), fused with online-softmax attention
-over the staged page.  One kv block == one pool page, which is why the
-sweepable "block size" for this kernel IS the engine's ``page_size``
-(`ops/flash_tuning.py` ``select_paged_page_size``).
+contiguous piece of the pool — and the pallas_call pipeline itself
+performs the HBM→VMEM page fetch (double-buffered against compute), fused
+with online-softmax attention over the staged pages. One grid step
+stages **n pages**: the pool is passed n times, operand j reading the
+table at ``i * n + j`` (`ops/flash_tuning.py` ``select_paged_geometry``
+chooses n from the call's shape — a grid of rows x pages, a page a step,
+costs more in steps than the pages' bytes). The table the kernel walks
+is resolved in front of the call: an entry the row's span cannot see
+(past its reach, before its window) names the pool's first page, and a
+block index that repeats from one step to the next is not fetched
+again — so only the pages a row holds leave HBM, once. The page size is
+the engine's (``select_paged_page_size``).
 
 Span support: queries are a contiguous (K+1)-position speculative verify
 span (or a prefill piece) starting at per-row position ``pos0[b]`` —
 query s sits at absolute position ``pos0[b] + s``.  The in-span causal
 mask (query s must not see the span's later keys)
 falls out of pure position arithmetic inside the tile mask here
-(key position ``i*P + lane`` is visible to query s iff it is ``<=
+(key position ``page*P + lane`` is visible to query s iff it is ``<=
 pos0 + s`` and inside the sliding window), so speculative verify needs
-no separate program.  GQA: a grid step holds the page for every kv head
-and loops over them; all ``H // kv_heads`` query heads of a group attend
-to their kv head's slice in-tile.
+no separate program.  GQA: a grid step holds its pages for every kv
+head. For every span the engine sends here (up to 16 queries a row) the
+heads are **folded** into one product a page (the page read as ``(P *
+kv_heads, D)`` rows, scores masked to the query's own head: the MXU's
+time is the K and V rows it takes in, which the fold does not multiply)
+and two or four pages share one softmax update; int8 pools — their
+scale planes lie heads-major — and a direct call with a longer span loop
+over the kv heads, all ``H // kv_heads`` query heads of a group
+attending to their kv head's slice in-tile.
 
 int8 KV: ``quantize_kv`` produces per-token-per-head symmetric int8
 codes plus an f32 scale per (kv_head, token) vector; the kernel
@@ -62,6 +74,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from kubeflow_tpu.ops.flash_tuning import PagedTile, select_paged_geometry
 
 NEG_INF = -1e30  # matches the gather path's masked-score fill
 
@@ -95,33 +109,51 @@ def dequantize_kv(codes: jax.Array, scale: jax.Array) -> jax.Array:
 # kernel
 # --------------------------------------------------------------------- #
 
+def _live_pages(pos0, *, page_size, span, window, table_pages):
+    """The page ordinals ``lo <= page <= hi`` (each ``(B,)`` int32) that
+    hold a key some query of the span at ``pos0`` may see: not wholly
+    past the span's last query (``page * P <= pos0 + S - 1``), inside the
+    table, not wholly before the earliest query's window (``page * P + P
+    - 1 >= pos0 - window + 1``). A dead row (position -1) has none.
+    Computed once in front of the call: the index maps and the kernel
+    read the two numbers, so a page that is not computed is not fetched
+    either and neither works the bounds out again."""
+    hi = jnp.minimum((pos0 + span - 1) // page_size, table_pages - 1)
+    if window is None:
+        return jnp.zeros_like(hi), hi
+    return jnp.maximum((pos0 - window + 1) // page_size, 0), hi
+
+
 def _paged_attn_kernel(
     # scalar prefetch (SMEM)
-    tbl_ref,    # (B, W) int32 page table
+    tbl_ref,    # (B, steps * n) int32 page table, dead entries 0
+    lo_ref,     # (B,) int32 first live page ordinal
+    hi_ref,     # (B,) int32 last live page ordinal
     pos0_ref,   # (B,) int32 span start positions
     # VMEM blocks
-    q_ref,      # (1, Hkv, G*S, D) — queries, GQA group folded into the span axis
-    k_ref,      # (P, Hkv, D) — the page picked by the index map, every kv head
-    v_ref,      # (P, Hkv, D)
-    ks_ref,     # (Hkv, 1, 1, P) f32 or None
-    vs_ref,     # (Hkv, 1, 1, P) f32 or None
-    o_ref,      # (1, Hkv, G*S, D)
-    # VMEM scratch
-    acc_ref,    # (Hkv, G*S, D) f32
-    m_ref,      # (Hkv, G*S, 1) f32
-    l_ref,      # (Hkv, G*S, 1) f32
-    *,
+    q_ref,      # folded: (1, Hkv*G*S, D); else (1, Hkv, G*S, D)
+    *refs,      # n K pages, n V pages (P, Hkv, D) each; when quantized n
+                # + n scale blocks (Hkv, 1, 1, P) f32; the output block
+                # (q's shape); scratch acc, m, l (f32)
     scale: float | None,
     window: int | None,
     page_size: int,
+    kv_heads: int,
     groups: int,
     span: int,
-    num_pages: int,
+    pages: int,
+    quant: bool,
+    fold: int,
 ):
+    n = pages
+    k_refs, v_refs, refs = refs[:n], refs[n:2 * n], refs[2 * n:]
+    if quant:
+        ks_refs, vs_refs, refs = refs[:n], refs[n:2 * n], refs[2 * n:]
+    o_ref, acc_ref, m_ref, l_ref = refs
     b = pl.program_id(0)
     i = pl.program_id(1)
-    P, G, S = page_size, groups, span
-    GS = G * S
+    P, Hkv, S = page_size, kv_heads, span
+    GS = groups * span
 
     @pl.when(i == 0)
     def _init():
@@ -130,64 +162,115 @@ def _paged_attn_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     pos0 = pos0_ref[b]  # SMEM scalar
-    first = i * P
-    # skip pages wholly past the span's last query...
-    run = first <= pos0 + S - 1
-    if window is not None:
-        # ...and, when windowed, pages wholly before the earliest
-        # query's window start
-        run = run & (first + P - 1 >= pos0 - window + 1)
+    d = q_ref.shape[-1]
+    if scale is None:
+        mult = 1.0 / jnp.sqrt(jnp.float32(d))  # gather-path spelling
+    else:
+        mult = jnp.float32(scale)
+    # operands go to the MXU in the pool's own type (a bf16 x bf16 product
+    # is exact in the f32 it accumulates in); dequantized int8 is f32
+    ctype = jnp.float32 if quant else jnp.promote_types(
+        q_ref.dtype, k_refs[0].dtype
+    )
 
-    @pl.when(run)
-    def _body():
-        d = q_ref.shape[-1]
-        if scale is None:
-            mult = 1.0 / jnp.sqrt(jnp.float32(d))  # gather-path spelling
-        else:
-            mult = jnp.float32(scale)
-        # absolute positions: row r of the GS axis is query s = r % S at
-        # position pos0 + s; lane j is key position first + j
-        kpos = first + jax.lax.broadcasted_iota(jnp.int32, (GS, P), 1)
-        qpos = pos0 + (jax.lax.broadcasted_iota(jnp.int32, (GS, P), 0) % S)
-        mask = kpos <= qpos
-        if window is not None:
-            mask &= kpos > qpos - window
-        for h in range(q_ref.shape[1]):  # static: one kv head at a time
-            q = q_ref[0, h].astype(jnp.float32)  # (GS, D)
-            k = k_ref[:, h, :].astype(jnp.float32)  # (P, D)
-            v = v_ref[:, h, :].astype(jnp.float32)
-            if ks_ref is not None:
-                k = k * ks_ref[h, 0].T
-                v = v * vs_ref[h, 0].T
-            s = jax.lax.dot_general(
-                q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+    def online_update(sl, s, mask, vs):
+        """Scores ``s`` (rows, keys) f32 of one or more pages — ``vs``
+        their V rows, page by page — into the running max, sum and
+        accumulator at ``sl``; masked lanes contribute EXACTLY 0 even
+        when the whole tile is masked (exp(s - m_cur) would be exp(0)=1
+        garbage at m==NEG_INF)."""
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[sl]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+        alpha = jnp.exp(m_prev - m_cur)
+        l_ref[sl] = l_ref[sl] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        p = p.astype(vs[0].dtype)
+        keys = p.shape[-1] // len(vs)
+        acc_ref[sl] = acc_ref[sl] * alpha + sum(
+            jax.lax.dot(
+                p[:, t * keys:(t + 1) * keys], v,
                 preferred_element_type=jnp.float32,
-            ) * mult  # (GS, P)
-            s = jnp.where(mask, s, NEG_INF)
-            m_prev = m_ref[h]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            # masked lanes contribute EXACTLY 0 even when the whole tile
-            # is masked (exp(s - m_cur) would be exp(0)=1 garbage at
-            # m==NEG_INF)
-            p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
-            alpha = jnp.exp(m_prev - m_cur)
-            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot(
-                p, v, preferred_element_type=jnp.float32
             )
-            m_ref[h] = m_cur
+            for t, v in enumerate(vs)
+        )
+        m_ref[sl] = m_cur
 
-    @pl.when(i == num_pages - 1)
+    lo, hi = lo_ref[b], hi_ref[b]
+
+    if fold:
+        # every kv head in ONE product: a page is read as (P*Hkv, D) rows
+        # in (token, head) order — the block as it lies in VMEM — against
+        # all Hkv*G*S query rows; a score counts only where the key's
+        # head is the query's. The MXU's time is the K and V rows it
+        # takes in, which this does not multiply; what it saves is the
+        # per-head slicing and Hkv tiny products a page. ``fold`` pages
+        # (consecutive ordinals, so consecutive positions) share one
+        # softmax update: their products are independent work in one
+        # block of straight-line code, where a page under a condition of
+        # its own is a chain of dependent steps nothing else fills.
+        R, L = Hkv * GS, fold * P * Hkv
+        lane = jax.lax.broadcasted_iota(jnp.int32, (R, L), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (R, L), 0)
+        same_head = lane % Hkv == row // GS
+        tok = lane // Hkv
+        qpos = pos0 + row % S
+        for j in range(0, n, fold):  # static: the pages this step staged
+            first = i * n + j
+
+            @pl.when((first <= hi) & (first + fold - 1 >= lo))
+            def _pages(j=j, first=first):
+                kpos = first * P + tok
+                mask = same_head & (kpos <= qpos)
+                if window is not None:
+                    mask &= kpos > qpos - window
+                q = q_ref[0].astype(ctype)
+                s = jnp.concatenate([
+                    jax.lax.dot_general(
+                        q, k_refs[j + t][...].reshape(P * Hkv, d).astype(ctype),
+                        dimension_numbers=(((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                    for t in range(fold)
+                ], axis=1) * mult  # (R, fold*P*Hkv)
+                online_update(slice(None), s, mask, [
+                    v_refs[j + t][...].reshape(P * Hkv, d).astype(ctype)
+                    for t in range(fold)
+                ])
+    else:
+        # row r of the GS axis is query s = r % S at position pos0 + s;
+        # lane j is key position first + j
+        tok = jax.lax.broadcasted_iota(jnp.int32, (GS, P), 1)
+        qpos = pos0 + (jax.lax.broadcasted_iota(jnp.int32, (GS, P), 0) % S)
+        for j in range(n):  # static: the pages this step staged
+            page = i * n + j
+
+            @pl.when((page <= hi) & (page >= lo))
+            def _page(j=j, page=page):
+                kpos = page * P + tok
+                mask = kpos <= qpos
+                if window is not None:
+                    mask &= kpos > qpos - window
+                for h in range(Hkv):  # static: one kv head at a time
+                    q = q_ref[0, h].astype(ctype)  # (GS, D)
+                    k = k_refs[j][:, h, :].astype(ctype)  # (P, D)
+                    v = v_refs[j][:, h, :].astype(ctype)
+                    if quant:
+                        k = k * ks_refs[j][h, 0].T
+                        v = v * vs_refs[j][h, 0].T
+                    s = jax.lax.dot_general(
+                        q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    ) * mult  # (GS, P)
+                    online_update(h, s, mask, [v])
+
+    @pl.when(i == pl.num_programs(1) - 1)
     def _finish():
         l = l_ref[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("page_size", "window", "scale", "interpret"),
-)
 def paged_attention(
     q: jax.Array,
     k_pool: jax.Array,
@@ -201,6 +284,7 @@ def paged_attention(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     interpret: bool = False,
+    tile: PagedTile | None = None,
 ) -> jax.Array:
     """Decode attention over a paged KV pool, addressed by block table.
 
@@ -214,8 +298,7 @@ def paged_attention(
       page_table: ``(B, W_pages)`` int32 — page ordinal → pool page.
       pos0: ``(B,)`` int32 — absolute position of each row's first query
         (query s sits at ``pos0 + s``).
-      page_size: tokens per page; one kv grid step stages one page of
-        every kv head.
+      page_size: tokens per page.
       window: optional sliding-window width (same semantics as the
         gather path's ``attn_window``).
       scale: score multiplier; defaults to ``1/sqrt(D)`` computed in f32
@@ -223,6 +306,9 @@ def paged_attention(
       k_scale / v_scale: ``(kv_heads, pool_tokens)`` f32 per-token
         dequant scales; both or neither.
       interpret: run the Pallas interpreter (CPU-verifiable).
+      tile: how many pages one grid step stages and how it computes
+        them; ``None`` = ``flash_tuning.select_paged_geometry`` on the
+        call's shape.
 
     Returns ``(B, H, S, D)`` in q's dtype.
     """
@@ -239,36 +325,83 @@ def paged_attention(
     quant = k_scale is not None
     if quant and k_scale.shape != (Hkv, T):
         raise ValueError(f"scale shape {k_scale.shape} != {(Hkv, T)}")
+    if tile is None:
+        tile = select_paged_geometry(
+            table_pages=page_table.shape[1], page_size=page_size,
+            kv_heads=Hkv, groups=H // Hkv, span=S, head_dim=D,
+            itemsize=k_pool.dtype.itemsize, quant=quant,
+        )
+    return _paged_attention(
+        q, k_pool, v_pool, page_table, pos0, k_scale, v_scale,
+        page_size=page_size, window=window, scale=scale,
+        interpret=interpret, tile=tile,
+    )
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("page_size", "window", "scale", "interpret", "tile"),
+)
+def _paged_attention(
+    q, k_pool, v_pool, page_table, pos0, k_scale, v_scale, *,
+    page_size, window, scale, interpret, tile,
+):
+    B, H, S, D = q.shape
+    T, Hkv, _ = k_pool.shape
+    quant = k_scale is not None
     G = H // Hkv
     W = page_table.shape[1]
+    n, fold = tile.pages, tile.fold
+    steps = -(-W // n)
+    # the table as the grid walks it: steps * n columns, an entry the
+    # row's span cannot see naming the pool's first page — a block index
+    # that repeats from one step to the next is not fetched again, so
+    # pages a row does not hold (past its reach, before its window) cost
+    # no traffic. A (B, W) integer select in front of the call.
+    lo, hi = _live_pages(
+        pos0.astype(jnp.int32), page_size=page_size, span=S, window=window,
+        table_pages=W,
+    )
+    ordinal = jnp.arange(steps * n, dtype=jnp.int32)[None, :]
+    table = jnp.where(
+        (ordinal >= lo[:, None]) & (ordinal <= hi[:, None]),
+        jnp.pad(page_table.astype(jnp.int32), ((0, 0), (0, steps * n - W))),
+        0,
+    )
 
     kernel = functools.partial(
         _paged_attn_kernel,
         scale=scale,
         window=window,
         page_size=page_size,
+        kv_heads=Hkv,
         groups=G,
         span=S,
-        num_pages=W,
+        pages=n,
+        quant=quant,
+        fold=fold,
     )
-    if not quant:
-        # keep the kernel signature uniform: drop the scale refs
-        kernel = functools.partial(_strip_scale_refs, kernel)
 
     # fold the GQA group into the span axis: head h = hkv*G + g maps to
-    # row g*S + s of the (G*S) query axis for kv head hkv
-    qg = q.reshape(B, Hkv, G * S, D)
-
+    # row g*S + s of the (G*S) query axis for kv head hkv; a folded call
+    # takes all Hkv*G*S rows as one axis
+    q_shape = (B, Hkv * G * S, D) if fold else (B, Hkv, G * S, D)
     row_spec = pl.BlockSpec(
-        (1, Hkv, G * S, D), lambda b, i, tbl, p0: (b, 0, 0, 0)
+        (1,) + q_shape[1:],
+        lambda b, i, *_: (b,) + (0,) * (len(q_shape) - 1),
     )
     # the block's last two dims equal the pool's, so any kv_heads / D
-    # tiles; the page axis (outermost) is the one the table indexes
-    page_spec = pl.BlockSpec(
-        (page_size, Hkv, D), lambda b, i, tbl, p0: (tbl[b, i], 0, 0)
-    )
-    in_specs = [row_spec, page_spec, page_spec]
-    operands = [qg, k_pool, v_pool]
+    # tiles; the page axis (outermost) is the one the table indexes:
+    # step i's j-th operand stages the page at table[b, i * n + j]
+    page_specs = [
+        pl.BlockSpec(
+            (page_size, Hkv, D),
+            lambda b, i, tbl, *_, j=j: (tbl[b, i * n + j], 0, 0),
+        )
+        for j in range(n)
+    ]
+    in_specs = [row_spec] + page_specs + page_specs
+    operands = [q.reshape(q_shape)] + [k_pool] * n + [v_pool] * n
     if quant:
         # a (kv_heads, page) block over the (kv_heads, pool_tokens)
         # scale array is off Mosaic's (8, 128) tiling for pages under
@@ -276,55 +409,70 @@ def paged_attention(
         # dims equal the array's. The pool layout is untouched; XLA makes
         # the view a relayout of the two scale arrays (4 bytes per token
         # per head) on each call.
-        scale_spec = pl.BlockSpec(
-            (Hkv, 1, 1, page_size),
-            lambda b, i, tbl, p0: (0, tbl[b, i], 0, 0),
-        )
-        in_specs += [scale_spec, scale_spec]
-        operands += [
+        scale_specs = [
+            pl.BlockSpec(
+                (Hkv, 1, 1, page_size),
+                lambda b, i, tbl, *_, j=j: (0, tbl[b, i * n + j], 0, 0),
+            )
+            for j in range(n)
+        ]
+        in_specs += scale_specs + scale_specs
+        views = [
             s.reshape(Hkv, T // page_size, 1, page_size)
             for s in (k_scale, v_scale)
         ]
+        operands += [views[0]] * n + [views[1]] * n
 
+    stat_shape = q_shape[1:-1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, W),
+        num_scalar_prefetch=4,
+        grid=(B, steps),
         in_specs=in_specs,
         out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((Hkv, G * S, D), jnp.float32),
-            pltpu.VMEM((Hkv, G * S, 1), jnp.float32),
-            pltpu.VMEM((Hkv, G * S, 1), jnp.float32),
+            pltpu.VMEM(stat_shape + (D,), jnp.float32),
+            pltpu.VMEM(stat_shape + (1,), jnp.float32),
+            pltpu.VMEM(stat_shape + (1,), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G * S, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(Hkv * G * S, D, q.dtype.itemsize),
+            vmem_limit_bytes=_vmem_limit(
+                Hkv * G * S, D, q.dtype.itemsize,
+                n * page_size * Hkv * D * k_pool.dtype.itemsize,
+            ),
         ),
         interpret=interpret,
-    )(
-        page_table.astype(jnp.int32), pos0.astype(jnp.int32), *operands
-    )
+        name=paged_kernel_name(page_size, tile, Hkv),
+    )(table, lo, hi, pos0.astype(jnp.int32), *operands)
     return out.reshape(B, H, S, D)
 
 
-def _vmem_limit(rows: int, d: int, q_bytes: int) -> int | None:
-    """The page block carries every kv head, so a row's queries, output
+def paged_kernel_name(page_size: int, tile: PagedTile, kv_heads: int) -> str:
+    """The call's name in a device trace: page size, pages a grid step,
+    kv heads a step (all of them) and, where products fold them, the
+    pages a softmax update takes together."""
+    return (
+        f"paged_decode_p{page_size}_n{tile.pages}_h{kv_heads}"
+        f"{f'_f{tile.fold}' if tile.fold else ''}"
+    )
+
+
+def _vmem_limit(rows: int, d: int, q_bytes: int, staged: int) -> int | None:
+    """The page blocks carry every kv head, so a row's queries, output
     (both double-buffered) and f32 accumulators for ALL heads are
     resident at once: ``rows`` = kv_heads x group x span of them, the
-    (rows, 1) running max and sum padded to 128 lanes. A decode step or a
-    verify span is far under the compiler's default scoped limit (None
-    leaves it alone); a 512-token prefill piece at 8 kv heads x 4 x 128
-    needs ~45 MB of a v5e's 128 MiB, so the limit is asked for by size."""
-    resident = rows * (4 * d * q_bytes + 4 * d + 2 * 4 * 128)
+    (rows, 1) running max and sum padded to 128 lanes; beside them the
+    ``staged`` bytes of K pages a step, again for V, double-buffered. A
+    decode step or a verify span is far under the compiler's default
+    scoped limit (None leaves it alone); a 512-token prefill piece at 8
+    kv heads x 4 x 128 needs ~45 MB of a v5e's 128 MiB, so the limit is
+    asked for by size (no engine path sends a piece here today — it
+    gathers: ROADMAP S1 (c) — but the call is public and
+    `tests/test_tpu_compile.py` keeps the piece compiling)."""
+    resident = rows * (4 * d * q_bytes + 4 * d + 2 * 4 * 128) + 4 * staged
     return None if resident < (8 << 20) else 2 * resident
-
-
-def _strip_scale_refs(kernel, tbl_ref, pos0_ref, q_ref, k_ref, v_ref,
-                      o_ref, acc_ref, m_ref, l_ref):
-    kernel(tbl_ref, pos0_ref, q_ref, k_ref, v_ref, None, None,
-           o_ref, acc_ref, m_ref, l_ref)

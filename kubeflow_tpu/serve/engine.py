@@ -63,6 +63,7 @@ from kubeflow_tpu.models.transformer import (
     TransformerConfig,
     TransformerLM,
     init_paged_kv_cache,
+    paged_kernel_read,
 )
 from kubeflow_tpu.obs import names, prom
 from kubeflow_tpu.obs.headers import (
@@ -170,12 +171,18 @@ class LMEngineConfig:
     traffic into less HBM than that rectangle. Admission is by the
     prompt's own length: ``len(ids) + max_new_tokens <= max_seq``.
 
-    ``paged_attn_impl``: how the paged read path runs — ``"gather"``
-    (default, in-graph XLA gather + masked softmax) or ``"kernel"``
-    (ops/paged_attention.py: Pallas decode attention fetching K/V pages
-    through the block table, online softmax fused; on CPU it runs the
-    Pallas interpreter when ``TransformerConfig.interpret_kernels`` is
-    set). Greedy token streams are byte-identical between the two.
+    The read path is not a setting: a decode step and a speculative
+    verify span read K and V through the Pallas kernel
+    (ops/paged_attention.py: only the pages a row holds leave HBM, once,
+    online softmax fused) where the backend is a TPU — or
+    ``TransformerConfig.interpret_kernels`` asks for the Pallas
+    interpreter — and no mesh is in force; a prefill piece (one row: the
+    kernel only up to 8 tokens, where the two read alike), an engine
+    under a mesh and a CPU without the interpreter gather the rows'
+    windows in-graph (``models/transformer.py::paged_kernel_read``).
+    Greedy token streams are byte-identical between the two;
+    ``stats["decode_chunks_kernel_read"]`` counts the chunks that took
+    the kernel.
     ``kv_quant``: ``"none"`` (default, byte-exact with the pre-quant
     engine) or ``"int8"`` — per-(kv_head, token) symmetric int8 pool
     with f32 scale side arrays, quantize-on-write / dequantize-on-read;
@@ -202,7 +209,6 @@ class LMEngineConfig:
     pipeline_depth: int = 1
     spec_draft_tokens: int = 0
     spec_ngram: int = 3
-    paged_attn_impl: str = "gather"
     kv_quant: str = "none"
     #: host-RAM KV tier byte budget (serve/kv_tier.py): > 0 enables the
     #: tier — sessioned rows swap their KV span out through the npz codec
@@ -423,11 +429,6 @@ class LMEngine:
         #: speculative decode: K draft tokens verified per forward (0=off)
         self.spec_k = config.spec_draft_tokens
         self.spec_ngram = config.spec_ngram
-        if config.paged_attn_impl not in ("gather", "kernel"):
-            raise ValueError(
-                f"paged_attn_impl must be 'gather' or 'kernel'; "
-                f"got {config.paged_attn_impl!r}"
-            )
         if config.kv_quant not in ("none", "int8"):
             raise ValueError(
                 f"kv_quant must be 'none' or 'int8'; got {config.kv_quant!r}"
@@ -617,6 +618,14 @@ class LMEngine:
             "kv_offload_out": 0, "kv_offload_in": 0,
             # most pool pages owned by resident rows at any admission
             "kv_pages_used_peak": 0,
+            # the decode read path, counted at each chunk's dispatch: the
+            # chunks whose attention read K and V through the Pallas
+            # kernel (the rest gathered the window), the pages the active
+            # rows hold up to their reach, and the pages of the window a
+            # gather would read (max_batch x table width) — live / window
+            # is the share of the window that exists
+            "decode_chunks_kernel_read": 0,
+            "decode_pages_live": 0, "decode_pages_window": 0,
             # the scheduler thread's wall time, once per loop iteration, and
             # under it each phase's self seconds and entries (_phase)
             "sched_loop_s": 0.0,
@@ -727,6 +736,20 @@ class LMEngine:
             else self._chunk_paged_impl,
             donate_argnums=chunk_donate, static_argnames=("seeded",),
         )
+        #: the model's programs are traced and run with the engine's mesh
+        #: in force, so what they decide by it (the paged read path: a
+        #: Mosaic kernel is not partitioned automatically) they decide
+        #: knowing it is there
+        self._mesh_scope = (
+            contextlib.nullcontext if mesh is None
+            else (lambda: jax.set_mesh(mesh))
+        )
+        with self._mesh_scope():
+            #: whether the decode chunk's attention reads through the
+            #: Pallas kernel: the model's own answer for the chunk's span
+            self.kernel_read = paged_kernel_read(
+                cfg, self.max_batch, self.spec_k + 1
+            )
         self._implant_jits: dict[int, Any] = {}
         #: a request held back by page backpressure (FIFO preserved:
         #: nothing admits past it until its pages free up)
@@ -906,7 +929,6 @@ class LMEngine:
                 {"params": params}, x, cache=cache,
                 positions=positions, page_table=table,
                 page_size=self.page_size, page_write_ok=write_ok,
-                paged_attn_impl=self.engine_config.paged_attn_impl,
                 kv_quant=self.kv_quant,
             )
             emitted, n_emit, n_acc = spec_accept(
@@ -971,7 +993,6 @@ class LMEngine:
         kw = dict(
             positions=positions, page_table=table,
             page_size=self.page_size, page_write_ok=write_ok,
-            paged_attn_impl=self.engine_config.paged_attn_impl,
             kv_quant=self.kv_quant,
         )
         if self.kv_quant == "int8":
@@ -1078,7 +1099,6 @@ class LMEngine:
                 page_table=table,
                 page_size=self.page_size,
                 page_write_ok=live[:, None],
-                paged_attn_impl=self.engine_config.paged_attn_impl,
                 kv_quant=self.kv_quant,
             )
             nxt = _sample(lg[:, 0], sub, temperature)
@@ -1878,19 +1898,22 @@ class LMEngine:
             seed = -1 if req.seed is None else req.seed
             pos = base + i * C + len(piece_ids)
             pages_w = self._pages_w(base + i * C + C)
-            self.cache, tok, valid, qerr = self._suffix_prefill(
-                self.params,
-                self.cache,
-                jnp.asarray(piece),
-                jnp.asarray([len(piece_ids)], np.int32),
-                base + i * C,
-                jnp.asarray(self.pager.table[row : row + 1, :pages_w].copy()),
-                jnp.float32(req.temperature),
-                seed,
-                pos,
-                sub,
-                seeded=req.seed is not None,
-            )
+            with self._mesh_scope():
+                self.cache, tok, valid, qerr = self._suffix_prefill(
+                    self.params,
+                    self.cache,
+                    jnp.asarray(piece),
+                    jnp.asarray([len(piece_ids)], np.int32),
+                    base + i * C,
+                    jnp.asarray(
+                        self.pager.table[row : row + 1, :pages_w].copy()
+                    ),
+                    jnp.float32(req.temperature),
+                    seed,
+                    pos,
+                    sub,
+                    seeded=req.seed is not None,
+                )
         if self.kv_quant == "int8":
             # same inline sync budget as the final piece's int(tok) below:
             # prefill is synchronous by design (one row, host-driven)
@@ -2274,27 +2297,38 @@ class LMEngine:
             c["table"] = self.pager.device_table(w)
             self._carry_pages_w = w
             self.overlap["carry_uploads"] += 1
-        if self.spec_k:
-            (
-                self.cache, c["hist"], tok, gen_count, active,
-                toks, valid, eos, prop, acc,
-            ) = self._chunk(
-                self.params, self.cache, c["hist"], c["last_tok"],
-                c["real_len"], c["gen_count"], c["active"], c["budget"],
-                c["temp"], c["seed"], sub, c["table"],
-                seeded=self._carry_seeded,
-            )
-        else:
-            (
-                self.cache, tok, gen_count, active, toks, valid
-            ) = self._chunk(
-                self.params, self.cache, c["last_tok"], c["real_len"],
-                c["gen_count"], c["active"], c["budget"], c["temp"],
-                c["seed"], sub, c["table"], seeded=self._carry_seeded,
-            )
+        with self._mesh_scope():
+            if self.spec_k:
+                (
+                    self.cache, c["hist"], tok, gen_count, active,
+                    toks, valid, eos, prop, acc,
+                ) = self._chunk(
+                    self.params, self.cache, c["hist"], c["last_tok"],
+                    c["real_len"], c["gen_count"], c["active"], c["budget"],
+                    c["temp"], c["seed"], sub, c["table"],
+                    seeded=self._carry_seeded,
+                )
+            else:
+                (
+                    self.cache, tok, gen_count, active, toks, valid
+                ) = self._chunk(
+                    self.params, self.cache, c["last_tok"], c["real_len"],
+                    c["gen_count"], c["active"], c["budget"], c["temp"],
+                    c["seed"], sub, c["table"], seeded=self._carry_seeded,
+                )
         c["last_tok"], c["gen_count"], c["active"] = tok, gen_count, active
         self._carry_chunks += 1
         self.stats["chunks"] += 1
+        self.stats["decode_chunks_kernel_read"] += self.kernel_read
+        # the rows' reach as the host last saw it (the drain of a chunk in
+        # flight will move it on by up to a chunk's tokens)
+        reach = (self.real_len + self.gen_count)[self.active]
+        self.stats["decode_pages_live"] += int(
+            (-(-reach // self.page_size)).sum()
+        )
+        self.stats["decode_pages_window"] += (
+            self.max_batch * self._carry_pages_w
+        )
         return _PendingChunk(
             toks=toks, valid=valid, last_tok=tok, gen_count=gen_count,
             active_out=active, active_in=active_in,
@@ -2736,7 +2770,7 @@ class LMEngineModel(LMRuntimeModel):
         prefill_chunk=None, mesh=None, rules=None,
         kv_pool_tokens=None, page_size=64, pipeline_depth=1,
         spec_draft_tokens=0, spec_ngram=3,
-        paged_attn_impl="gather", kv_quant="none", host_kv_bytes=0,
+        kv_quant="none", host_kv_bytes=0,
         watchdog=True,
         watchdog_interval_s=0.5, watchdog_wedge_factor=8.0,
         watchdog_min_wedge_s=30.0, **kwargs,
@@ -2755,7 +2789,6 @@ class LMEngineModel(LMRuntimeModel):
         self._engine_pipeline_depth = pipeline_depth
         self._engine_spec_draft = spec_draft_tokens
         self._engine_spec_ngram = spec_ngram
-        self._engine_paged_attn_impl = paged_attn_impl
         self._engine_kv_quant = kv_quant
         self._engine_max_seq = max_seq or (
             self.buckets.seq_lens[-1] + self.max_new_tokens
@@ -2805,7 +2838,6 @@ class LMEngineModel(LMRuntimeModel):
             pipeline_depth=self._engine_pipeline_depth,
             spec_draft_tokens=self._engine_spec_draft,
             spec_ngram=self._engine_spec_ngram,
-            paged_attn_impl=self._engine_paged_attn_impl,
             kv_quant=self._engine_kv_quant,
             host_kv_bytes=self._engine_host_kv_bytes,
         )

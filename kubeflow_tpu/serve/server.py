@@ -1081,14 +1081,7 @@ class ModelServer:
                         f'{names.ENGINE_KV_PREFIX}{key}{{model="{name}"}} '
                         f"{val}"
                     )
-                # paged read-path selection + KV quantization health
-                kernel_on = int(
-                    getattr(eng, "paged_attn_impl", "gather") == "kernel"
-                )
-                lines.append(
-                    f'{names.ENGINE_PAGED_ATTN_KERNEL}{{model="{name}"}} '
-                    f"{kernel_on}"
-                )
+                # KV quantization health
                 if ov is not None and "kv_quant_error" in ov:
                     lines.append(
                         f'{names.ENGINE_KV_QUANT_ERROR}{{model="{name}"}} '

@@ -217,25 +217,30 @@ from kubeflow_tpu.models.transformer import (  # noqa: E402
 )
 from kubeflow_tpu.serve.engine import LMEngine  # noqa: E402
 
-cfg = TransformerConfig(
+import dataclasses  # noqa: E402
+
+# the read path is the program's choice: on a CPU the Pallas kernel where
+# the configuration asks for the Mosaic interpreter (same semantics), the
+# XLA gather where it does not
+kernel_cfg = TransformerConfig(
     vocab_size=64, d_model=32, n_layers=1, n_heads=4, d_ff=64, causal=True,
     max_seq_len=128, attn_impl="reference", dtype=jnp.float32,
-    interpret_kernels=True,  # CPU smoke: Mosaic interpreter, same semantics
+    interpret_kernels=True,
 )
-model = TransformerLM(cfg)
-params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))[
-    "params"
-]
+cfg = gather_cfg = dataclasses.replace(kernel_cfg, interpret_kernels=False)
+params = TransformerLM(cfg).init(
+    jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+)["params"]
 prompts = [[3, 5, 7, 11, 13], [2, 4, 6]]
 
 
-def run(impl, quant="none"):
+def run(cfg, quant="none"):
     eng = LMEngine(
-        model, cfg, params, max_batch=2, max_seq=64, chunk_steps=4,
-        prefill_buckets=(16,), eos_id=cfg.vocab_size + 1,
-        kv_pool_tokens=16 * 10, page_size=16,
-        paged_attn_impl=impl, kv_quant=quant,
+        TransformerLM(cfg), cfg, params, max_batch=2, max_seq=64,
+        chunk_steps=4, prefill_buckets=(16,), eos_id=cfg.vocab_size + 1,
+        kv_pool_tokens=16 * 10, page_size=16, kv_quant=quant,
     ).start()
+    assert eng.kernel_read == cfg.interpret_kernels
     try:
         outs = [eng.submit(p, max_new_tokens=16) for p in prompts]
         kv = sum(int(lc[w].nbytes)
@@ -247,11 +252,11 @@ def run(impl, quant="none"):
     return outs, kv, sc
 
 
-gather, kv_f32, sc_f32 = run("gather")
-kernel, _, _ = run("kernel")
+gather, kv_f32, sc_f32 = run(gather_cfg)
+kernel, _, _ = run(kernel_cfg)
 # the read-path swap is a layout change, not a numerics change
 assert kernel == gather, (kernel, gather)
-_, kv_int8, sc_int8 = run("gather", "int8")
+_, kv_int8, sc_int8 = run(gather_cfg, "int8")
 # int8 pool = 1/4 of f32 = 1/2 of the bf16 pool the chip serves from;
 # per-token-per-head f32 scales are the 1/head_dim overhead on top
 assert kv_int8 * 4 == kv_f32 and sc_f32 == 0, (kv_int8, kv_f32)

@@ -574,6 +574,10 @@ def test_reload_cycle_and_engine_metrics(model_and_params):
         text = asyncio.run(scrape())
         assert 'kubeflow_tpu_engine_completed{model="lm"}' in text
         assert 'kubeflow_tpu_engine_active_rows{model="lm"}' in text
+        # the decode read path's counters ride the same export
+        for key in ("decode_chunks_kernel_read", "decode_pages_live",
+                    "decode_pages_window"):
+            assert f'kubeflow_tpu_engine_{key}{{model="lm"}}' in text
     finally:
         m.unload()
 
@@ -1248,6 +1252,52 @@ def test_engine_config_object_and_depth_validation(model_and_params):
         LMEngine(model, CFG, params, max_batch=2, pipeline_depth=2)
     with pytest.raises(TypeError):
         LMEngine(model, CFG, params, not_a_knob=1)
+    # the read path is chosen by what the program observes, not set
+    import dataclasses
+
+    fields = [f.name for f in dataclasses.fields(LMEngineConfig)]
+    assert len(fields) == 20 and "paged_attn_impl" not in fields
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["gather", "kernel"])
+def test_decode_read_path_counters(model_and_params, interpret):
+    """The three counters of the decode read path, on rows of known
+    length: chunks whose attention read through the kernel (all of them
+    under the interpreter, none on the gather path), the pages the active
+    rows hold up to their reach, and the pages of the window a gather
+    reads (max_batch x table width) — counted at each chunk's dispatch."""
+    import dataclasses
+
+    _, params = model_and_params
+    cfg = dataclasses.replace(CFG, interpret_kernels=interpret)
+    eng = LMEngine(
+        TransformerLM(cfg), cfg, params, max_batch=4, max_seq=96,
+        chunk_steps=4, prefill_buckets=(64,), eos_id=CFG.vocab_size + 1,
+        page_size=16, pipeline_depth=0,
+    ).start()
+    try:
+        assert eng.kernel_read == interpret
+        # a 20-token prompt: the prefill emits token 1, so the first chunk
+        # is dispatched at reach 21 (2 pages of 16), the second at 25 (2),
+        # the third at 29 (2): 13 tokens in all
+        eng.submit(list(range(2, 22)), max_new_tokens=13)
+        assert eng.stats["chunks"] == 3
+        assert eng.stats["decode_pages_live"] == 2 + 2 + 2
+        # table widths 2, 2, 4: the pages of reach + a chunk's 4 tokens
+        # (25, 29, 33 tokens), rounded up to a power of two
+        assert eng.stats["decode_pages_window"] == 4 * (2 + 2 + 4)
+        # a second, longer row beside nothing: 40 tokens, reach 41 (3
+        # pages), one chunk; its table is 4 pages wide (45 tokens -> 3,
+        # rounded up to a power of two)
+        eng.submit(list(range(2, 42)), max_new_tokens=5)
+        assert eng.stats["chunks"] == 4
+        assert eng.stats["decode_pages_live"] == 6 + 3
+        assert eng.stats["decode_pages_window"] == 32 + 4 * 4
+        assert eng.stats["decode_chunks_kernel_read"] == (
+            4 if interpret else 0
+        )
+    finally:
+        eng.stop()
 
 
 def test_engine_with_sliding_window(model_and_params):

@@ -10,10 +10,13 @@ Two contracts pinned here, in two layers:
    Mosaic interpreter on CPU, so these are real kernel-semantics tests,
    not a shadow implementation.
 
-2. Engine layer — ``paged_attn_impl="kernel"`` is a READ-PATH SWAP, NOT A
-   NUMERICS CHANGE: byte-identical greedy streams vs the XLA gather
-   across the whole engine matrix (churn, chunked prefill, prefix hits,
-   mid-stream cancellation, spec K=4, pipeline 0/1). int8 KV is lossy by
+2. Engine layer — the kernel read (what the model chooses for a short
+   span when ``interpret_kernels`` asks for the interpreter; on a TPU,
+   for itself) is a READ-PATH SWAP, NOT A NUMERICS CHANGE: byte-identical
+   greedy streams vs the XLA gather (the same model without the
+   interpreter, on this CPU) across the whole engine matrix (churn,
+   chunked prefill, prefix hits, mid-stream cancellation, spec K=4,
+   pipeline 0/1). int8 KV is lossy by
    design, so its contract is different: gather and kernel must agree
    with each other EXACTLY (same dequant arithmetic), the token stream
    must track the fp32 engine within a stated tolerance, the quant-error
@@ -32,10 +35,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from kubeflow_tpu.models.transformer import TransformerConfig, TransformerLM
+import dataclasses
+
+from kubeflow_tpu.models.transformer import (
+    PAGED_KERNEL_MAX_SPAN,
+    PAGED_KERNEL_MAX_SPAN_ONE_ROW,
+    TransformerConfig,
+    TransformerLM,
+    paged_gather_attention,
+    paged_kernel_read,
+)
+from kubeflow_tpu.ops.flash_tuning import PagedTile, select_paged_geometry
 from kubeflow_tpu.ops.paged_attention import (
     dequantize_kv,
     paged_attention,
+    paged_kernel_name,
     quantize_kv,
 )
 from kubeflow_tpu.serve.engine import LMEngine
@@ -49,6 +63,9 @@ CFG = TransformerConfig(
     causal=True, max_seq_len=256, attn_impl="reference", dtype=jnp.float32,
     interpret_kernels=True,
 )
+#: the same model on the gather read path: on this CPU the paged branch
+#: takes the kernel only where the configuration asks for the interpreter
+CFG_GATHER = dataclasses.replace(CFG, interpret_kernels=False)
 EOS = 1
 
 
@@ -170,6 +187,246 @@ def test_kernel_first_token_pos0_zero():
     assert np.all(np.isfinite(np.asarray(out)))
 
 
+# ------------------------------------------- the geometry, at the cell's
+# head shape: `mistral-7b_gen-closed` reads 32 query heads over 8 kv heads
+# of 128 through 64-token pages, table widths 1 to 16
+
+CELL = dict(H=32, Hkv=8, D=128, P=64)
+#: context (keys a row holds, the query's own among them) of each ragged
+#: row: one token; ending exactly on a page boundary; one key into a new
+#: page; mid-page; the table's whole width. -1 is a dead row (its table
+#: points at the scratch page, its position is real_len + gen_count - 1
+#: of a free slot)
+RAGGED = (1, 128, 129, 300, None, -1)
+
+
+def _cell_case(table_pages, *, span=1, quant=False, dtype=jnp.bfloat16,
+               seed=0):
+    """Queries, pools, table and positions at the cell's head shape with
+    RAGGED rows (None = the table's whole width)."""
+    H, Hkv, D, P = (CELL[k] for k in ("H", "Hkv", "D", "P"))
+    rng = np.random.default_rng(seed)
+    ctx = [table_pages * P if c is None else c for c in RAGGED]
+    ctx = [min(c, table_pages * P) for c in ctx]
+    B = len(ctx)
+    n_pages = 1 + B * table_pages
+    T = n_pages * P
+    q = jnp.asarray(rng.normal(size=(B, H, span, D)) / np.sqrt(D), dtype)
+    kp = jnp.asarray(rng.normal(size=(T, Hkv, D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(T, Hkv, D)), dtype)
+    table = 1 + rng.permutation(B * table_pages).astype(np.int32).reshape(
+        B, table_pages
+    )
+    live = np.asarray([c > 0 for c in ctx])
+    table[~live] = 0                      # a dead row owns no page
+    # the span's first query sits at context - span (its last at the
+    # row's last key); a row shorter than the span starts at 0
+    pos0 = np.asarray([max(c - span, 0) if c > 0 else -1 for c in ctx],
+                      np.int32)
+    cache = {"k": kp, "v": vp}
+    if quant:
+        kq, ks = quantize_kv(kp)
+        vq, vs = quantize_kv(vp)
+        cache = {"k": kq, "v": vq, "k_scale": ks.T, "v_scale": vs.T}
+    return q, cache, jnp.asarray(table), jnp.asarray(pos0), live
+
+
+def _kernel_vs_gather(q, cache, table, pos0, live, *, tile, window=None):
+    """The kernel (interpreter) against the model's own gather read on the
+    live rows; a dead row's output is never used, only finite."""
+    P = CELL["P"]
+    span = q.shape[2]
+    got = paged_attention(
+        q, cache["k"], cache["v"], table, pos0, page_size=P, window=window,
+        k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"),
+        interpret=True, tile=tile,
+    )
+    want = paged_gather_attention(
+        q, cache, table, pos0[:, None] + jnp.arange(span)[None, :],
+        page_size=P, window=window,
+    )
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.all(np.isfinite(got))
+    # bf16: the result is rounded to it, and probabilities against a
+    # running maximum (kernel) and the row's (gather) round differently
+    tol = 2e-2 if q.dtype == jnp.bfloat16 else 1e-4
+    np.testing.assert_allclose(got[live], want[live], atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("table_pages", [1, 8, 16])
+def test_selected_geometry_matches_gather_on_ragged_rows(table_pages, quant):
+    """What the rule chooses for the cell's head shape at table widths 1, 8
+    and 16, on ragged rows: a row of one token, a row ending exactly on a
+    page boundary, a dead row on the scratch page, a full row."""
+    case = _cell_case(table_pages, quant=quant, seed=table_pages)
+    _kernel_vs_gather(*case, tile=None)
+
+
+@pytest.mark.parametrize("fold", [0, 1, 2, 4], ids="fold{}".format)
+@pytest.mark.parametrize("pages", [1, 2, 4, 8, 16])
+def test_every_pages_per_step_matches_gather(pages, fold):
+    """Every pages-per-step the rule can return (powers of two up to 16),
+    a head at a time and folded in groups of 1, 2 and 4 pages, on a table
+    of 16 pages — with a window that leaves whole pages of the longer
+    rows behind it (a row past the window)."""
+    case = _cell_case(16, seed=pages)
+    _kernel_vs_gather(
+        *case, tile=PagedTile(pages, min(fold, pages)), window=200
+    )
+
+
+@pytest.mark.parametrize("pages", [2, 16])
+def test_pages_per_step_int8_and_a_table_it_does_not_divide(pages):
+    """int8 pools through more than one page a step, and a table width
+    (6) that the step's pages do not divide: the pages past the table are
+    neither fetched nor computed."""
+    case = _cell_case(6, quant=True, seed=pages)
+    _kernel_vs_gather(*case, tile=PagedTile(pages, 0), window=200)
+    case = _cell_case(6, dtype=jnp.float32, seed=pages + 1)
+    _kernel_vs_gather(*case, tile=PagedTile(pages, 2))
+
+
+@pytest.mark.parametrize("span", [4, 5])
+def test_verify_span_matches_gather_at_the_cell_shape(span):
+    """The speculative verify span (K + 1 queries a row) through the
+    rule's geometry: in-span causality, rows shorter than the span."""
+    case = _cell_case(8, span=span, seed=span)
+    _kernel_vs_gather(*case, tile=None)
+
+
+def test_geometry_pinned_at_the_cell_shape():
+    """`mistral-7b_gen-closed`'s decode step: 32 rows, table 16 pages of
+    64, 8 kv heads x 4 x 128, bf16 — sixteen pages a grid step, the kv
+    heads folded into one product, four pages a softmax update: 32 steps
+    a layer, 512 over 16 layers (a page a step: 8,192). Narrower tables
+    take what they have; a longer span folds two pages an update; int8
+    and spans past 16 go a head at a time; the trace names the call."""
+    shape = dict(page_size=64, kv_heads=8, groups=4, head_dim=128)
+    cell = select_paged_geometry(table_pages=16, span=1, **shape)
+    assert cell == PagedTile(16, 4)
+    assert paged_kernel_name(64, cell, 8) == "paged_decode_p64_n16_h8_f4"
+    assert 32 * -(-16 // cell.pages) * 16 <= 1000
+    for w in (1, 2, 4, 8):
+        assert select_paged_geometry(
+            table_pages=w, span=1, **shape
+        ) == PagedTile(w, min(w, 4))
+    # the widest table of the deployment (8,320 tokens: 130 pages)
+    assert select_paged_geometry(table_pages=130, span=1, **shape).pages == 16
+    # the verify span and every other span the engine sends: folded, four
+    # pages an update up to four queries a row, two up to sixteen; longer
+    # (a direct call with a piece) a head at a time
+    assert [
+        select_paged_geometry(table_pages=16, span=s, **shape).fold
+        for s in (2, 4, 5, 8, 9, 16, 17, 512)
+    ] == [4, 4, 2, 2, 2, 2, 0, 0]
+    assert select_paged_geometry(
+        table_pages=1, span=8, **shape
+    ) == PagedTile(1, 1)
+    # a head at a time: sixteen products a step, two pages of eight heads
+    assert select_paged_geometry(
+        table_pages=16, span=1, quant=True, itemsize=1, **shape
+    ) == PagedTile(2, 0)
+    assert select_paged_geometry(
+        table_pages=16, span=512, **shape
+    ) == PagedTile(2, 0)
+    # f32 pools stage half the pages in the same VMEM
+    assert select_paged_geometry(
+        table_pages=16, span=1, itemsize=4, **shape
+    ).pages == 8
+
+
+def test_read_path_is_chosen_by_span_interpreter_and_mesh():
+    """`paged_kernel_read`: on this CPU the kernel only where the
+    configuration asks for the interpreter, only for decode and verify
+    shapes — up to 16 queries of several rows, up to 8 of one row (a
+    prefill piece's window is its own: a 16-token piece gathers) — and
+    not with a mesh in force."""
+    rows = 32
+    assert paged_kernel_read(CFG, rows, 1) and paged_kernel_read(CFG, rows, 5)
+    assert paged_kernel_read(CFG, rows, PAGED_KERNEL_MAX_SPAN)
+    assert not paged_kernel_read(CFG, rows, PAGED_KERNEL_MAX_SPAN + 1)
+    assert not paged_kernel_read(CFG, rows, 512)
+    # one row: an engine of one row decodes through the kernel, a piece
+    # of 16 tokens (the smallest suffix bucket) or 512 gathers
+    assert (PAGED_KERNEL_MAX_SPAN, PAGED_KERNEL_MAX_SPAN_ONE_ROW) == (16, 8)
+    assert paged_kernel_read(CFG, 1, 1) and paged_kernel_read(CFG, 1, 8)
+    assert not paged_kernel_read(CFG, 1, 9)
+    assert not paged_kernel_read(CFG, 1, 16)
+    assert not paged_kernel_read(CFG, 1, 512)
+    assert not paged_kernel_read(CFG_GATHER, rows, 1)
+    mesh = jax.sharding.Mesh(
+        np.asarray(jax.devices()[:2]).reshape(1, 2), ("data", "model")
+    )
+    with jax.set_mesh(mesh):
+        assert not paged_kernel_read(CFG, rows, 1)
+
+
+def test_act_constraint_gives_no_hint_on_a_mesh_without_its_axes():
+    """The model's activation hint under the meshes it can be traced
+    with: `build_mesh`'s (every axis: the constraint the trainer has
+    always had), none, and a serving engine's ("data", "model"), which
+    lacks fsdp and seq — no hint there (the constraint would name axes the
+    mesh does not have)."""
+    from kubeflow_tpu.core import MeshSpec, build_mesh
+    from kubeflow_tpu.models.transformer import _act_constraint
+
+    x = jnp.zeros((2, 8, 4))
+    plain = jax.make_jaxpr(_act_constraint)(x)
+    assert "sharding_constraint" not in str(plain)
+    with jax.set_mesh(jax.sharding.Mesh(
+        np.asarray(jax.devices()[:2]).reshape(1, 2), ("data", "model")
+    )):
+        assert str(jax.make_jaxpr(_act_constraint)(x)) == str(plain)
+    with jax.set_mesh(build_mesh(MeshSpec(data=2), devices=jax.devices()[:2])):
+        hinted = str(jax.make_jaxpr(_act_constraint)(x))
+    assert "sharding_constraint" in hinted
+    assert "data" in hinted and "fsdp" in hinted and "seq" in hinted
+
+
+def test_engine_under_a_mesh_keeps_the_gather_read():
+    """An engine under a 2-device ("data", "model") mesh traces and runs
+    its programs with the mesh in force (`jax.set_mesh`), so the model
+    sees it: the configuration asks for the kernel (the interpreter is
+    on) and the engine still gathers — a Mosaic kernel is not partitioned
+    automatically. Same streams as the engine with no mesh, which reads
+    through the kernel; entering the scope at every dispatch compiles
+    nothing again."""
+    from kubeflow_tpu.parallel.sharding import transformer_rules
+
+    cfg = dataclasses.replace(CFG, vocab_size=96)  # divides over "model"
+    model = TransformerLM(cfg)
+    params = model.init(jax.random.PRNGKey(2), jnp.zeros((1, 8), jnp.int32))[
+        "params"
+    ]
+    prompts = _prompts(np.random.default_rng(41), 3)
+    want = _run_engine(params, prompts, cfg=cfg)  # no mesh: the kernel
+    mesh = jax.sharding.Mesh(
+        np.asarray(jax.devices()[:2]).reshape(1, 2), ("data", "model")
+    )
+    eng = LMEngine(
+        model, cfg, params, max_batch=4, max_seq=96,
+        chunk_steps=4, prefill_buckets=(32,), eos_id=EOS,
+        kv_pool_tokens=16 * 24, page_size=16,
+        mesh=mesh, rules=transformer_rules(fsdp=False),
+    ).start()
+    try:
+        assert eng.kernel_read is False
+        k0 = next(iter(eng.cache.values()))["k"]
+        assert tuple(k0.sharding.spec) == (None, "model", None)
+        got = [eng.submit(p, max_new_tokens=MAX_NEW) for p in prompts]
+        compiled = (eng._chunk._cache_size(), eng._suffix_prefill._cache_size())
+        again = [eng.submit(p, max_new_tokens=MAX_NEW) for p in prompts]
+        assert (
+            eng._chunk._cache_size(), eng._suffix_prefill._cache_size()
+        ) == compiled
+        assert eng.stats["chunks"] > 0
+        assert eng.stats["decode_chunks_kernel_read"] == 0
+    finally:
+        eng.stop()
+    assert got == want and again == want
+
+
 def test_quantize_roundtrip_bound():
     """Per-token-per-head symmetric int8: roundtrip error is bounded by
     half a quantization step of that token's own scale, and the scale
@@ -191,13 +448,17 @@ def test_quantize_roundtrip_bound():
 MAX_NEW = 12
 
 
-def _run_engine(model, params, prompts, *, max_new=MAX_NEW, **kw):
+def _run_engine(params, prompts, *, max_new=MAX_NEW, cfg=CFG, **kw):
+    """Serve ``prompts``; the read path is ``cfg``'s (CFG: the kernel under
+    the interpreter for decode and verify spans, CFG_GATHER: the gather).
+    The engine's counter must say the same."""
     kw.setdefault("kv_pool_tokens", 16 * 24)
     kw.setdefault("page_size", 16)
     eng = LMEngine(
-        model, CFG, params, max_batch=4, max_seq=96, chunk_steps=4,
-        prefill_buckets=(32,), eos_id=EOS, **kw,
+        TransformerLM(cfg), cfg, params, max_batch=4, max_seq=96,
+        chunk_steps=4, prefill_buckets=(32,), eos_id=EOS, **kw,
     ).start()
+    assert eng.kernel_read == cfg.interpret_kernels
     try:
         # concurrent submits → requests batch up to max_batch, so the
         # parity matrix also exercises batched decode (streams are
@@ -207,7 +468,11 @@ def _run_engine(model, params, prompts, *, max_new=MAX_NEW, **kw):
                 ex.submit(eng.submit, p, max_new_tokens=max_new)
                 for p in prompts
             ]
-            return [f.result() for f in futs]
+            outs = [f.result() for f in futs]
+        assert eng.stats["decode_chunks_kernel_read"] == (
+            eng.stats["chunks"] if cfg.interpret_kernels else 0
+        )
+        return outs
     finally:
         eng.stop()
 
@@ -223,7 +488,7 @@ def gather_streams(model_and_params, shared_prompts):
     computed once; both the kernel matrix and the int8 contract measure
     relative to these streams."""
     model, params = model_and_params
-    return _run_engine(model, params, shared_prompts)
+    return _run_engine(params, shared_prompts, cfg=CFG_GATHER)
 
 
 @pytest.mark.slow
@@ -234,11 +499,11 @@ def test_kernel_byte_parity_matrix(model_and_params, shared_prompts,
     gather and kernel."""
     model, params = model_and_params
     for name, kw in [
-        ("kernel", dict(paged_attn_impl="kernel")),
-        ("kernel_pipe0", dict(paged_attn_impl="kernel", pipeline_depth=0)),
-        ("kernel_spec4", dict(paged_attn_impl="kernel", spec_draft_tokens=4)),
+        ("kernel", dict()),
+        ("kernel_pipe0", dict(pipeline_depth=0)),
+        ("kernel_spec4", dict(spec_draft_tokens=4)),
     ]:
-        got = _run_engine(model, params, shared_prompts, **kw)
+        got = _run_engine(params, shared_prompts, **kw)
         assert got == gather_streams, name
 
 
@@ -253,13 +518,14 @@ def test_kernel_parity_churn_chunked_prefix_cancel(model_and_params):
         [int(x) for x in rng.integers(2, 89, size=n)] for n in (34, 41)
     ]
 
-    def run(impl):
+    def run(cfg):
         eng = LMEngine(
-            model, CFG, params, max_batch=3, max_seq=96, chunk_steps=4,
-            prefill_buckets=(48,), eos_id=EOS, prefill_chunk=16,
-            prefix_cache_entries=4, kv_pool_tokens=16 * 24, page_size=16,
-            paged_attn_impl=impl,
+            TransformerLM(cfg), cfg, params, max_batch=3, max_seq=96,
+            chunk_steps=4, prefill_buckets=(48,), eos_id=EOS,
+            prefill_chunk=16, prefix_cache_entries=4,
+            kv_pool_tokens=16 * 24, page_size=16,
         ).start()
+        assert eng.kernel_read == cfg.interpret_kernels
         outs: dict[int, list[int]] = {}
         errors: list[Exception] = []
 
@@ -291,9 +557,11 @@ def test_kernel_parity_churn_chunked_prefix_cancel(model_and_params):
         assert not errors, errors
         return outs, stats
 
-    want, want_stats = run("gather")
-    got, got_stats = run("kernel")
+    want, want_stats = run(CFG_GATHER)
+    got, got_stats = run(CFG)
     assert got == want
+    assert want_stats["decode_chunks_kernel_read"] == 0
+    assert got_stats["decode_chunks_kernel_read"] == got_stats["chunks"] > 0
     assert got_stats["max_concurrent"] >= 2  # churn really happened
     assert got_stats["prefix_hits"] >= 1  # the resubmit hit the cache
 
@@ -301,13 +569,14 @@ def test_kernel_parity_churn_chunked_prefix_cancel(model_and_params):
 def test_kernel_and_int8_need_no_pool_size_named(model_and_params):
     """One cache layout: the kernel read path and the int8 pool run on the
     pool the engine derives when no ``kv_pool_tokens`` is given (they
-    used to be refused there); unknown values are still refused."""
+    used to be refused there); the read path is no setting any more, and
+    unknown values of what is one are still refused."""
     model, params = model_and_params
     kernel = LMEngine(
         model, CFG, params, max_batch=2, max_seq=64, chunk_steps=4,
-        eos_id=EOS, paged_attn_impl="kernel",
+        eos_id=EOS,
     )
-    assert kernel.engine_config.paged_attn_impl == "kernel"
+    assert kernel.kernel_read
     int8 = LMEngine(
         model, CFG, params, max_batch=2, max_seq=64, chunk_steps=4,
         eos_id=EOS, kv_quant="int8",
@@ -316,11 +585,11 @@ def test_kernel_and_int8_need_no_pool_size_named(model_and_params):
     assert layer["k"].dtype == jnp.int8 and "k_scale" in layer
     for eng in (kernel, int8):
         assert eng.pager.stats()["pages_total"] == 2  # one 64-token page a row
-    with pytest.raises(ValueError, match="paged_attn_impl"):
+    with pytest.raises(TypeError, match="paged_attn_impl"):
         LMEngine(
             model, CFG, params, max_batch=2, max_seq=64, chunk_steps=4,
             eos_id=EOS, kv_pool_tokens=16 * 8, page_size=16,
-            paged_attn_impl="nope",
+            paged_attn_impl="kernel",
         )
     with pytest.raises(ValueError, match="kv_quant"):
         LMEngine(
@@ -338,10 +607,11 @@ def int8_gather(model_and_params, shared_prompts):
     (streams, kv_quant_error observed after serving the prompts)."""
     model, params = model_and_params
     eng = LMEngine(
-        model, CFG, params, max_batch=4, max_seq=96, chunk_steps=4,
-        prefill_buckets=(32,), eos_id=EOS, kv_pool_tokens=16 * 24,
-        page_size=16, kv_quant="int8",
+        TransformerLM(CFG_GATHER), CFG_GATHER, params, max_batch=4,
+        max_seq=96, chunk_steps=4, prefill_buckets=(32,), eos_id=EOS,
+        kv_pool_tokens=16 * 24, page_size=16, kv_quant="int8",
     ).start()
+    assert not eng.kernel_read
     try:
         with ThreadPoolExecutor(len(shared_prompts)) as ex:
             futs = [
@@ -367,16 +637,15 @@ def test_int8_gather_kernel_agree_and_track_fp32(model_and_params,
     pool must not introduce further drift vs its own non-spec run."""
     model, params = model_and_params
     g8, _ = int8_gather
-    k8 = _run_engine(model, params, shared_prompts, kv_quant="int8",
-                     paged_attn_impl="kernel")
+    k8 = _run_engine(params, shared_prompts, kv_quant="int8")
     assert g8 == k8  # same dequant arithmetic → bitwise same streams
     pairs = [
         (a, b) for p, q in zip(gather_streams, g8) for a, b in zip(p, q)
     ]
     match = float(np.mean([a == b for a, b in pairs]))
     assert match >= 0.85, match
-    s8 = _run_engine(model, params, shared_prompts, kv_quant="int8",
-                     paged_attn_impl="kernel", spec_draft_tokens=4)
+    s8 = _run_engine(params, shared_prompts, kv_quant="int8",
+                     spec_draft_tokens=4)
     assert s8 == k8  # verify-span reads the same quantized pool
 
 

@@ -23,8 +23,8 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from kubeflow_tpu.ops.flash_attention import _tile_name, flash_attention
-from kubeflow_tpu.ops.flash_tuning import select_geometry
-from kubeflow_tpu.ops.paged_attention import paged_attention
+from kubeflow_tpu.ops.flash_tuning import select_geometry, select_paged_geometry
+from kubeflow_tpu.ops.paged_attention import paged_attention, paged_kernel_name
 
 
 @pytest.fixture(scope="module")
@@ -156,6 +156,18 @@ CASES["paged-bfloat16-cell-piece512"] = _paged_case(
 )
 
 
+# the same geometry's decode step as the rule stages it — 32 rows, table 16
+# pages: sixteen pages a grid step, the kv heads folded into one product —
+# the verify span (K = 3), the longest span the engine sends (16: two
+# pages an update) and the int8 pool, a head at a time
+for _kv, _s in (
+    (jnp.bfloat16, 1), (jnp.bfloat16, 4), (jnp.bfloat16, 16), (jnp.int8, 1)
+):
+    CASES[f"paged-{jnp.dtype(_kv).name}-cell-span{_s}"] = _paged_case(
+        kv_dtype=_kv, groups=4, span=_s, batch=32, kv_heads=8, head_dim=128,
+    )
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(v5e, name):
     fn, shapes = CASES[name]
@@ -168,13 +180,30 @@ def test_kernel_compiles_for_v5e(v5e, name):
         geometry = select_geometry(s, s, d, heads=h)
         for kind, tile in zip(("fwd", "dq", "dkv"), geometry):
             assert _tile_name(kind, tile) in text
+    if "-cell-span" in name:
+        # the trace will name the call by the geometry the rule chose
+        ((b, h, span, d), _), (_, kv_dtype) = shapes[0], shapes[1]
+        tile = select_paged_geometry(
+            table_pages=16, page_size=64, kv_heads=8, groups=4,
+            span=span, head_dim=d, itemsize=jnp.dtype(kv_dtype).itemsize,
+            quant=kv_dtype == jnp.int8,
+        )
+        assert paged_kernel_name(64, tile, 8) in text
 
 
 # --------------------------------------------------------------------- #
 # the serving engine's paged programs: the pool passes through untouched
 # --------------------------------------------------------------------- #
 
-def _paged_engine(kv_quant):
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The program asks the backend which read path to take and, compiling
+    for a described chip, would see this CPU: answer for the chip here, in
+    the test (`on-chip-measurement` guide §2.3), not through an option."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _paged_engine(kv_quant, *, max_batch=8, n_heads=16):
     """A small model at `mistral-7b_gen-closed`'s head geometry (8 kv
     heads of 128, 64-token pages), two layers, a pool well above what
     one chunk works on."""
@@ -182,8 +211,9 @@ def _paged_engine(kv_quant):
     from kubeflow_tpu.serve.engine import LMEngine, LMEngineConfig
 
     cfg = TransformerConfig(
-        vocab_size=512, d_model=2048, n_layers=2, n_heads=16, n_kv_heads=8,
-        d_ff=1024, max_seq_len=1024, dtype=jnp.bfloat16, attn_window=1024,
+        vocab_size=512, d_model=n_heads * 128, n_layers=2, n_heads=n_heads,
+        n_kv_heads=8, d_ff=1024, max_seq_len=1024, dtype=jnp.bfloat16,
+        attn_window=1024,
     )
     model = TransformerLM(cfg)
     abstract = jax.eval_shape(
@@ -195,9 +225,10 @@ def _paged_engine(kv_quant):
     engine = LMEngine(
         model, cfg, params,
         config=LMEngineConfig(
-            max_batch=8, max_seq=1024, prefill_buckets=(128,),
+            max_batch=max_batch, max_seq=1024, prefill_buckets=(128,),
             prefill_chunk=128, eos_id=cfg.vocab_size + 1,
-            kv_pool_tokens=16384, page_size=64, kv_quant=kv_quant,
+            kv_pool_tokens=max(16384, (max_batch * 16 + 1) * 64),
+            page_size=64, kv_quant=kv_quant,
         ),
     )
     return engine, params
@@ -223,7 +254,7 @@ def _pool_sized_copies(text, cache):
 @pytest.mark.parametrize("program", ["chunk", "prefill", "implant", "extract"])
 @pytest.mark.parametrize("kv_quant", ["none", "int8"])
 def test_paged_pool_passes_through_the_program_without_a_copy(
-    v5e, kv_quant, program
+    v5e, on_tpu, kv_quant, program
 ):
     """The pool is stored in the axis order the compiler's scatter and
     gather compute in (token-major), so no program that takes it re-lays
@@ -236,24 +267,13 @@ def test_paged_pool_passes_through_the_program_without_a_copy(
     like = lambda tree: jax.tree_util.tree_map(
         lambda a: sds(a.shape, a.dtype), tree
     )
-    a_params, a_cache = like(params), like(engine.cache)
-    key = sds((2,), jnp.uint32)
-    B, C = engine.max_batch, engine.prefill_chunk
-    if program == "chunk":
-        args = (
-            a_params, a_cache, sds((B,), jnp.int32), sds((B,), jnp.int32),
-            sds((B,), jnp.int32), sds((B,), jnp.bool_), sds((B,), jnp.int32),
-            sds((B,), jnp.float32), sds((B,), jnp.int32), key,
-            sds((B, 4), jnp.int32),
+    a_cache = like(engine.cache)
+    if program in ("chunk", "prefill"):
+        compiled = _compile_program(engine, params, program, v5e, 4)
+        # the chunk reads through the kernel, the piece gathers
+        assert ("tpu_custom_call" in compiled.as_text()) == (
+            program == "chunk"
         )
-        compiled = engine._chunk.lower(*args, seeded=False).compile()
-    elif program == "prefill":
-        args = (
-            a_params, a_cache, sds((1, C), jnp.int32), sds((1,), jnp.int32),
-            sds((), jnp.int32), sds((1, 4), jnp.int32), sds((), jnp.float32),
-            sds((), jnp.int32), sds((), jnp.int32), key,
-        )
-        compiled = engine._suffix_prefill.lower(*args, seeded=False).compile()
     else:
         # the engine builds these two per prefix length, on first use:
         # use them once on the CPU, then compile what it built
@@ -277,3 +297,77 @@ def test_paged_pool_passes_through_the_program_without_a_copy(
             for a in jax.tree_util.tree_leaves(engine.cache)
         )
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+def _compile_program(engine, params, program, v5e, table_pages):
+    """The engine's decode chunk or prefill piece, lowered and compiled
+    for the described chip with a block table ``table_pages`` wide."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+    like = lambda tree: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), tree
+    )
+    a_params, a_cache = like(params), like(engine.cache)
+    key = sds((2,), jnp.uint32)
+    B, C = engine.max_batch, engine.prefill_chunk
+    if program == "chunk":
+        args = (
+            a_params, a_cache, sds((B,), jnp.int32), sds((B,), jnp.int32),
+            sds((B,), jnp.int32), sds((B,), jnp.bool_), sds((B,), jnp.int32),
+            sds((B,), jnp.float32), sds((B,), jnp.int32), key,
+            sds((B, table_pages), jnp.int32),
+        )
+        return engine._chunk.lower(*args, seeded=False).compile()
+    args = (
+        a_params, a_cache, sds((1, C), jnp.int32), sds((1,), jnp.int32),
+        sds((), jnp.int32), sds((1, table_pages), jnp.int32),
+        sds((), jnp.float32), sds((), jnp.int32), sds((), jnp.int32), key,
+    )
+    return engine._suffix_prefill.lower(*args, seeded=False).compile()
+
+
+def _window_arrays(text, rows, tokens, kv_heads=8, head_dim=128):
+    """Arrays of the compiled text with a gathered window's shape — K or
+    V of ``rows`` x ``tokens`` keys, as gathered or transposed for the
+    einsum."""
+    shapes = {
+        f"[{rows},{tokens},{kv_heads},{head_dim}]",
+        f"[{rows},{kv_heads},{tokens},{head_dim}]",
+    }
+    return sorted({m for m in re.findall(r"\w+(\[[\d,]+\])", text) if m in shapes})
+
+
+@pytest.mark.parametrize(
+    "program,backend,windows",
+    [
+        # the parent's decode chunk (the read path a CPU takes): every
+        # layer gathers 32 rows x 1,024 tokens and transposes them
+        ("chunk", "cpu", ["[32,1024,8,128]", "[32,8,1024,128]"]),
+        # on the chip the chunk reads through the block table in the
+        # kernel: no window of the rows exists
+        ("chunk", "tpu", []),
+        # the prefill piece keeps the gather path: its text on the chip is
+        # the text the parent's read path gives, line for line
+        ("prefill", "tpu", None),
+    ],
+)
+def test_decode_chunk_holds_no_gathered_window(
+    v5e, monkeypatch, program, backend, windows
+):
+    """`mistral-7b_gen-closed`'s geometry — 8 kv heads of 128, 64-token
+    pages, 32 rows, table width 16 — lowered for the described v5e: the
+    decode chunk holds no array of the window's shape (32, 1024, 8, 128)
+    or its transpose; the prefill piece is what it was."""
+    engine, params = _paged_engine("none", max_batch=32, n_heads=32)
+
+    def text_for(backend):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        return _compile_program(engine, params, program, v5e, 16).as_text()
+
+    text = text_for(backend)
+    kernel = "paged_decode_p64_n16_h8_f4"
+    if program == "prefill":
+        assert kernel not in text and "tpu_custom_call" not in text
+        assert text == text_for("cpu")
+        return
+    assert _window_arrays(text, 32, 1024) == sorted(windows)
+    assert (kernel in text) == (backend == "tpu")

@@ -14,7 +14,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from kubeflow_tpu.ops.paged_attention import paged_attention, quantize_kv
+from kubeflow_tpu.models.transformer import paged_gather_attention
+from kubeflow_tpu.ops.flash_tuning import PagedTile, select_paged_geometry
+from kubeflow_tpu.ops.paged_attention import (
+    paged_attention,
+    paged_kernel_name,
+    quantize_kv,
+)
 
 
 def _gather_oracle(q, k_pool, v_pool, table, pos0, P, k_scale=None,
@@ -106,6 +112,122 @@ def test_paged_compiled_window():
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         rtol=2e-2, atol=2e-2,
     )
+
+
+# ------------------------------------------- the geometry, at the cell's
+# head shape (`mistral-7b_gen-closed`: 32 query heads over 8 kv heads of
+# 128, 64-token pages), compiled, against the model's own gather read
+
+#: keys each ragged row holds: one token; ending exactly on a page
+#: boundary; one key into a new page; mid-page; the table's whole width
+#: (None); -1 = a dead row on the scratch page (a free slot's position)
+RAGGED = (1, 128, 129, 300, None, -1)
+
+
+def _cell_case(table_pages, *, span=1, quant=False, seed=0):
+    H, Hkv, D, P = 32, 8, 128, 64
+    rng = np.random.default_rng(seed)
+    ctx = [
+        min(table_pages * P if c is None else c, table_pages * P)
+        for c in RAGGED
+    ]
+    B = len(ctx)
+    T = (1 + B * table_pages) * P
+    q = jnp.asarray(rng.normal(size=(B, H, span, D)) / np.sqrt(D), jnp.bfloat16)
+    kp = jnp.asarray(rng.normal(size=(T, Hkv, D)), jnp.bfloat16)
+    vp = jnp.asarray(rng.normal(size=(T, Hkv, D)), jnp.bfloat16)
+    table = 1 + rng.permutation(B * table_pages).astype(np.int32).reshape(
+        B, table_pages
+    )
+    live = np.asarray([c > 0 for c in ctx])
+    table[~live] = 0
+    pos0 = np.asarray(
+        [max(c - span, 0) if c > 0 else -1 for c in ctx], np.int32
+    )
+    cache = {"k": kp, "v": vp}
+    if quant:
+        kq, ks = quantize_kv(kp)
+        vq, vs = quantize_kv(vp)
+        cache = {"k": kq, "v": vq, "k_scale": ks.T, "v_scale": vs.T}
+    return q, cache, jnp.asarray(table), jnp.asarray(pos0), live
+
+
+def _kernel_vs_gather(q, cache, table, pos0, live, *, tile, window=None):
+    span = q.shape[2]
+    got = paged_attention(
+        q, cache["k"], cache["v"], table, pos0, page_size=64, window=window,
+        k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"), tile=tile,
+    )
+    want = jax.jit(
+        lambda q, cache: paged_gather_attention(
+            q, cache, table, pos0[:, None] + jnp.arange(span)[None, :],
+            page_size=64, window=window,
+        )
+    )(q, cache)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got[live], want[live], atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("table_pages", [1, 8, 16])
+def test_selected_geometry_compiled_on_ragged_rows(table_pages, quant):
+    """What the rule chooses at table widths 1, 8 and 16: a row of one
+    token, a row ending on a page boundary, a dead row, a full row."""
+    _kernel_vs_gather(*_cell_case(table_pages, quant=quant), tile=None)
+
+
+@pytest.mark.parametrize("pages", [1, 2, 4, 8, 16])
+def test_every_pages_per_step_compiled(pages):
+    """Every pages-per-step the rule can return, as the rule folds them
+    and a head at a time, with a window that leaves whole pages behind
+    the longer rows."""
+    fold = select_paged_geometry(
+        table_pages=16, page_size=64, kv_heads=8, groups=4, span=1,
+        head_dim=128,
+    ).fold
+    case = _cell_case(16, seed=pages)
+    _kernel_vs_gather(
+        *case, tile=PagedTile(pages, min(fold, pages)), window=200
+    )
+    _kernel_vs_gather(*case, tile=PagedTile(pages, 0), window=200)
+
+
+def test_verify_span_and_int8_pages_compiled():
+    """The verify span (K + 1 = 4) through the rule's geometry, and int8
+    pools through sixteen pages a step."""
+    _kernel_vs_gather(*_cell_case(8, span=4, seed=4), tile=None)
+    _kernel_vs_gather(
+        *_cell_case(16, quant=True, seed=5), tile=PagedTile(16, 0), window=200
+    )
+
+
+@pytest.mark.parametrize("span", [1, 4])
+def test_geometry_sweep_times_kernel_and_gather(tmp_path, span):
+    """`flash_tuning.sweep_paged_geometry` — the tool PERF.md's span
+    crossover and pages-a-step numbers were read with — times the rule's
+    choice, a forced tile and the gather from a profile of the compiled
+    calls, names the kernel it timed and says how far it lies from the
+    gather (mirroring test_block_sweep_and_tuned_s512_parity)."""
+    from kubeflow_tpu.ops import flash_tuning as ft
+
+    res = ft.sweep_paged_geometry(
+        contexts=(span, 64, 300, 512), table_pages=8, span=span,
+        gather=paged_gather_attention,
+        candidates=("gather", None, PagedTile(8, 0)), steps=3,
+        logdir=str(tmp_path),
+    )
+    assert [r["read"] for r in res] == ["gather", "kernel", "kernel"]
+    assert not [r for r in res if "error" in r], res
+    assert all(r["ms"] > 0 for r in res), res
+    rule = select_paged_geometry(
+        table_pages=8, page_size=64, kv_heads=8, groups=4, span=span,
+        head_dim=128,
+    )
+    assert res[1]["tile"] == list(rule) and res[2]["tile"] == [8, 0]
+    for r, tile in ((res[1], rule), (res[2], PagedTile(8, 0))):
+        assert paged_kernel_name(64, tile, 8) in r["ops"], r
+        assert r["max_abs_err_vs_gather"] < 2e-2, r
 
 
 def test_page_sweep_and_tuned_pickup(tmp_path):
