@@ -3,7 +3,7 @@
 
 Run from the root of the checkout on a machine with one TPU v5e:
 
-    python chip_smoke.py            # job, train, serve, restart phases
+    python chip_smoke.py            # job, train, serve, kinds, restart phases
     python chip_smoke.py --chips 4  # only the sharded path + its reference
 
 Every phase goes through the entry points a user calls (``LocalCluster``,
@@ -161,6 +161,33 @@ class ShardedPhaseConfig:
     page_size: int = 64
     kv_pool_tokens: int = 4 * 256
     tie_tol: float = 2.0 ** -4
+    seed: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class KindsPhaseConfig:
+    """A model whose layers differ — window and global attention mixed, a
+    leading dense layer, then sigmoid-routed experts beside a shared one —
+    in the key names of its published configuration
+    (`benchmark/families/afmoe.py` reads them; `benchmark/reference/
+    afmoe.py` is the oracle)."""
+
+    model: dict[str, Any]
+    #: overrides of the program's configuration (the CPU test's kernels
+    #: run under the interpreter)
+    program: dict[str, Any] = dataclasses.field(default_factory=dict)
+    #: past the window, in several prefill pieces
+    prompt_lens: tuple[int, ...] = (40, 200, 300)
+    prefill_chunk: int = 128
+    max_new_tokens: int = 32
+    max_batch: int = 4
+    max_seq: int = 512
+    chunk_steps: int = 8
+    page_size: int = 64
+    #: the loosest limits a benchmark cell may state (benchmark/check.py):
+    #: a term of the mathematics missing reads whole standard deviations
+    regret_mean_max: float = 0.05
+    argmax_share_min: float = 0.8
     seed: int = 0
 
 
@@ -696,6 +723,70 @@ def phase_serve(cfg: ServePhaseConfig) -> dict[str, Any]:
 
 
 # --------------------------------------------------------------------- #
+# kinds: layers that differ, routed experts — the engine against the
+# plain reference
+# --------------------------------------------------------------------- #
+
+def phase_kinds(cfg: KindsPhaseConfig) -> dict[str, Any]:
+    """`LMEngine` serving a model of mixed layer kinds with dropless routed
+    experts, compiled: prefill in pieces, decode through the paged kernel
+    (each layer passing its own window) and the grouped product; every
+    served token is rated by the float32 reference's full forward pass —
+    how far its logit lies below the position's best, in standard
+    deviations of the position's logits."""
+    from benchmark.families import afmoe
+    from benchmark.weights import seeded_params
+    from kubeflow_tpu.ops.grouped_matmul import gmm_kernel_runs
+    from kubeflow_tpu.serve.engine import LMEngine, LMEngineConfig
+
+    t0 = time.perf_counter()
+    pc = afmoe.program_config(cfg.model, **cfg.program)
+    model = TransformerLM(pc)
+    params = seeded_params(afmoe.abstract_params(model), cfg.seed, pc.dtype)
+    engine = LMEngine(model, pc, params, config=LMEngineConfig(
+        max_batch=cfg.max_batch, max_seq=cfg.max_seq, chunk_steps=cfg.chunk_steps,
+        prefill_buckets=(cfg.prefill_chunk,), prefill_chunk=cfg.prefill_chunk,
+        eos_id=pc.vocab_size + 1, page_size=cfg.page_size,
+    )).start()
+    prompts = _prompts(cfg.prompt_lens, pc.vocab_size, cfg.seed)
+    try:
+        answers = _engine_answers(engine, prompts, cfg.max_new_tokens)
+    finally:
+        engine.stop()
+    stats = engine.stats
+    if not engine.kernel_read or not gmm_kernel_runs(pc.interpret_kernels):
+        raise RuntimeError("the engine did not take the kernels' read path")
+    regrets = []
+    for prompt, served in zip(prompts, answers):
+        seq = np.zeros((cfg.max_seq,), np.int32)
+        seq[: len(prompt) + len(served)] = prompt + served
+        rows = np.arange(len(prompt) - 1, len(prompt) + len(served) - 1)
+        logits = np.asarray(
+            afmoe.reference_logits(params, seq, rows, cfg.model), np.float64
+        )
+        chosen = logits[np.arange(len(rows)), np.asarray(served)]
+        regrets.append((logits.max(axis=1) - chosen) / logits.std(axis=1))
+    regrets = np.concatenate(regrets)
+    out = {
+        "model": f"{pc.n_layers} layers, windows {[k.window for k in pc.kinds]}, "
+                 f"{pc.moe.num_experts} experts top-{pc.moe.top_k}",
+        "tokens_rated": int(regrets.size),
+        "regret_mean": float(regrets.mean()), "regret_max": float(regrets.max()),
+        "argmax_share": float((regrets == 0).mean()),
+        "moe_assignments_decode": int(stats["moe_assignments_decode"]),
+        "moe_experts_touched_decode": int(stats["moe_experts_touched_decode"]),
+        "kv_pages_dead_window": int(stats["kv_pages_dead_window"]),
+        "kv_pages_held": int(stats["kv_pages_held"]),
+        "wall_s": round(time.perf_counter() - t0, 2),
+    }
+    if (out["regret_mean"] > cfg.regret_mean_max
+            or out["argmax_share"] < cfg.argmax_share_min
+            or any(len(a) != cfg.max_new_tokens for a in answers)):
+        raise RuntimeError(f"the engine is not the reference's model: {out}")
+    return out
+
+
+# --------------------------------------------------------------------- #
 # restart: what the compile cache holds for the next start
 # --------------------------------------------------------------------- #
 
@@ -913,6 +1004,25 @@ def bert_config() -> BertConfig:
     )
 
 
+def kinds_config() -> dict[str, Any]:
+    """Trinity-Mini's pattern at a smoke's size: head width 128 (not
+    hidden / heads), window 128, one dense layer then sliding, sliding,
+    sliding, full expert layers of 16 experts top-4 and a shared one."""
+    return dict(
+        family="afmoe", hidden_size=512, num_attention_heads=8,
+        num_key_value_heads=2, head_dim=128,
+        layer_types=["sliding_attention"] * 4 + ["full_attention"],
+        sliding_window=128, rope_theta=10000, rms_norm_eps=1e-5,
+        num_dense_layers=1, intermediate_size=1024, num_hidden_layers=5,
+        num_experts=16, num_experts_per_tok=4, moe_intermediate_size=256,
+        num_shared_experts=1, score_func="sigmoid", route_norm=True,
+        route_scale=2.826, mup_enabled=True, vocab_size=4096,
+        tie_word_embeddings=False, hidden_act="silu",
+        max_position_embeddings=1024, activation_dtype="bfloat16",
+        weight_dtype="bfloat16",
+    )
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument(
@@ -942,6 +1052,7 @@ def main(argv: list[str] | None = None) -> int:
             bert_buckets=BucketSpec(batch_sizes=(1, 4), seq_lens=(128,)),
             lm=lm_config(),
         )))
+        emit("kinds", **phase_kinds(KindsPhaseConfig(model=kinds_config())))
         emit("restart", **phase_restart(cache_dir, entries_at_start))
     faulthandler.cancel_dump_traceback_later()
     print(json.dumps({"ok": True, "device": device}), flush=True)

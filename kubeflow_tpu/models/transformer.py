@@ -11,7 +11,10 @@ Design choices are TPU-first:
   (all_to_all SP) — the last two run in shard_map over the live mesh;
 - activations carry sharding constraints (batch over data/fsdp, seq over
   seq) so pjit propagates layouts instead of guessing;
-- optional MoE FFN every Nth layer (expert axis, ``parallel.expert``);
+- a per-layer pattern of kinds (``LayerKind``): window or global
+  attention, rope or none, a dense FFN of its own width or experts
+  (``parallel.expert``: capacity dispatch over the ``expert`` axis for
+  training, dropless grouped dispatch for serving);
 - param names line up with ``parallel.sharding.transformer_rules`` so
   FSDP/TP layouts are one function call.
 
@@ -38,11 +41,38 @@ from kubeflow_tpu.ops.paged_attention import (
     paged_attention,
     quantize_kv,
 )
-from kubeflow_tpu.parallel.expert import MoEConfig, moe_ffn
+from kubeflow_tpu.parallel.expert import MoEConfig, dropless_moe_ffn, moe_ffn
 from kubeflow_tpu.parallel.ring_attention import ring_attention_local
 from kubeflow_tpu.parallel.ulysses import ulysses_attention_local
 
 ATTN_IMPLS = ("reference", "flash", "ring", "ulysses")
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What one layer of the stack is: its attention (the keys a query
+    sees, whether q and k are rotated) and its FFN."""
+
+    #: sliding window: a query sees the ``window`` keys ending at itself
+    #: (None = every earlier key)
+    window: int | None = None
+    #: rotary embeddings on q and k (a model with ``use_rope=False`` has
+    #: learned positions and rotates nothing)
+    rope: bool = True
+    ffn: str = "dense"               # "dense" | "moe" (``cfg.moe``)
+    #: width of a dense FFN (None = ``cfg.d_ff``)
+    d_ff: int | None = None
+
+
+def moe_every_kinds(
+    n_layers: int, every: int, **kind
+) -> tuple[LayerKind, ...]:
+    """The pattern "every ``every``-th layer routes to experts, the others
+    are dense" (``kind``: what all of them share)."""
+    return tuple(
+        LayerKind(ffn="moe" if (i + 1) % every == 0 else "dense", **kind)
+        for i in range(n_layers)
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,9 +90,29 @@ class TransformerConfig:
     use_rope: bool = True            # False → learned positions (BERT)
     dtype: Any = jnp.float32         # activation/compute dtype (bf16 on TPU)
     attn_impl: str = "flash"
+    #: width of one head (None = d_model // n_heads)
+    d_head: int | None = None
     #: sliding-window attention (requires causal; flash/reference impls):
-    #: each position attends to the previous ``attn_window`` tokens only
+    #: each position attends to the previous ``attn_window`` tokens only.
+    #: The window of every layer of a model whose layers are all alike;
+    #: ``layer_kinds`` gives each layer its own
     attn_window: int | None = None
+    #: one entry per layer where layers differ (None = every layer is
+    #: ``LayerKind(attn_window, use_rope, "dense")``)
+    layer_kinds: tuple[LayerKind, ...] | None = None
+    #: epsilon of every RMS norm
+    norm_eps: float = 1e-6
+    #: RMS norm (a learned scale over the head's width) of every head of
+    #: q and k, before the rotation
+    qk_norm: bool = False
+    #: o = attention(...) * sigmoid(x . W_g): an output gate with a
+    #: projection of its own
+    attn_gate: bool = False
+    #: a second pair of norms a block, on the attention's and the FFN's
+    #: results before they join the residual stream
+    sandwich_norm: bool = False
+    #: the embedding times sqrt(d_model)
+    embed_scale: bool = False
     #: None → per-shape selection (ops/flash_tuning.py: measured table
     #: when a sweep has run on hardware, heuristic otherwise)
     attn_block_q: int | None = None
@@ -76,7 +126,7 @@ class TransformerConfig:
     #: dots_with_no_batch_dims_saveable — usually the throughput sweet
     #: spot on TPU: MXU results are kept, VPU work is replayed).
     remat_policy: str | None = None
-    moe_every: int = 0               # every Nth layer uses MoE FFN (0 = never)
+    #: the expert layers' rule (the layers whose kind says ``ffn="moe"``)
     moe: MoEConfig = dataclasses.field(default_factory=MoEConfig)
     dropout_rate: float = 0.0
     # "gather" = table lookup (best single-chip/serving). "onehot" = one-hot
@@ -89,8 +139,23 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.d_head is not None:
+            return self.d_head
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    def kind(self, layer: int) -> LayerKind:
+        if self.layer_kinds is not None:
+            return self.layer_kinds[layer]
+        return LayerKind(window=self.attn_window, rope=self.use_rope)
+
+    @property
+    def kinds(self) -> tuple[LayerKind, ...]:
+        return tuple(self.kind(i) for i in range(self.n_layers))
+
+    @property
+    def moe_layers(self) -> int:
+        return sum(k.ffn == "moe" for k in self.kinds)
 
     @property
     def kv_heads(self) -> int:
@@ -103,10 +168,30 @@ class TransformerConfig:
             raise ValueError(
                 f"attn_impl {self.attn_impl!r} not in {ATTN_IMPLS}"
             )
-        if self.attn_window is not None:
-            if self.attn_window < 1:
+        if self.layer_kinds is not None:
+            if len(self.layer_kinds) != self.n_layers:
                 raise ValueError(
-                    f"attn_window must be >= 1, got {self.attn_window}"
+                    f"layer_kinds has {len(self.layer_kinds)} entries for "
+                    f"{self.n_layers} layers"
+                )
+            if self.attn_window is not None:
+                raise ValueError(
+                    "attn_window is the window of a model whose layers are "
+                    "alike; with layer_kinds each kind carries its own"
+                )
+        for kind in set(self.kinds):
+            if kind.ffn not in ("dense", "moe"):
+                raise ValueError(f"LayerKind.ffn {kind.ffn!r} not dense/moe")
+            if kind.rope and not self.use_rope:
+                raise ValueError(
+                    "a layer kind with rope needs use_rope=True (False = "
+                    "learned positions, nothing rotated)"
+                )
+            if kind.window is None:
+                continue
+            if kind.window < 1:
+                raise ValueError(
+                    f"attn_window must be >= 1, got {kind.window}"
                 )
             if not self.causal:
                 raise ValueError("attn_window requires causal=True")
@@ -116,6 +201,8 @@ class TransformerConfig:
                     f"(got {self.attn_impl!r}); window + context parallelism "
                     "is not implemented"
                 )
+        if self.moe_layers:
+            self.moe.validate()
         if self.remat_policy not in (None, "dots"):
             raise ValueError(
                 f"remat_policy {self.remat_policy!r} not in (None, 'dots')"
@@ -270,10 +357,25 @@ def paged_gather_attention(q, cache, page_table, positions, *, page_size,
     tokens (W = table width x page size) out of the token-major pool
     ``cache`` (a layer's ``k`` / ``v``, with ``k_scale`` / ``v_scale``
     when int8), mask by position and run grouped attention over all of
-    it. q (B, H, S, D), positions (B, S)."""
+    it. q (B, H, S, D), positions (B, S). A window layer whose span's
+    windows reach fewer pages than the table is wide gathers those pages
+    only — the ``window + S - 1`` keys ending at the span's last query,
+    from the page the first one lies on (positions contiguous along S, as
+    every engine caller's are): a prefill piece far into a long prompt
+    reads what its window holds, not the prompt."""
     B, H, S, D = q.shape
     P = page_size
     Hkv = cache["k"].shape[1]
+    pages = page_table.shape[1]
+    reach = None if window is None else -(-(window + S - 1) // P) + 1
+    first = None          # the first page gathered, where not the table's
+    if reach is not None and reach < pages:
+        first = jnp.minimum(
+            jnp.maximum(positions[:, 0] - window + 1, 0) // P, pages - reach
+        )                                                          # (B,)
+        page_table = jnp.take_along_axis(
+            page_table, first[:, None] + jnp.arange(reach)[None, :], axis=1
+        )
     W = page_table.shape[1] * P
     j = jnp.arange(W)
     flat_r = (
@@ -288,14 +390,19 @@ def paged_gather_attention(q, cache, page_table, positions, *, page_size,
         Vsg = cache["v_scale"][:, flat_r].reshape(Hkv, B, W).transpose(1, 0, 2)
         Kg = dequantize_kv(Kg, Ksg)
         Vg = dequantize_kv(Vg, Vsg)
-    mask = j[None, None, :] <= positions[:, :, None]           # (B,S,W)
+    kpos = j[None, None, :]                  # the keys' absolute positions
+    if first is not None:
+        kpos = kpos + (first * P)[:, None, None]
+    mask = kpos <= positions[:, :, None]                       # (B,S,W)
     if window is not None:
-        mask &= j[None, None, :] > positions[:, :, None] - window
+        mask &= kpos > positions[:, :, None] - window
     return _grouped_cache_attention(q, Kg, Vg, mask, H // Hkv)
 
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    #: this layer's own window and rotation
+    kind: LayerKind
 
     @nn.compact
     def __call__(
@@ -311,7 +418,8 @@ class Attention(nn.Module):
         page_write_ok=None,
         kv_quant="none",
     ):
-        cfg = self.cfg
+        cfg, kind = self.cfg, self.kind
+        window = kind.window
         B, S, _ = x.shape
         H, D = cfg.n_heads, cfg.head_dim
         Hkv = cfg.kv_heads
@@ -319,10 +427,19 @@ class Attention(nn.Module):
         dense = lambda name, nh: nn.Dense(
             nh * D, use_bias=False, dtype=cfg.dtype, name=name
         )
-        q = dense("q_proj", H)(x).reshape(B, S, H, D).transpose(0, 2, 1, 3)
-        k = dense("k_proj", Hkv)(x).reshape(B, S, Hkv, D).transpose(0, 2, 1, 3)
+        # per head over its width, before the heads move in front
+        head_norm = (
+            (lambda name, t: RMSNorm(cfg.norm_eps, name=name)(t))
+            if cfg.qk_norm else (lambda name, t: t)
+        )
+        q = head_norm(
+            "q_norm", dense("q_proj", H)(x).reshape(B, S, H, D)
+        ).transpose(0, 2, 1, 3)
+        k = head_norm(
+            "k_norm", dense("k_proj", Hkv)(x).reshape(B, S, Hkv, D)
+        ).transpose(0, 2, 1, 3)
         v = dense("v_proj", Hkv)(x).reshape(B, S, Hkv, D).transpose(0, 2, 1, 3)
-        if cfg.use_rope:
+        if kind.rope:
             q, k = rope(q, positions), rope(k, positions)
         # GQA: the CACHE and projections hold Hkv heads (the memory bill);
         # attention itself sees the repeated view
@@ -414,7 +531,7 @@ class Attention(nn.Module):
                     page_table,
                     positions[:, 0],
                     page_size=P,
-                    window=cfg.attn_window,
+                    window=window,
                     k_scale=new_cache.get("k_scale"),
                     v_scale=new_cache.get("v_scale"),
                     interpret=cfg.interpret_kernels,
@@ -422,7 +539,7 @@ class Attention(nn.Module):
             else:
                 o = paged_gather_attention(
                     q, new_cache, page_table, positions, page_size=P,
-                    window=cfg.attn_window,
+                    window=window,
                 )
         elif layer_cache is not None:
             # Autoregressive decode path (SURVEY.md §2.2 "vLLM backend"
@@ -464,15 +581,15 @@ class Attention(nn.Module):
                 if getattr(cache_index, "ndim", 0) == 1:
                     qpos = cache_index[:, None] + jnp.arange(S)[None, :]
                     mask = kpos[None, None, :] <= qpos[:, :, None]  # (B,S,T)
-                    if cfg.attn_window is not None:
+                    if window is not None:
                         mask &= kpos[None, None, :] > (
-                            qpos[:, :, None] - cfg.attn_window
+                            qpos[:, :, None] - window
                         )
                 else:
                     qpos = cache_index + jnp.arange(S)
                     mask = kpos[None, :] <= qpos[:, None]
-                    if cfg.attn_window is not None:
-                        mask &= kpos[None, :] > qpos[:, None] - cfg.attn_window
+                    if window is not None:
+                        mask &= kpos[None, :] > qpos[:, None] - window
                     mask = jnp.broadcast_to(mask[None, :, :], (B, S, T))
             else:
                 # caller-supplied slot mask: slot index need NOT equal token
@@ -490,10 +607,13 @@ class Attention(nn.Module):
             o = _grouped_cache_attention(q, K, V, mask, groups)
         else:
             o = dispatch_attention(
-                q, expand(k), expand(v), cfg, segment_ids=segment_ids
+                q, expand(k), expand(v), cfg, segment_ids=segment_ids,
+                window=window,
             )
 
         o = o.transpose(0, 2, 1, 3).reshape(B, S, H * D)
+        if cfg.attn_gate:
+            o = o * nn.sigmoid(dense("gate_proj", H)(x))
         out = nn.Dense(
             cfg.d_model, use_bias=False, dtype=cfg.dtype, name="o_proj"
         )(o)
@@ -502,8 +622,11 @@ class Attention(nn.Module):
         return out
 
 
-def dispatch_attention(q, k, v, cfg: TransformerConfig, *, segment_ids=None):
-    """Route to the configured attention strategy. q/k/v: (B, H, S, D)."""
+def dispatch_attention(
+    q, k, v, cfg: TransformerConfig, *, segment_ids=None, window=None
+):
+    """Route to the configured attention strategy. q/k/v: (B, H, S, D);
+    ``window``: the calling layer's own."""
     mesh = jax.sharding.get_abstract_mesh()
     kw = dict(
         causal=cfg.causal,
@@ -516,12 +639,12 @@ def dispatch_attention(q, k, v, cfg: TransformerConfig, *, segment_ids=None):
     ):
         if cfg.attn_impl == "reference":
             return reference_attention(
-                q, k, v, causal=cfg.causal, window=cfg.attn_window,
+                q, k, v, causal=cfg.causal, window=window,
                 q_segment_ids=segment_ids, kv_segment_ids=segment_ids,
             )
         return flash_attention(
             q, k, v, q_segment_ids=segment_ids, kv_segment_ids=segment_ids,
-            window=cfg.attn_window, **kw,
+            window=window, **kw,
         )
     if mesh.empty:
         raise ValueError(
@@ -539,7 +662,7 @@ def dispatch_attention(q, k, v, cfg: TransformerConfig, *, segment_ids=None):
         def local(q, k, v, seg):
             seg = seg if has_seg else None
             return flash_attention(
-                q, k, v, window=cfg.attn_window,
+                q, k, v, window=window,
                 q_segment_ids=seg, kv_segment_ids=seg, **kw,
             )
     elif cfg.attn_impl == "ring":
@@ -577,46 +700,88 @@ def dispatch_attention(q, k, v, cfg: TransformerConfig, *, segment_ids=None):
 
 class Mlp(nn.Module):
     cfg: TransformerConfig
+    d_ff: int | None = None          # None = cfg.d_ff
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        up = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype, name="up_proj")(x)
-        gate = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype, name="gate_proj")(x)
+        d_ff = self.d_ff or cfg.d_ff
+        up = nn.Dense(d_ff, use_bias=False, dtype=cfg.dtype, name="up_proj")(x)
+        gate = nn.Dense(d_ff, use_bias=False, dtype=cfg.dtype, name="gate_proj")(x)
         return nn.Dense(
             cfg.d_model, use_bias=False, dtype=cfg.dtype, name="down_proj"
         )(nn.silu(gate) * up)
 
 
+class _Kernel(nn.Module):
+    """One ``kernel`` leaf under a scope of its own — a stack of experts
+    ``(E, in, out)`` or a router ``(in, E)`` — so that it is named, laid
+    out and seeded like a ``Dense``'s; ``bias``: a zero vector beside it."""
+
+    shape: tuple[int, ...]
+    bias: bool = False
+
+    @nn.compact
+    def __call__(self):
+        kernel = self.param(
+            "kernel",
+            nn.initializers.lecun_normal(
+                in_axis=-2, out_axis=-1,
+                batch_axis=tuple(range(len(self.shape) - 2)),
+            ),
+            self.shape,
+        )
+        if not self.bias:
+            return kernel
+        return kernel, self.param(
+            "bias", nn.initializers.zeros, self.shape[-1:]
+        )
+
+
 class Experts(nn.Module):
+    """An expert layer under ``cfg.moe``'s rule. With a capacity it is the
+    training path (`parallel.expert.moe_ffn`: dense dispatch over the
+    ``expert`` axis, tokens past an expert's capacity dropped); without
+    one it is dropless (`dropless_moe_ffn`: no token dropped, a row's
+    result independent of its neighbours — what a continuous batch
+    needs). ``live`` marks the tokens that count (pad slots and dead rows
+    do not) for the routing counters sown into ``moe_stats``."""
+
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x2d):
+    def __call__(self, x2d, live=None):
         cfg, moe = self.cfg, self.cfg.moe
-        router = self.param(
-            "router_kernel",
-            nn.initializers.lecun_normal(),
-            (cfg.d_model, moe.num_experts),
+        d, f, held = cfg.d_model, moe.expert_dim, moe.held
+        router = _Kernel(
+            (d, moe.num_experts), bias=moe.select_bias, name="router"
+        )()
+        router, bias = router if moe.select_bias else (router, None)
+        gate = (
+            _Kernel((held, d, f), name="gate_proj")()
+            if moe.expert_form == "gated_silu" else None
         )
-        up = self.param(
-            "up_kernel",
-            nn.initializers.lecun_normal(),
-            (moe.num_experts, cfg.d_model, moe.expert_dim),
-        )
-        down = self.param(
-            "down_kernel",
-            nn.initializers.lecun_normal(),
-            (moe.num_experts, moe.expert_dim, cfg.d_model),
-        )
-        out, aux, stats = moe_ffn(x2d, router, up, down, moe)
-        self.sow("losses", "moe_aux", aux)
+        up = _Kernel((held, d, f), name="up_proj")()
+        down = _Kernel((held, f, d), name="down_proj")()
+        if moe.capacity_factor is None:
+            out, counts = dropless_moe_ffn(
+                x2d, router, bias, gate, up, down, moe, live=live,
+                interpret=cfg.interpret_kernels,
+            )
+            self.sow("moe_stats", "assignments", counts)
+        else:
+            out, aux, _ = moe_ffn(x2d, router, up, down, moe)
+            self.sow("losses", "moe_aux", aux)
+        if moe.shared_experts:
+            out = out + Mlp(
+                cfg, d_ff=moe.shared_experts * f, name="shared"
+            )(x2d)
         return out
 
 
 class Block(nn.Module):
     cfg: TransformerConfig
-    use_moe: bool = False
+    kind: LayerKind
 
     @nn.compact
     def __call__(
@@ -632,11 +797,12 @@ class Block(nn.Module):
         page_write_ok=None,
         kv_quant="none",
     ):
-        cfg = self.cfg
+        cfg, kind = self.cfg, self.kind
+        norm = lambda name: RMSNorm(cfg.norm_eps, name=name)
         new_cache = None
-        attn_in = RMSNorm(name="ln1")(x)
+        attn_in = norm("ln1")(x)
         if layer_cache is not None:
-            h, new_cache = Attention(cfg, name="attn")(
+            h, new_cache = Attention(cfg, kind, name="attn")(
                 attn_in, positions, segment_ids,
                 layer_cache=layer_cache, cache_index=cache_index,
                 kv_mask=kv_mask, page_table=page_table,
@@ -644,15 +810,20 @@ class Block(nn.Module):
                 kv_quant=kv_quant,
             )
         else:
-            h = Attention(cfg, name="attn")(attn_in, positions, segment_ids)
+            h = Attention(cfg, kind, name="attn")(attn_in, positions, segment_ids)
+        if cfg.sandwich_norm:
+            h = norm("ln1_post")(h)
         x = _act_constraint(x + h)
-        y = RMSNorm(name="ln2")(x)
-        if self.use_moe:
+        y = norm("ln2")(x)
+        if kind.ffn == "moe":
             B, S, d = y.shape
-            out = Experts(cfg, name="experts")(y.reshape(B * S, d))
+            live = None if page_write_ok is None else page_write_ok.reshape(B * S)
+            out = Experts(cfg, name="experts")(y.reshape(B * S, d), live)
             y = out.reshape(B, S, d)
         else:
-            y = Mlp(cfg, name="mlp")(y)
+            y = Mlp(cfg, d_ff=kind.d_ff, name="mlp")(y)
+        if cfg.sandwich_norm:
+            y = norm("ln2_post")(y)
         out = _act_constraint(x + y)
         if layer_cache is not None:
             return out, new_cache
@@ -678,6 +849,7 @@ class TransformerLM(nn.Module):
         page_size=None,
         page_write_ok=None,
         kv_quant="none",
+        logit_positions=None,
     ):
         """Training/scoring: ``(tokens) -> logits``. Autoregressive serving:
         pass ``cache`` (from :func:`init_kv_cache`) + ``cache_index`` →
@@ -687,7 +859,10 @@ class TransformerLM(nn.Module):
         Paged serving (serve/paging.py) instead passes a pooled cache from
         :func:`init_paged_kv_cache` + ``page_table``/``page_size``/
         ``page_write_ok`` and explicit ``positions``; masking is derived
-        from positions in-branch (kv_mask unused)."""
+        from positions in-branch (kv_mask unused). ``logit_positions`` (B, K):
+        the indices along S whose logits are wanted — the hidden state is
+        gathered there before the head, so logits are (B, K, vocab) and the
+        head's product is K rows, not S (a prefill piece wants one)."""
         cfg = self.cfg
         cfg.validate()
         B, S = tokens.shape
@@ -698,6 +873,8 @@ class TransformerLM(nn.Module):
             cfg.vocab_size, cfg.d_model,
             dtype=cfg.dtype, impl=cfg.embed_impl, name="embed",
         )(tokens)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
         if not cfg.use_rope:
             pos_emb = self.param(
                 "pos_embedding",
@@ -714,8 +891,7 @@ class TransformerLM(nn.Module):
         BlockCls = nn.remat(Block, policy=policy) if cfg.remat else Block
         new_cache = {} if cache is not None else None
         for i in range(cfg.n_layers):
-            use_moe = cfg.moe_every > 0 and (i + 1) % cfg.moe_every == 0
-            block = BlockCls(cfg, use_moe=use_moe, name=f"layers_{i}")
+            block = BlockCls(cfg, cfg.kind(i), name=f"layers_{i}")
             if cache is not None:
                 x, new_cache[f"layers_{i}"] = block(
                     x, positions, segment_ids,
@@ -729,7 +905,9 @@ class TransformerLM(nn.Module):
                 )
             else:
                 x = block(x, positions, segment_ids)
-        x = RMSNorm(name="ln_f")(x)
+        if logit_positions is not None:
+            x = jnp.take_along_axis(x, logit_positions[:, :, None], axis=1)
+        x = RMSNorm(cfg.norm_eps, name="ln_f")(x)
         logits = nn.Dense(
             cfg.vocab_size, use_bias=False, dtype=jnp.float32, name="unembed"
         )(x)

@@ -109,6 +109,12 @@ def transformer_rules(
     m = Axis.MODEL if tensor else None
     f = Axis.FSDP if fsdp else None
     rules: list[tuple[str, P]] = [
+        # MoE experts: stacks (n_experts, in, out) — expert dim over the
+        # expert axis — before the projections' own rules, which their
+        # names would also match
+        (r"experts/(up|gate)_proj/kernel$", P(Axis.EXPERT, f, m)),
+        (r"experts/down_proj/kernel$", P(Axis.EXPERT, m, f)),
+        (r"experts/router/kernel$", P(f, None)),
         (r"embed/embedding$", P(m, f)),            # (vocab, d_model)
         (r"(q_proj|k_proj|v_proj)/kernel$", P(f, m)),
         (r"o_proj/kernel$", P(m, f)),
@@ -117,9 +123,5 @@ def transformer_rules(
         (r"unembed/kernel$", P(f, m)),             # (d_model, vocab)
         (r"(q_proj|k_proj|v_proj|up_proj|gate_proj)/bias$", P(m)),
         (r"(scale|bias)$", P()),
-        # MoE experts: (n_experts, in, out) — expert dim over expert axis
-        (r"experts/(up|gate)_kernel$", P(Axis.EXPERT, f, m)),
-        (r"experts/down_kernel$", P(Axis.EXPERT, m, f)),
-        (r"experts/router_kernel$", P(f, None)),
     ]
     return ShardingRules(tuple(rules))

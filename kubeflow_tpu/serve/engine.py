@@ -48,6 +48,7 @@ epoch, and those are exactly the points that re-upload.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import queue
 import threading
@@ -235,6 +236,9 @@ class _PendingChunk:
     eos: Any = None    # (B, T) a live EOS landed in this step's span
     prop: Any = None   # (B, T) draft tokens proposed (live rows)
     acc: Any = None    # (B, T) draft tokens accepted (live rows)
+    #: the chunk's routing counts (LMEngine._moe_counts), device handles;
+    #: None for a model with no expert layer
+    moe: Any = None
     # dispatch stamp (time.monotonic) — the drain records one
     # ``decode.chunk`` span per traced resident row from this
     t_dispatch: float = 0.0
@@ -376,6 +380,11 @@ class EngineOverloaded(RuntimeError):
     """Admission queue full — callers should shed load (HTTP 429)."""
 
 
+#: the routing counters a program with expert layers returns beside its
+#: tokens (`LMEngine._moe_counts`), each kept per phase in ``stats``
+_MOE_COUNTERS = ("assignments", "experts_touched", "layer_steps", "load_max")
+
+
 class LMEngine:
     """Continuous-batching engine over a TransformerLM + params.
 
@@ -448,6 +457,13 @@ class LMEngine:
 
         enable_compilation_cache()  # engine start is compile-dominated
         self.model, self.cfg = model, cfg
+        #: whether the model routes to experts: its programs then return
+        #: the routing counts of live rows beside their tokens
+        self._moe = cfg.moe_layers > 0
+        #: assignments per expert, live rows, since the engine started
+        self.moe_expert_load = np.zeros(
+            (cfg.moe.num_experts if self._moe else 0,), np.int64
+        )
         self.mesh = mesh
         #: label for engine-stage spans and the TTFT/TPOT histograms;
         #: LMEngineModel stamps its serving-model name here
@@ -626,6 +642,20 @@ class LMEngine:
             # is the share of the window that exists
             "decode_chunks_kernel_read": 0,
             "decode_pages_live": 0, "decode_pages_window": 0,
+            # beside them, what the active rows hold over all layers (pages
+            # x layers) and, of that, the pages lying wholly before a
+            # window layer's window: held in vain on the one table every
+            # layer shares — what a per-kind allocator would free
+            "kv_pages_held": 0, "kv_pages_dead_window": 0,
+            # expert layers' routing of LIVE rows only (pad slots and dead
+            # rows excluded), decode chunks and prefill pieces apart:
+            # (token, expert) assignments, distinct (layer, step, expert)
+            # triples touched, the layer-steps they are summed over, and
+            # the fullest expert's assignments summed over layer-steps
+            **{
+                f"moe_{name}_{phase}": 0
+                for name in _MOE_COUNTERS for phase in ("decode", "prefill")
+            },
             # the scheduler thread's wall time, once per loop iteration, and
             # under it each phase's self seconds and entries (_phase)
             "sched_loop_s": 0.0,
@@ -750,6 +780,11 @@ class LMEngine:
             self.kernel_read = paged_kernel_read(
                 cfg, self.max_batch, self.spec_k + 1
             )
+        #: window layers by their window: what the dead pages counted at
+        #: each chunk's dispatch are summed over
+        self._window_layers = collections.Counter(
+            kind.window for kind in cfg.kinds if kind.window is not None
+        )
         self._implant_jits: dict[int, Any] = {}
         #: a request held back by page backpressure (FIFO preserved:
         #: nothing admits past it until its pages free up)
@@ -925,11 +960,9 @@ class LMEngine:
             write_ok = live0[:, None] & (
                 positions < (real_len + budget)[:, None]
             )
-            lg, cache = self.model.apply(
-                {"params": params}, x, cache=cache,
-                positions=positions, page_table=table,
-                page_size=self.page_size, page_write_ok=write_ok,
-                kv_quant=self.kv_quant,
+            lg, cache, moe, _ = self._forward(
+                params, x, cache, positions=positions, page_table=table,
+                page_write_ok=write_ok,
             )
             emitted, n_emit, n_acc = spec_accept(
                 lg, draft, draft_len, sub, temperature
@@ -950,7 +983,7 @@ class LMEngine:
             )
             hist = self._spec_hist_update(hist, L, emitted, live_i)
             return (cache, hist, tok, gen_count, active, rng), (
-                out, valid_i, eos_step, prop, acc,
+                out, valid_i, eos_step, prop, acc, moe,
             )
 
         (cache, hist, tok, gen_count, active, _), outs = jax.lax.scan(
@@ -959,14 +992,66 @@ class LMEngine:
             None,
             length=self.chunk_steps,
         )
-        toks, valid, eos, prop, acc = outs
+        toks, valid, eos, prop, acc, moe = outs
         return (
             cache, hist, tok, gen_count, active,
             jnp.moveaxis(toks, 0, 1), jnp.moveaxis(valid, 0, 1),
             eos.T, prop.T, acc.T,
-        )
+        ) + self._moe * (jax.tree_util.tree_map(lambda x: x.sum(0), moe),)
 
     # -- device programs through the block table (serve/paging.py) ---------- #
+
+    def _forward(self, params, tokens, cache, *, quant_stats=False, **kw):
+        """The model through the block table: ``(logits, cache, moe,
+        qerr)``. ``moe``: the routing counts of this call's live tokens
+        where the model has expert layers, else None (``_moe_counts``);
+        ``qerr``: the int8 pool's quantization error (abs, den) where
+        asked for. A model with neither is applied exactly as before."""
+        mutable = ["moe_stats"] * self._moe + ["quant_stats"] * quant_stats
+        variables = {"params": params}
+        kw = dict(
+            kw, cache=cache, page_size=self.page_size, kv_quant=self.kv_quant
+        )
+        if not mutable:
+            logits, cache = self.model.apply(variables, tokens, **kw)
+            return logits, cache, None, None
+        (logits, cache), sown = self.model.apply(
+            variables, tokens, mutable=mutable, **kw
+        )
+        leaves = jax.tree_util.tree_leaves
+        return (
+            logits, cache,
+            self._moe_counts(leaves(sown["moe_stats"])) if self._moe else None,
+            sum(leaves(sown["quant_stats"])) if quant_stats else None,
+        )
+
+    @staticmethod
+    def _moe_counts(per_layer):
+        """One forward's routing, from each expert layer's assignments per
+        expert (live tokens only): the vector summed over layers, the
+        (layer, expert) pairs touched, the layers that had a live token,
+        and the fullest expert's assignments summed over layers."""
+        return {
+            "per_expert": sum(per_layer),
+            "experts_touched": sum((c > 0).sum() for c in per_layer),
+            "layer_steps": sum((c.sum() > 0).astype(jnp.int32) for c in per_layer),
+            "load_max": sum(c.max() for c in per_layer),
+        }
+
+    def _count_moe(self, counts: list, phase: str) -> dict:
+        """Fold drained ``_moe_counts`` — a chunk's, summed over its steps,
+        or one of each of a request's pieces — into ``stats``; returns
+        their sum as host ints."""
+        moe = {
+            k: sum(np.asarray(c[k]) for c in counts)  # kft: noqa[jax-sync] — rides the token drain (a chunk) or the final piece's sample (a prefill)
+            for k in counts[0]
+        }
+        self.moe_expert_load += moe["per_expert"]
+        out = {"assignments": int(moe["per_expert"].sum())}
+        out.update({k: int(moe[k]) for k in _MOE_COUNTERS[1:]})
+        for name, value in out.items():
+            self.stats[f"moe_{name}_{phase}"] += value
+        return out
 
     def _pages_w(self, tokens: int) -> int:
         """Read-window width in pages: pow2-rounded so the compiled
@@ -990,30 +1075,19 @@ class LMEngine:
         S = suffix.shape[1]
         positions = offset + jnp.arange(S)[None, :]          # (1, S)
         write_ok = (jnp.arange(S) < slen[:, None])           # (1, S)
-        kw = dict(
-            positions=positions, page_table=table,
-            page_size=self.page_size, page_write_ok=write_ok,
-            kv_quant=self.kv_quant,
+        # the ONLY program that materializes the quantization-error
+        # telemetry the model sows (abs, den): per-admission amortization,
+        # and the scan-carry chunk programs stay telemetry-free. The head
+        # is computed at the one position whose logits are sampled: the
+        # piece's other S - 1 rows of (S, vocab) are never built
+        logits, cache, moe, qerr = self._forward(
+            params, suffix, cache, quant_stats=self.kv_quant == "int8",
+            positions=positions, page_table=table, page_write_ok=write_ok,
+            logit_positions=(slen - 1)[:, None],
         )
-        if self.kv_quant == "int8":
-            # the ONLY program that materializes the quantization-error
-            # telemetry the model sows: per-admission amortization, and
-            # the scan-carry chunk programs stay telemetry-free
-            (logits, cache), qs = self.model.apply(
-                {"params": params}, suffix, cache=cache,
-                mutable=["quant_stats"], **kw,
-            )
-            qerr = sum(
-                jax.tree_util.tree_leaves(qs["quant_stats"])
-            )                                                # (2,) abs, den
-        else:
-            logits, cache = self.model.apply(
-                {"params": params}, suffix, cache=cache, **kw,
-            )
+        if qerr is None:
             qerr = jnp.zeros((2,), jnp.float32)
-        last = jnp.take_along_axis(
-            logits, (slen - 1)[:, None, None], axis=1
-        )[:, 0]
+        last = logits[:, 0]
         tok = _sample(last, rng, temperature[None])
         if seeded:
             tok = self._seeded_sample(
@@ -1021,7 +1095,7 @@ class LMEngine:
                 jnp.asarray(pos, jnp.int32)[None], temperature[None], tok,
             )
         tok = tok[0]
-        return cache, tok, tok != self.eos_id, qerr
+        return (cache, tok, tok != self.eos_id, qerr) + self._moe * (moe,)
 
     def _implant_paged(self, stored, row: int, n16: int):
         """Scatter a stored prefix (1, kv_heads, n16, D per layer —
@@ -1091,15 +1165,9 @@ class LMEngine:
             rng, sub = jax.random.split(rng)
             live = active & (gen_count < budget)             # (B,)
             cur = real_len + gen_count - 1                   # (B,) token idx
-            lg, cache = self.model.apply(
-                {"params": params},
-                tok[:, None],
-                cache=cache,
-                positions=cur[:, None],
-                page_table=table,
-                page_size=self.page_size,
-                page_write_ok=live[:, None],
-                kv_quant=self.kv_quant,
+            lg, cache, moe, _ = self._forward(
+                params, tok[:, None], cache, positions=cur[:, None],
+                page_table=table, page_write_ok=live[:, None],
             )
             nxt = _sample(lg[:, 0], sub, temperature)
             if seeded:
@@ -1110,15 +1178,19 @@ class LMEngine:
             out = jnp.where(valid, nxt, self.pad_id)
             gen_count = jnp.where(live, gen_count + 1, gen_count)
             tok = jnp.where(valid, out, tok)
-            return (cache, tok, gen_count, valid, rng), (out, valid)
+            return (cache, tok, gen_count, valid, rng), (out, valid, moe)
 
-        (cache, tok, gen_count, active, _), (toks, valid) = jax.lax.scan(
+        (cache, tok, gen_count, active, _), (toks, valid, moe) = jax.lax.scan(
             step,
             (cache, last_tok, gen_count, active, rng),
             None,
             length=self.chunk_steps,
         )
-        return cache, tok, gen_count, active, toks.T, valid.T  # (B, T)
+        # (B, T) tokens; beside them, where the model routes, the chunk's
+        # routing counts summed over its steps
+        return (cache, tok, gen_count, active, toks.T, valid.T) + self._moe * (
+            jax.tree_util.tree_map(lambda x: x.sum(0), moe),
+        )
 
     # -- host scheduler ----------------------------------------------------- #
 
@@ -1899,7 +1971,7 @@ class LMEngine:
             pos = base + i * C + len(piece_ids)
             pages_w = self._pages_w(base + i * C + C)
             with self._mesh_scope():
-                self.cache, tok, valid, qerr = self._suffix_prefill(
+                self.cache, tok, valid, qerr, *moe = self._suffix_prefill(
                     self.params,
                     self.cache,
                     jnp.asarray(piece),
@@ -1925,9 +1997,17 @@ class LMEngine:
         self.stats["prefill_tokens"] += len(piece_ids)
         self.stats["prefill_padded_tokens"] += C
         st["piece"] = i + 1
+        # device handles: read with the final piece's sample
+        st.setdefault("moe", []).extend(moe)
         if not final:
             return  # tok is a throwaway sample from a non-final position
         del self._prefilling[row]
+        if st["moe"]:
+            with self._phase("prefill_wait"):
+                routed = self._count_moe(st["moe"], "prefill")
+            if req.pspan is not None:
+                req.pspan.set_attr("assignments", routed["assignments"])
+                req.pspan.set_attr("experts_touched", routed["experts_touched"])
         if req.pspan is not None:
             req.pspan.end()
             req.pspan = None
@@ -2301,7 +2381,7 @@ class LMEngine:
             if self.spec_k:
                 (
                     self.cache, c["hist"], tok, gen_count, active,
-                    toks, valid, eos, prop, acc,
+                    toks, valid, eos, prop, acc, *moe,
                 ) = self._chunk(
                     self.params, self.cache, c["hist"], c["last_tok"],
                     c["real_len"], c["gen_count"], c["active"], c["budget"],
@@ -2310,7 +2390,7 @@ class LMEngine:
                 )
             else:
                 (
-                    self.cache, tok, gen_count, active, toks, valid
+                    self.cache, tok, gen_count, active, toks, valid, *moe
                 ) = self._chunk(
                     self.params, self.cache, c["last_tok"], c["real_len"],
                     c["gen_count"], c["active"], c["budget"], c["temp"],
@@ -2323,17 +2403,23 @@ class LMEngine:
         # the rows' reach as the host last saw it (the drain of a chunk in
         # flight will move it on by up to a chunk's tokens)
         reach = (self.real_len + self.gen_count)[self.active]
-        self.stats["decode_pages_live"] += int(
-            (-(-reach // self.page_size)).sum()
-        )
+        pages_live = int((-(-reach // self.page_size)).sum())
+        self.stats["decode_pages_live"] += pages_live
         self.stats["decode_pages_window"] += (
             self.max_batch * self._carry_pages_w
         )
+        self.stats["kv_pages_held"] += self.cfg.n_layers * pages_live
+        for window, layers in self._window_layers.items():
+            # the pages wholly before the last token's window: those
+            # below the one that holds key (reach - 1) - window + 1
+            self.stats["kv_pages_dead_window"] += layers * int(
+                (np.maximum(reach - window, 0) // self.page_size).sum()
+            )
         return _PendingChunk(
             toks=toks, valid=valid, last_tok=tok, gen_count=gen_count,
             active_out=active, active_in=active_in,
             slots=list(self._slots), eos=eos, prop=prop, acc=acc,
-            t_dispatch=time.monotonic(),
+            moe=moe[0] if moe else None, t_dispatch=time.monotonic(),
         )
 
     def _drain_chunk(self, p: _PendingChunk) -> None:
@@ -2361,6 +2447,8 @@ class LMEngine:
                 )
             self._ewma("d2h_drain_ms", (time.perf_counter() - t0) * 1e3)
         with self._phase("drain_emit"):
+            if p.moe is not None:
+                self._count_moe([p.moe], "decode")
             chunk_prop = chunk_acc = 0
             for row in range(self.max_batch):
                 req = p.slots[row]

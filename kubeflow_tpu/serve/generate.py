@@ -91,6 +91,13 @@ def make_generate_fn(
     prefill + scan-decode, jittable per (batch, prefill_len) bucket."""
 
     sample = sample_logits
+    if len({kind.window for kind in cfg.kinds}) > 1:
+        # decode_kv_mask below is one slot mask for every layer
+        raise ValueError(
+            "this dense-cache path masks every layer alike; a model whose "
+            "layer kinds differ in their window is served by LMEngine"
+        )
+    window = cfg.kind(0).window
 
     def generate(params, prompt, prompt_len, rng, temperature):
         B, P = prompt.shape
@@ -125,7 +132,7 @@ def make_generate_fn(
             # this one; never pad slots, never unwritten slots
             positions = (prompt_len + j)[:, None]  # rope continues per row
             kv_mask = decode_kv_mask(
-                kpos, prompt_len, P, slot, cfg.attn_window
+                kpos, prompt_len, P, slot, window
             )
             lg, cache = model.apply(
                 {"params": params},

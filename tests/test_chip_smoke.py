@@ -67,6 +67,27 @@ def test_serve_phase_tiny():
     json.dumps(out)
 
 
+def test_kinds_phase_tiny():
+    """The mixed-kind routed pattern at a tiny size, float32: the engine's
+    tokens are the reference's choices."""
+    model = dict(
+        chip_smoke.kinds_config(), hidden_size=64, num_attention_heads=4,
+        head_dim=32, sliding_window=8, intermediate_size=96,
+        moe_intermediate_size=48, vocab_size=128, activation_dtype="float32",
+        weight_dtype="float32",
+    )
+    out = chip_smoke.phase_kinds(chip_smoke.KindsPhaseConfig(
+        model=model, program={"interpret_kernels": True},
+        prompt_lens=(9, 37), prefill_chunk=16, max_new_tokens=6, max_batch=2,
+        max_seq=64, chunk_steps=2, page_size=16,
+    ))
+    assert out["tokens_rated"] == 12 and out["argmax_share"] == 1.0
+    assert out["regret_max"] <= 1e-3
+    assert out["moe_assignments_decode"] == 16 * 2 * 5
+    assert 0 < out["kv_pages_dead_window"] < out["kv_pages_held"]
+    json.dumps(out)
+
+
 def test_greedy_mismatch_is_explained_and_refused():
     """The near-tie rule: a differing token passes only when the oracle
     holds the two within tolerance — a wrong token does not."""

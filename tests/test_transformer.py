@@ -14,6 +14,7 @@ from kubeflow_tpu.models.transformer import (
     TransformerLM,
     make_init_fn,
     make_loss_fn,
+    moe_every_kinds,
 )
 from kubeflow_tpu.parallel.expert import MoEConfig
 from kubeflow_tpu.parallel.sharding import transformer_rules
@@ -137,7 +138,7 @@ def test_train_moe_expert_parallel(devices8):
     cfg = _cfg(
         n_layers=2,
         attn_impl="reference",
-        moe_every=2,
+        layer_kinds=moe_every_kinds(2, 2),
         moe=MoEConfig(num_experts=4, expert_dim=64, top_k=2),
     )
     trainer, state, history = _train(
@@ -145,7 +146,7 @@ def test_train_moe_expert_parallel(devices8):
     )
     assert history[-1]["loss"] < history[0]["loss"]
     assert "moe_aux" in history[0]
-    up = state.params["layers_1"]["experts"]["up_kernel"]
+    up = state.params["layers_1"]["experts"]["up_proj"]["kernel"]
     assert up.sharding.spec[0] == Axis.EXPERT
 
 

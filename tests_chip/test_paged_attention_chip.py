@@ -124,8 +124,8 @@ def test_paged_compiled_window():
 RAGGED = (1, 128, 129, 300, None, -1)
 
 
-def _cell_case(table_pages, *, span=1, quant=False, seed=0):
-    H, Hkv, D, P = 32, 8, 128, 64
+def _cell_case(table_pages, *, span=1, quant=False, seed=0, kv_heads=8):
+    H, Hkv, D, P = 32, kv_heads, 128, 64
     rng = np.random.default_rng(seed)
     ctx = [
         min(table_pages * P if c is None else c, table_pages * P)
@@ -191,6 +191,23 @@ def test_every_pages_per_step_compiled(pages):
         *case, tile=PagedTile(pages, min(fold, pages)), window=200
     )
     _kernel_vs_gather(*case, tile=PagedTile(pages, 0), window=200)
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["window2048", "global"])
+def test_trinity_geometry_compiled_with_and_without_a_window(window):
+    """`trinity-mini_mixed-closed`'s head shape — 32 query heads over 4 kv
+    heads of 128, eight a group — at the widest table its engine sends
+    (136 pages: max_seq 8,704, not a power of two), as a window layer
+    (2,048: the full row's first 103 pages are dead) and as the global
+    layer read it, against the gather."""
+    tile = select_paged_geometry(
+        table_pages=136, page_size=64, kv_heads=4, groups=8, span=1,
+        head_dim=128,
+    )
+    assert paged_kernel_name(64, tile, 4) == "paged_decode_p64_n16_h4_f4"
+    _kernel_vs_gather(*_cell_case(136, kv_heads=4, seed=7), tile=None, window=window)
+    # and a narrower table, as shorter rows get
+    _kernel_vs_gather(*_cell_case(32, kv_heads=4, seed=8), tile=None, window=window)
 
 
 def test_verify_span_and_int8_pages_compiled():
