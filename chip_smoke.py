@@ -52,7 +52,11 @@ from kubeflow_tpu.models.bert import (
     make_mlm_init_fn,
     make_mlm_loss_fn,
 )
-from kubeflow_tpu.models.transformer import TransformerConfig, TransformerLM
+from kubeflow_tpu.models.transformer import (
+    TransformerConfig,
+    TransformerLM,
+    paged_flash_read,
+)
 from kubeflow_tpu.obs import names
 from kubeflow_tpu.serve.model import BucketSpec
 
@@ -621,6 +625,9 @@ async def _serve(cfg: ServePhaseConfig) -> dict[str, Any]:
         label = '{model="lm"}'
         served = {
             "prefill_pieces": metrics[f"{names.ENGINE_PREFIX}prefill_pieces{label}"],
+            "prefill_pieces_flash_read": metrics[
+                f"{names.ENGINE_PREFIX}prefill_pieces_flash_read{label}"
+            ],
             "decode_chunks": metrics[f"{names.ENGINE_PREFIX}chunks{label}"],
             "carry_uploads": metrics[names.ENGINE_CARRY_UPLOADS_TOTAL + label],
             "ttft_count": metrics[f"{names.SERVER_TTFT_MS}_count{label}"],
@@ -631,6 +638,13 @@ async def _serve(cfg: ServePhaseConfig) -> dict[str, Any]:
         if min(served["prefill_pieces"], served["decode_chunks"],
                served["ttft_count"], served["tpot_count"]) <= 0:
             raise RuntimeError(f"/metrics shows no engine work: {served}")
+        # the piece's read path is the model's own answer for its shape:
+        # on the chip a 128-token piece attends through the flash kernel
+        flash_pieces = served["prefill_pieces"] * paged_flash_read(
+            cfg.lm, cfg.prefill_chunk
+        )
+        if served["prefill_pieces_flash_read"] != flash_pieces:
+            raise RuntimeError(f"pieces through the flash kernel: {served}")
 
         # ---- read paths: kernel == gather, int8 kernel == int8 gather -- #
         # the engine chooses the read path itself: the Pallas kernel for

@@ -27,6 +27,7 @@ model zoo is first-party so every parallel strategy is testable end-to-end.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import flax.linen as nn
@@ -35,7 +36,12 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from kubeflow_tpu.core.mesh import Axis
-from kubeflow_tpu.ops.flash_attention import flash_attention, reference_attention
+from kubeflow_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_attention_span,
+    reference_attention,
+)
+from kubeflow_tpu.ops.flash_tuning import span_kv_block
 from kubeflow_tpu.ops.paged_attention import (
     dequantize_kv,
     paged_attention,
@@ -325,57 +331,94 @@ def _grouped_cache_attention(q, K, V, mask, groups):
 #: times faster through the kernel — the gather reads every row's share
 #: of the widest row's window, which mostly does not exist.
 PAGED_KERNEL_MAX_SPAN = 16
-#: the same for a call of one row — a prefill piece, or an engine of one
-#: row. One row's window is its own, so the gather wastes nothing: at 480
-#: and 1,000 keys the two read within 15 % of each other up to 8 queries
-#: (a table of one page: the kernel, 1.5 to 3.6 times), at 16 the gather
-#: is 1.2 to 1.35 times faster and at 32 and over 1.7 to 3 times.
+#: the same for a call of one row — an engine of one row, or a short
+#: prefill piece. One row's window is its own, so gathering it wastes
+#: nothing: at 480 and 1,000 keys the two read within 15 % of each other
+#: up to 8 queries (a table of one page: the kernel, 1.5 to 3.6 times), at
+#: 16 the gather is 1.2 to 1.35 times faster and at 32 and over 1.7 to 3
+#: times. What a long span pays for in the gather is not the window but
+#: the float32 scores over it: from ``PAGED_FLASH_MIN_SPAN`` on the
+#: gathered window goes through the flash forward kernel instead.
 PAGED_KERNEL_MAX_SPAN_ONE_ROW = 8
+#: the shortest span whose gathered window is read by the flash forward
+#: kernel (`ops/flash_attention.py::flash_attention_span`), and the
+#: granule a longer one has to be a multiple of: the kernel's q block is
+#: a whole number of 128-row lane tiles. An engine's prefill piece is 512
+#: or 1,024 tokens; spans of 17 to 127, and lengths off the granule, keep
+#: `paged_gather_attention`. Measured on a v5e, one row of 32 heads
+#: (PERF.md section 6, PR 35): from 67 MB of float32 scores on (512
+#: queries x 1,024 keys) the kernel reads 2.4 to 6 times faster; under
+#: about 33 MB (512 x 512) XLA keeps the scores on the chip and the
+#: gather is 0.01 to 0.025 ms a layer ahead — the rule stays span alone.
+PAGED_FLASH_MIN_SPAN = 128
 
 
-def paged_kernel_read(cfg: TransformerConfig, rows: int, span: int) -> bool:
-    """Whether the paged branch reads K and V through the Pallas kernel
-    (`ops/paged_attention.py`) or gathers the rows' windows: decided by
-    what the code can observe. The kernel where the backend is a TPU (or
-    the configuration asks for the interpreter, which is how the CPU
-    suites run it), no mesh is in force (a Mosaic kernel is not
-    partitioned automatically) and the call is a decode or verify shape:
-    a short span of several rows, or a shorter one of one row. The engine
-    asks the same question for its counter
-    (`stats["decode_chunks_kernel_read"]`)."""
-    limit = PAGED_KERNEL_MAX_SPAN if rows > 1 else PAGED_KERNEL_MAX_SPAN_ONE_ROW
+def _kernels_usable(cfg: TransformerConfig) -> bool:
+    """Whether a Pallas kernel can run where this call is traced: the
+    backend is a TPU (or the configuration asks for the interpreter,
+    which is how the CPU suites run one) and no mesh is in force (a
+    Mosaic kernel is not partitioned automatically)."""
     return (
-        span <= limit
-        and (cfg.interpret_kernels or jax.default_backend() == "tpu")
+        (cfg.interpret_kernels or jax.default_backend() == "tpu")
         and jax.sharding.get_abstract_mesh().empty
     )
 
 
-def paged_gather_attention(q, cache, page_table, positions, *, page_size,
-                           window):
-    """The paged branch's XLA read: gather each row's first W logical
-    tokens (W = table width x page size) out of the token-major pool
-    ``cache`` (a layer's ``k`` / ``v``, with ``k_scale`` / ``v_scale``
-    when int8), mask by position and run grouped attention over all of
-    it. q (B, H, S, D), positions (B, S). A window layer whose span's
-    windows reach fewer pages than the table is wide gathers those pages
-    only — the ``window + S - 1`` keys ending at the span's last query,
-    from the page the first one lies on (positions contiguous along S, as
-    every engine caller's are): a prefill piece far into a long prompt
-    reads what its window holds, not the prompt."""
-    B, H, S, D = q.shape
+def paged_kernel_read(cfg: TransformerConfig, rows: int, span: int) -> bool:
+    """Whether the paged branch reads K and V through the Pallas paged
+    kernel (`ops/paged_attention.py`): decided by what the code can
+    observe. The kernel where one can run (:func:`_kernels_usable`) and
+    the call is a decode or verify shape: a short span of several rows,
+    or a shorter one of one row. The engine asks the same question for
+    its counter (`stats["decode_chunks_kernel_read"]`)."""
+    limit = PAGED_KERNEL_MAX_SPAN if rows > 1 else PAGED_KERNEL_MAX_SPAN_ONE_ROW
+    return span <= limit and _kernels_usable(cfg)
+
+
+def paged_flash_read(cfg: TransformerConfig, span: int) -> bool:
+    """Whether the paged branch gathers the rows' windows and attends
+    through the flash forward kernel (:func:`paged_flash_attention`): a
+    prefill piece's shape — a span of whole q blocks, too long for the
+    paged kernel — where a kernel can run. Everything else gathers and
+    attends in XLA (:func:`paged_gather_attention`). The engine asks the
+    same question for `stats["prefill_pieces_flash_read"]`."""
+    return span % PAGED_FLASH_MIN_SPAN == 0 and _kernels_usable(cfg)
+
+
+def _gathered_window(cache, page_table, positions, *, page_size, window,
+                     whole_blocks=False):
+    """Each row's keys and values out of the token-major pool ``cache``
+    (a layer's ``k`` / ``v``, with ``k_scale`` / ``v_scale`` when int8,
+    dequantized here): ``(K, V, first)`` with K and V ``(B, Hkv, W, D)``
+    and ``first`` the position of each row's key 0 (None: position 0).
+    W is the table's width x page size — a row's first W logical tokens —
+    unless the layer has a window whose reach over the span is narrower:
+    then only the pages that hold the ``window + S - 1`` keys ending at
+    the span's last query are gathered, from the page the first one lies
+    on (positions (B, S) contiguous along S, as every engine caller's
+    are), so a prefill piece far into a long prompt reads what its window
+    holds, not the prompt. ``whole_blocks``: the gathered pages are made
+    up to a width of whole kv blocks (`flash_tuning.span_kv_block`) with
+    the scratch page (0); the keys added lie after the row's last query."""
+    B, S = positions.shape
     P = page_size
-    Hkv = cache["k"].shape[1]
+    Hkv, D = cache["k"].shape[1:]
     pages = page_table.shape[1]
     reach = None if window is None else -(-(window + S - 1) // P) + 1
     first = None          # the first page gathered, where not the table's
+    n = pages
     if reach is not None and reach < pages:
+        n = reach
         first = jnp.minimum(
             jnp.maximum(positions[:, 0] - window + 1, 0) // P, pages - reach
         )                                                          # (B,)
         page_table = jnp.take_along_axis(
             page_table, first[:, None] + jnp.arange(reach)[None, :], axis=1
         )
+    if whole_blocks:
+        kv_block = span_kv_block(n * P)
+        step = kv_block // math.gcd(P, kv_block)    # pages a whole block
+        page_table = jnp.pad(page_table, ((0, 0), (0, -n % step)))
     W = page_table.shape[1] * P
     j = jnp.arange(W)
     flat_r = (
@@ -390,13 +433,53 @@ def paged_gather_attention(q, cache, page_table, positions, *, page_size,
         Vsg = cache["v_scale"][:, flat_r].reshape(Hkv, B, W).transpose(1, 0, 2)
         Kg = dequantize_kv(Kg, Ksg)
         Vg = dequantize_kv(Vg, Vsg)
-    kpos = j[None, None, :]                  # the keys' absolute positions
+    return Kg, Vg, None if first is None else first * P
+
+
+def paged_gather_attention(q, cache, page_table, positions, *, page_size,
+                           window):
+    """The paged branch's XLA read: gather each row's window
+    (:func:`_gathered_window`), mask by position and run grouped
+    attention over all of it, the scores a float32 array ``(B, Hkv,
+    groups, S, W)``. q (B, H, S, D), positions (B, S). What a CPU without
+    the interpreter, a mesh, and spans the kernels do not take (17 to
+    127, or off the flash kernel's granule) read through."""
+    Kg, Vg, first = _gathered_window(
+        cache, page_table, positions, page_size=page_size, window=window
+    )
+    kpos = jnp.arange(Kg.shape[2])[None, None, :]   # the keys' positions
     if first is not None:
-        kpos = kpos + (first * P)[:, None, None]
+        kpos = kpos + first[:, None, None]
     mask = kpos <= positions[:, :, None]                       # (B,S,W)
     if window is not None:
         mask &= kpos > positions[:, :, None] - window
-    return _grouped_cache_attention(q, Kg, Vg, mask, H // Hkv)
+    return _grouped_cache_attention(q, Kg, Vg, mask, q.shape[1] // Kg.shape[1])
+
+
+def paged_flash_attention(q, cache, page_table, positions, *, page_size,
+                          window, interpret=False):
+    """A prefill piece's read: gather each row's window as
+    :func:`paged_gather_attention` does — a few MB — and attend through
+    the flash forward kernel, block by block with the running max and
+    denominator in VMEM: no score array is written. The queries' offset
+    among the gathered keys (the span's first position less the first
+    gathered key's) rides into the kernel as a scalar; the causal and
+    window masks are the same arithmetic on positions. The gathered
+    width is made up to whole kv blocks (`flash_tuning.span_kv_block`)
+    so that the geometry rule tiles every table width alike; the kernel
+    skips the blocks added."""
+    Kg, Vg, first = _gathered_window(
+        cache, page_table, positions, page_size=page_size, window=window,
+        whole_blocks=True,
+    )
+    q_offset = positions[:, 0] if first is None else positions[:, 0] - first
+    # an int8 pool comes back dequantized to float32: the kernel's
+    # operands share one type
+    ctype = jnp.promote_types(q.dtype, Kg.dtype)
+    return flash_attention_span(
+        q.astype(ctype), Kg.astype(ctype), Vg.astype(ctype), q_offset,
+        window=window, interpret=interpret,
+    ).astype(q.dtype)
 
 
 class Attention(nn.Module):
@@ -535,6 +618,14 @@ class Attention(nn.Module):
                     k_scale=new_cache.get("k_scale"),
                     v_scale=new_cache.get("v_scale"),
                     interpret=cfg.interpret_kernels,
+                )
+            elif paged_flash_read(cfg, S):
+                # a prefill piece: the rows' windows gathered, then the
+                # flash forward kernel over them (same assumption on the
+                # positions)
+                o = paged_flash_attention(
+                    q, new_cache, page_table, positions, page_size=P,
+                    window=window, interpret=cfg.interpret_kernels,
                 )
             else:
                 o = paged_gather_attention(

@@ -33,6 +33,13 @@ attention (SURVEY.md §5.7). Design per the TPU kernel playbook
 Returns optionally the (max, logsumexp) residuals, which is what lets
 ``kubeflow_tpu.parallel.ring_attention`` merge partial results across ring
 steps.
+
+``flash_attention_span`` is the forward kernel for a serving engine's
+prefill piece: a span of queries that starts anywhere among its keys (the
+row's window, gathered out of the paged pool). The span's offset is a
+traced value, so it rides as a scalar-prefetch operand beside the live kv
+blocks it implies, and grouped query heads read their kv head in place.
+The training entry, query i at position i, carries no such operand.
 """
 
 from __future__ import annotations
@@ -53,6 +60,7 @@ from kubeflow_tpu.ops.flash_tuning import (
     geometry_from_blocks,
     resolve_blocks,
     select_geometry,
+    select_span_tile,
 )
 
 NEG_INF = -1e30  # large-but-finite: keeps exp() well-defined on fully-masked rows
@@ -146,6 +154,7 @@ def _fwd_kernel(
     window: int | None,
     tile: Tile,
     num_k_blocks: int,
+    q_base=None,
 ):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
@@ -157,7 +166,11 @@ def _fwd_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # the block's first query in the keys' coordinates: row i is position
+    # i unless the caller's queries start further in (``q_base``)
     q0 = iq * block_q
+    if q_base is not None:
+        q0 = q0 + q_base
     qseg = None if qseg_ref is None else _col(qseg_ref[0])
 
     for j in range(block_k // sub):
@@ -250,7 +263,9 @@ def _with_segment_refs(impl, has_seg: bool, n_inputs: int):
     gets None for them."""
     if has_seg:
         return impl
-    return lambda *refs: impl(*refs[:n_inputs], None, None, *refs[n_inputs:])
+    return lambda *refs, **kw: impl(
+        *refs[:n_inputs], None, None, *refs[n_inputs:], **kw
+    )
 
 
 def _check_tile(tile: Tile, sq, skv, heads, *, looped: str) -> Tile:
@@ -285,6 +300,28 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
 )
 
 
+def _live_kv_blocks(q_offset, tile: Tile, nq: int, nk: int, window):
+    """The kv blocks ``lo <= block <= hi`` (each ``(B, nq)`` int32) that
+    hold a key some query of a q block may see, the queries of row ``b``
+    starting at ``q_offset[b]`` in the keys' coordinates: not wholly after
+    the block's last query, not wholly before its first one's window.
+    Worked out once in front of the call; the kv operands' index maps
+    read the two numbers."""
+    first = q_offset[:, None] + jnp.arange(nq, dtype=jnp.int32) * tile.block_q
+    hi = jnp.clip((first + tile.block_q - 1) // tile.block_k, 0, nk - 1)
+    if window is None:
+        return jnp.zeros_like(hi), hi
+    return jnp.minimum(jnp.maximum(first - window + 1, 0) // tile.block_k, hi), hi
+
+
+def _span_kernel(off_ref, lo_ref, hi_ref, *refs, kernel):
+    """The forward kernel under a grid with scalar prefetch: the row's
+    offset becomes the kernel's ``q_base``; the live range is the index
+    maps' business."""
+    del lo_ref, hi_ref
+    kernel(*refs, q_base=off_ref[pl.program_id(0)])
+
+
 # The kernel entry points are jitted on their static arguments so that a
 # model's twelfth layer reuses the first one's trace and lowering: tracing a
 # kernel body and serialising it for Mosaic costs about a tenth of a second
@@ -294,16 +331,25 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
     static_argnames=("causal", "scale", "tile", "interpret", "window"),
 )
 def _flash_forward(
-    q, k, v, q_segment_ids, kv_segment_ids,
+    q, k, v, q_segment_ids, kv_segment_ids, q_offset=None,
     *, causal, scale, tile: Tile, interpret, window=None,
 ):
+    """``q_offset`` (B,) int32, or None: query i of row b sits at key
+    position ``q_offset[b] + i`` — a traced value, so it rides as a
+    scalar-prefetch operand beside the live kv range it implies. None is
+    the training case, query i at position i, with no such operand.
+    ``k`` / ``v`` may hold fewer heads than ``q`` (grouped queries, one
+    head a step): q head h reads kv head ``h // groups``."""
     batch, heads, sq, d = q.shape
-    _, _, skv, _ = k.shape
+    _, kv_heads, skv, _ = k.shape
     tile = _check_tile(tile, sq, skv, heads, looped="k")
     block_q, block_k, _, hb = tile
     nq, nk = sq // block_q, skv // block_k
     if window is not None and window >= skv:
         window = None  # the band is as wide as the rows: it never bites
+    groups = heads // kv_heads
+    if groups > 1 and hb > 1:
+        raise ValueError(f"grouped heads take one head a step, not {hb}")
 
     impl = functools.partial(
         _fwd_kernel,
@@ -315,12 +361,22 @@ def _flash_forward(
         num_k_blocks=nk,
     )
     has_seg = q_segment_ids is not None
-    kv_block = _kv_block_map(tile, causal=causal, window=window)
+    if q_offset is None:
+        clamp = _kv_block_map(tile, causal=causal, window=window)
+        kv_block = lambda b, iq, ik, *_: clamp(iq, ik)
+    else:
+        q_offset = q_offset.astype(jnp.int32)
+        live = _live_kv_blocks(q_offset, tile, nq, nk, window)
+        kv_block = lambda b, iq, ik, off, lo, hi: jnp.clip(
+            ik, lo[b, iq], hi[b, iq]
+        )
+    kv_head = (lambda h: h) if groups == 1 else (lambda h: h // groups)
     q_spec = pl.BlockSpec(
-        (1, hb, block_q, d), lambda b, h, iq, ik: (b, h, iq, 0)
+        (1, hb, block_q, d), lambda b, h, iq, ik, *_: (b, h, iq, 0)
     )
     kv_spec = pl.BlockSpec(
-        (1, hb, block_k, d), lambda b, h, iq, ik: (b, h, kv_block(iq, ik), 0)
+        (1, hb, block_k, d),
+        lambda b, h, iq, ik, *s: (b, kv_head(h), kv_block(b, iq, ik, *s), 0),
     )
     in_specs = [q_spec, kv_spec, kv_spec]
     inputs = [q, k, v]
@@ -328,37 +384,47 @@ def _flash_forward(
         # (B, S) → (B, 1, S): TPU block shapes need the trailing two dims
         # to tile cleanly (1 matches the singleton dim; block divides S).
         in_specs.append(
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, 0, iq))
+            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik, *_: (b, 0, iq))
         )
         in_specs.append(
             pl.BlockSpec(
                 (1, 1, block_k),
-                lambda b, h, iq, ik: (b, 0, kv_block(iq, ik)),
+                lambda b, h, iq, ik, *s: (b, 0, kv_block(b, iq, ik, *s)),
             )
         )
         inputs.extend(
             [q_segment_ids[:, None, :], kv_segment_ids[:, None, :]]
         )
 
-    out, lse4 = pl.pallas_call(
-        _with_segment_refs(impl, has_seg, 3),
+    kernel = _with_segment_refs(impl, has_seg, 3)
+    grid = dict(
         grid=(batch, heads // hb, nq, nk),
         in_specs=in_specs,
         out_specs=[
             q_spec,
             # per-row statistics leave as lane-dense rows: (B, H, 1, S)
             pl.BlockSpec(
-                (1, hb, 1, block_q), lambda b, h, iq, ik: (b, h, 0, iq)
+                (1, hb, 1, block_q), lambda b, h, iq, ik, *_: (b, h, 0, iq)
             ),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((batch, heads, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((hb, block_q, d), jnp.float32),   # acc
             pltpu.VMEM((hb, block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((hb, block_q, 1), jnp.float32),   # running denom
+        ],
+    )
+    if q_offset is not None:
+        kernel = functools.partial(_span_kernel, kernel=kernel)
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, **grid
+        ))
+        inputs = [q_offset, *live, *inputs]
+    out, lse4 = pl.pallas_call(
+        kernel,
+        **grid,
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((batch, heads, 1, sq), jnp.float32),
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
@@ -783,6 +849,52 @@ def flash_attention(
         q, k, v, q_segment_ids, kv_segment_ids,
         causal, scale, geometry, (interpret, window),
     )
+
+
+def flash_attention_span(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    q_offset: jax.Array,
+    *,
+    window: int | None = None,
+    scale: float | None = None,
+    interpret: bool = False,
+    tile: Tile | None = None,
+) -> jax.Array:
+    """Causal attention of a span of queries that starts anywhere among
+    its keys — a serving engine's prefill piece against its row's cached
+    window. q (B, H, Sq, D); k / v (B, Hkv, Skv, D) with ``H`` a multiple
+    of ``Hkv`` (grouped heads are read in place, never repeated);
+    ``q_offset`` (B,) int32: query i of row b sits at key position
+    ``q_offset[b] + i``, key j at position j, and every query lies among
+    the keys (``q_offset + Sq <= Skv``). Keys after a query, and
+    those ``window`` or more before it, are masked by position, and kv
+    blocks that hold only such keys are neither fetched nor computed — so
+    a caller may pad ``Skv`` up to whole blocks with anything that lies
+    after the last query. Forward only (no VJP, no segment ids);
+    ``tile`` None = ``flash_tuning.select_span_tile``'s."""
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(
+            f"{q.shape[1]} query heads not a multiple of {k.shape[1]} kv heads"
+        )
+    if window is not None and window < 1:
+        raise ValueError(f"window={window} must be >= 1")
+    if window is not None and window >= k.shape[2]:
+        # as wide as the keys: it never bites, and the call shares the
+        # global layers' trace of the kernel
+        window = None
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    if tile is None:
+        tile = select_span_tile(
+            q.shape[2], k.shape[2], q.shape[3], itemsize=q.dtype.itemsize
+        )
+    return _flash_forward(
+        q, k, v, None, None, q_offset,
+        causal=True, scale=scale, window=window, tile=tile,
+        interpret=interpret,
+    )[0]
 
 
 def reference_attention(

@@ -16,6 +16,11 @@ computes, so a step stages whole rows where they fit and loops over score
 tiles of a quarter to half a million elements, and only then do the MXU
 and the VPU, not the step, set the time.
 
+The serving engine's prefill piece runs the forward kernel alone over
+its row's gathered window (``flash_attention_span``); :func:`span_kv_block`
+says what width the gather makes the window up to, so that the same rule
+tiles every page-table width (PERF.md section 6, PR 35).
+
 ``flash_attention(block_q=None)`` (and TransformerConfig
 ``attn_block_q=None``) routes through the rule; explicit ``block_q`` /
 ``block_k`` are honoured as the staged block of every kernel
@@ -134,6 +139,22 @@ _STEP_ELEMS = 4 * 512 * 512
 _STAGED_ROWS = 4096
 
 
+def span_kv_block(seq_kv: int) -> int:
+    """The kv rows a caller who can pad its keys (`flash_attention_span`'s:
+    the serving engine's gathered window) makes them up to whole blocks
+    of, so that :func:`select_span_tile` tiles every page-table width
+    alike: 8,704 keys (136 pages of 64) would get sub-tiles of 128 (6.78
+    ms a layer against 1.19 at 9,216: PERF.md section 6, PR 35) and
+    3,136 (49 pages) has no block over 64 rows; 9,216 and 4,096 run in
+    sub-tiles of 1,024. A width of one block or less is only brought
+    onto the lane tile: keys added are skipped a sub-tile at a time, and
+    a wider sub-tile would compute them."""
+    return _SPAN_KV_BLOCK if seq_kv > _SPAN_KV_BLOCK else LANES
+
+
+_SPAN_KV_BLOCK = 1024
+
+
 def select_geometry(
     seq_q: int,
     seq_kv: int,
@@ -170,6 +191,23 @@ def select_geometry(
             _heads_per_step(heads, staged_q * block_k),
         ),
     )
+
+
+def select_span_tile(
+    seq_q: int, seq_kv: int, head_dim: int, *, itemsize: int = 2
+) -> Tile:
+    """The forward tile of a span call (`flash_attention_span`: a serving
+    engine's prefill piece over its row's gathered window): the rule's q
+    block and sub-tile, one sub-tile staged a step. The kernel's body
+    holds every sub-tile of a staged block twice (with and without the
+    positional mask), and an engine traces and lowers one such kernel
+    per table width and window at every start, compile cache or not:
+    staging 3,072 or 4,096 keys read 3 to 11 % faster in the kernel
+    (1.095 against 1.188 ms at 9,216 keys, 0.489 against 0.552 over a
+    window layer's 4,096) and cost `trinity-mini_mixed-closed` 6 s of a
+    71 s start (PERF.md section 6, PR 35)."""
+    fwd = select_geometry(seq_q, seq_kv, head_dim, itemsize=itemsize).fwd
+    return Tile(fwd.block_q, fwd.sub, fwd.sub, 1)
 
 
 def geometry_from_blocks(block_q: int, block_k: int) -> Geometry:
@@ -245,6 +283,9 @@ def sweep_blocks(
     causal: bool = False,
     window: int | None = None,
     segments: bool = False,
+    seq_kv: int | None = None,
+    kv_heads: int | None = None,
+    q_offset: int | None = None,
     candidates: tuple[Geometry | None, ...] = (None,),
     steps: int = 5,
     logdir: str,
@@ -255,8 +296,13 @@ def sweep_blocks(
     device milliseconds of its forward, dq and dkv kernels, read from a
     profile (host timers cannot split the three, and include dispatch).
     ``segments`` passes all-ones segment ids, as BERT's unpadded batches
-    do. Run this on the chip — CPU-interpret timings are meaningless. A
-    candidate the compiler refuses is reported with its error."""
+    do. With ``q_offset`` it times what the serving engine's prefill
+    piece runs instead — the forward alone (``flash_attention_span``) of
+    ``seq`` queries that start at ``q_offset`` among ``seq_kv`` keys of
+    ``kv_heads`` heads, the keys made up to whole blocks of the
+    candidate's (the rule's: :func:`span_kv_block`). Run this on the
+    chip — CPU-interpret timings are meaningless. A candidate the
+    compiler refuses is reported with its error."""
     import glob
     import importlib
 
@@ -265,16 +311,29 @@ def sweep_blocks(
 
     # the package re-exports the function under the module's name
     fa = importlib.import_module("kubeflow_tpu.ops.flash_attention")
-    shape = (batch, heads, seq, head_dim)
-    q, k, v, w = (
-        jax.random.normal(key, shape, jnp.bfloat16)
-        for key in jax.random.split(jax.random.PRNGKey(0), 4)
+    seq_kv, kv_heads = seq_kv or seq, kv_heads or heads
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, w = (
+        jax.random.normal(key, (batch, heads, seq, head_dim), jnp.bfloat16)
+        for key in keys[:2]
     )
     seg = jnp.ones((batch, seq), jnp.int32) if segments else None
     scale = head_dim ** -0.5
     results = []
     for i, cand in enumerate(candidates):
-        geometry = cand or select_geometry(seq, seq, head_dim, heads=heads)
+        block = cand.fwd.block_k if cand else span_kv_block(seq_kv)
+        width = seq_kv if q_offset is None else -(-seq_kv // block) * block
+        geometry = cand or select_geometry(seq, width, head_dim, heads=heads)
+        if q_offset is not None and not cand:
+            geometry = geometry._replace(
+                fwd=select_span_tile(seq, width, head_dim)
+            )
+        k, v = (
+            jax.random.normal(
+                key, (batch, kv_heads, width, head_dim), jnp.bfloat16
+            )
+            for key in keys[2:]
+        )
 
         def loss(q, k, v, geometry=geometry):
             out = fa._flash(
@@ -282,8 +341,17 @@ def sweep_blocks(
             )
             return (out.astype(jnp.float32) * w.astype(jnp.float32)).sum()
 
-        step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
-        row = {"geometry": [list(t) for t in geometry]}
+        def piece(q, k, v, tile=geometry.fwd):
+            return fa.flash_attention_span(
+                q, k, v, jnp.full((batch,), q_offset, jnp.int32),
+                window=window, tile=tile,
+            )
+
+        step = jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1, 2))
+            if q_offset is None else piece
+        )
+        row = {"geometry": [list(t) for t in geometry], "seq_kv": width}
         try:
             jax.block_until_ready(step(q, k, v))  # compile + warm
             run_dir = os.path.join(logdir, f"cand{i}")

@@ -471,8 +471,9 @@ def _vmem_limit(rows: int, d: int, q_bytes: int, staged: int) -> int | None:
     decode step or a verify span is far under the compiler's default
     scoped limit (None leaves it alone); a 512-token prefill piece at 8
     kv heads x 4 x 128 needs ~45 MB of a v5e's 128 MiB, so the limit is
-    asked for by size (no engine path sends a piece here today — it
-    gathers: ROADMAP S1 (c) — but the call is public and
-    `tests/test_tpu_compile.py` keeps the piece compiling)."""
+    asked for by size (no engine path sends a piece here — it gathers
+    its row's window and attends through the flash forward kernel,
+    `models/transformer.py::paged_flash_attention` — but the call is
+    public and `tests/test_tpu_compile.py` keeps the piece compiling)."""
     resident = rows * (4 * d * q_bytes + 4 * d + 2 * 4 * 128) + 4 * staged
     return None if resident < (8 << 20) else 2 * resident

@@ -64,6 +64,7 @@ from kubeflow_tpu.models.transformer import (
     TransformerConfig,
     TransformerLM,
     init_paged_kv_cache,
+    paged_flash_read,
     paged_kernel_read,
 )
 from kubeflow_tpu.obs import names, prom
@@ -177,13 +178,17 @@ class LMEngineConfig:
     (ops/paged_attention.py: only the pages a row holds leave HBM, once,
     online softmax fused) where the backend is a TPU — or
     ``TransformerConfig.interpret_kernels`` asks for the Pallas
-    interpreter — and no mesh is in force; a prefill piece (one row: the
-    kernel only up to 8 tokens, where the two read alike), an engine
-    under a mesh and a CPU without the interpreter gather the rows'
-    windows in-graph (``models/transformer.py::paged_kernel_read``).
-    Greedy token streams are byte-identical between the two;
+    interpreter — and no mesh is in force; there a prefill piece of
+    whole 128-token blocks gathers its row's window and attends through
+    the flash forward kernel (ops/flash_attention.py: no score array is
+    written). An engine under a mesh, a CPU without the interpreter and
+    the spans between (a piece of 16 to 127 tokens, or off the block)
+    gather the rows' windows and attend in-graph
+    (``models/transformer.py::paged_kernel_read``, ``paged_flash_read``).
+    Greedy token streams are byte-identical between the decode paths;
     ``stats["decode_chunks_kernel_read"]`` counts the chunks that took
-    the kernel.
+    the kernel, ``stats["prefill_pieces_flash_read"]`` the pieces that
+    took the flash kernel.
     ``kv_quant``: ``"none"`` (default, byte-exact with the pre-quant
     engine) or ``"int8"`` — per-(kv_head, token) symmetric int8 pool
     with f32 scale side arrays, quantize-on-write / dequantize-on-read;
@@ -612,6 +617,11 @@ class LMEngine:
             # cross-replica prefix-KV transfer (peer pull endpoints)
             "prefix_imported": 0, "prefix_exported": 0,
             "prefill_pieces": 0, "idle_wakes": 0,
+            # of the pieces, those whose attention read the gathered
+            # window through the flash forward kernel (the rest wrote
+            # float32 scores over it): the model's own answer for the
+            # piece's shape, `transformer.paged_flash_read`
+            "prefill_pieces_flash_read": 0,
             # what the prefill programs computed: prompt tokens, and the
             # token slots they were padded to (pieces x piece length)
             "prefill_tokens": 0, "prefill_padded_tokens": 0,
@@ -1971,6 +1981,7 @@ class LMEngine:
             pos = base + i * C + len(piece_ids)
             pages_w = self._pages_w(base + i * C + C)
             with self._mesh_scope():
+                flash_read = paged_flash_read(self.cfg, C)
                 self.cache, tok, valid, qerr, *moe = self._suffix_prefill(
                     self.params,
                     self.cache,
@@ -1994,6 +2005,7 @@ class LMEngine:
             if d > 0:
                 self._ewma("kv_quant_error", e / d)
         self.stats["prefill_pieces"] += 1
+        self.stats["prefill_pieces_flash_read"] += flash_read
         self.stats["prefill_tokens"] += len(piece_ids)
         self.stats["prefill_padded_tokens"] += C
         st["piece"] = i + 1

@@ -140,6 +140,31 @@ def test_prefill_in_pieces_then_decode_matches_the_full_forward(params, tokens, 
         np.testing.assert_allclose(logits, want, atol=1e-4 * want.std())
 
 
+@pytest.mark.parametrize("window", [8, 100], ids=["window8", "window100"])
+def test_flash_read_pieces_match_the_full_forward(params, window):
+    """128-token pieces — whole q blocks, so under the interpreter each
+    one gathers its row's window and attends through the flash forward
+    kernel (`paged_flash_read`) — of prompts of 230 and 150 tokens: a
+    second piece at offset 128, last pieces with 26 and 106 pad
+    positions, window layers (8 keys; 100, which crosses the pieces'
+    boundary) beside the global one, then six decode steps through the
+    paged kernel; against the reference's one pass over each sequence."""
+    from kubeflow_tpu.models.transformer import paged_flash_read
+
+    cfg = dict(CFG, sliding_window=window)
+    pc = afmoe.program_config(cfg, attn_impl="reference", interpret_kernels=True)
+    assert paged_flash_read(pc, 128)
+    rng = np.random.default_rng(3)
+    seqs = [rng.integers(2, CFG["vocab_size"], size=n).astype(np.int32)
+            for n in (236, 156)]
+    got = paged_logits(pc, params, seqs, piece=128, page=16)
+    for seq, logits in zip(seqs, got):
+        want = np.asarray(
+            afmoe.reference_logits(params, seq, np.arange(len(seq)), cfg)
+        )
+        np.testing.assert_allclose(logits, want, atol=1e-4 * want.std())
+
+
 def serve(pc, params, prompts, new=14):
     """The engine itself: prompts of unequal length admitted at different
     times, prefilled in pieces, decoded through the paged pool."""

@@ -576,7 +576,7 @@ def test_reload_cycle_and_engine_metrics(model_and_params):
         assert 'kubeflow_tpu_engine_active_rows{model="lm"}' in text
         # the decode read path's counters ride the same export
         for key in ("decode_chunks_kernel_read", "decode_pages_live",
-                    "decode_pages_window"):
+                    "decode_pages_window", "prefill_pieces_flash_read"):
             assert f'kubeflow_tpu_engine_{key}{{model="lm"}}' in text
     finally:
         m.unload()
@@ -1295,6 +1295,39 @@ def test_decode_read_path_counters(model_and_params, interpret):
         assert eng.stats["decode_pages_window"] == 32 + 4 * 4
         assert eng.stats["decode_chunks_kernel_read"] == (
             4 if interpret else 0
+        )
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["gather", "flash"])
+def test_prefill_flash_read_counter(model_and_params, interpret):
+    """`stats["prefill_pieces_flash_read"]` beside `stats["prefill_pieces"]`:
+    every piece of a chunked prompt under the interpreter (a 128-token
+    piece is a whole q block: its gathered window goes through the flash
+    forward kernel), none on a plain CPU — and either engine serves the
+    whole-batch path's tokens."""
+    import dataclasses
+
+    _, params = model_and_params
+    cfg = dataclasses.replace(
+        CFG, interpret_kernels=interpret, max_seq_len=512
+    )
+    oracle = GenerateOracle(
+        TransformerLM(cfg), cfg, params, eos_id=CFG.vocab_size + 1
+    )
+    eng = LMEngine(
+        TransformerLM(cfg), cfg, params, max_batch=2, max_seq=448,
+        chunk_steps=4, prefill_buckets=(128,), prefill_chunk=128,
+        eos_id=CFG.vocab_size + 1, page_size=16, pipeline_depth=0,
+    ).start()
+    try:
+        rng = np.random.default_rng(5)
+        prompt = [int(t) for t in rng.integers(2, CFG.vocab_size, size=300)]
+        assert eng.submit(prompt, max_new_tokens=6) == oracle.submit(prompt, 6)
+        assert eng.stats["prefill_pieces"] == 3
+        assert eng.stats["prefill_pieces_flash_read"] == (
+            3 if interpret else 0
         )
     finally:
         eng.stop()

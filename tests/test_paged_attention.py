@@ -38,14 +38,24 @@ import pytest
 import dataclasses
 
 from kubeflow_tpu.models.transformer import (
+    PAGED_FLASH_MIN_SPAN,
     PAGED_KERNEL_MAX_SPAN,
     PAGED_KERNEL_MAX_SPAN_ONE_ROW,
     TransformerConfig,
     TransformerLM,
+    paged_flash_attention,
+    paged_flash_read,
     paged_gather_attention,
     paged_kernel_read,
 )
-from kubeflow_tpu.ops.flash_tuning import PagedTile, select_paged_geometry
+from kubeflow_tpu.ops.flash_tuning import (
+    PagedTile,
+    Tile,
+    select_geometry,
+    select_paged_geometry,
+    select_span_tile,
+    span_kv_block,
+)
 from kubeflow_tpu.ops.paged_attention import (
     dequantize_kv,
     paged_attention,
@@ -360,6 +370,135 @@ def test_read_path_is_chosen_by_span_interpreter_and_mesh():
     )
     with jax.set_mesh(mesh):
         assert not paged_kernel_read(CFG, rows, 1)
+
+
+@pytest.mark.parametrize("rows", [1, 32], ids="rows{}".format)
+@pytest.mark.parametrize("span", [1, 8, 16, 48, 512, 1024, 1000])
+def test_read_path_follows_span_rows_backend_and_mesh(span, rows):
+    """The paged branch's three read paths, by what the call can observe:
+    the paged kernel for a decode or verify shape (up to 16 queries of
+    several rows, up to 8 of one), the gathered window through the flash
+    forward kernel for a span of whole 128-row q blocks (a prefill
+    piece: 512, 1,024), the XLA gather for what lies between (17 to 127)
+    or off the block (1,000) — and for everything on a plain CPU or with
+    a mesh in force."""
+    kernel = span <= (PAGED_KERNEL_MAX_SPAN if rows > 1
+                      else PAGED_KERNEL_MAX_SPAN_ONE_ROW)
+    flash = span >= PAGED_FLASH_MIN_SPAN and span % PAGED_FLASH_MIN_SPAN == 0
+    assert PAGED_FLASH_MIN_SPAN == 128 and not (kernel and flash)
+    assert paged_kernel_read(CFG, rows, span) == kernel
+    assert paged_flash_read(CFG, span) == flash
+    assert flash == (span in (512, 1024))
+    # a plain CPU: no kernel of either kind
+    assert not paged_kernel_read(CFG_GATHER, rows, span)
+    assert not paged_flash_read(CFG_GATHER, span)
+    mesh = jax.sharding.Mesh(
+        np.asarray(jax.devices()[:2]).reshape(1, 2), ("data", "model")
+    )
+    with jax.set_mesh(mesh):
+        assert not paged_kernel_read(CFG, rows, span)
+        assert not paged_flash_read(CFG, span)
+
+
+#: a prefill piece's place in its row, as the engine sends them: (span,
+#: its first position, the table's pages, the pages the row holds — None
+#: = all, the queries counted — None = all)
+PIECES = {
+    # a prompt's first piece: the table is the piece's own two pages
+    "offset0": (128, 0, 2, None, None),
+    # far into a long prompt, two q blocks: a window layer gathers the
+    # 35 pages its windows reach (made up to 48), a global one all 48
+    "deep": (256, 2816, 48, None, None),
+    # after a prefix hit whose base (16 x 129) is no multiple of the piece
+    "prefix-base": (128, 2064, 48, None, None),
+    # a table wider than the row holds: the pages past the piece are the
+    # scratch page, as are the pages the gathered width is made up with
+    # (40 pages of 64 -> 3,072 keys)
+    "wide-table": (128, 128, 40, 4, None),
+    # the widest table is no power of two (the engine caps it at the
+    # row's pages): 52 pages, made up to 64
+    "capped-table": (128, 3200, 52, None, None),
+    # a prompt's last piece: 37 tokens and 91 pad positions, whose writes
+    # went to the scratch page and whose outputs nobody reads
+    "last-piece-pads": (128, 2048, 34, 33, 37),
+}
+
+
+@pytest.mark.parametrize("groups,kv_heads", [(8, 1), (4, 2)],
+                         ids=["groups8", "groups4"])
+@pytest.mark.parametrize("window", [2048, None], ids=["window2048", "global"])
+@pytest.mark.parametrize("piece", sorted(PIECES))
+def test_flash_read_matches_gather_on_a_prefill_piece(piece, window, groups,
+                                                      kv_heads):
+    """`paged_flash_attention` — the row's window gathered out of the
+    pool, then the flash forward kernel (interpreter) with the queries'
+    offset as a scalar and grouped heads read in place — against
+    `paged_gather_attention`, which masks by position over a float32
+    score array: the same keys, the same masks, another order of
+    summation."""
+    span, offset, pages, held, valid = PIECES[piece]
+    P, D = 64, 32
+    rng = np.random.default_rng(7)
+    q = jnp.asarray(rng.normal(size=(1, kv_heads * groups, span, D)), jnp.float32)
+    pool = lambda: jnp.asarray(
+        rng.normal(size=((1 + pages) * P, kv_heads, D)), jnp.float32
+    )
+    cache = {"k": pool(), "v": pool()}
+    table = 1 + rng.permutation(pages).astype(np.int32)[None, :]
+    table[:, held:] = 0
+    positions = offset + jnp.arange(span)[None, :]
+    kw = dict(page_size=P, window=window)
+    got = paged_flash_attention(
+        q, cache, jnp.asarray(table), positions, interpret=True, **kw
+    )
+    want = paged_gather_attention(q, cache, jnp.asarray(table), positions, **kw)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(
+        np.asarray(got)[:, :, :valid], np.asarray(want)[:, :, :valid],
+        atol=2e-6, rtol=2e-6,
+    )
+
+
+def test_flash_read_two_rows_int8_pool_and_the_widths_it_pads():
+    """Several rows, each with an offset of its own, over an int8 pool
+    (dequantized after the gather, as the gather path does); and the
+    widths the engine's tables give, made up to whole kv blocks so that
+    the kernel runs in 1,024-key sub-tiles at every one of them."""
+    P, D, Hkv, G, span = 64, 32, 2, 4, 128
+    rng = np.random.default_rng(3)
+    pages = 40
+    q = jnp.asarray(rng.normal(size=(2, Hkv * G, span, D)), jnp.float32)
+    kq, ks = quantize_kv(jnp.asarray(
+        rng.normal(size=((1 + 2 * pages) * P, Hkv, D)), jnp.float32))
+    vq, vs = quantize_kv(jnp.asarray(
+        rng.normal(size=((1 + 2 * pages) * P, Hkv, D)), jnp.float32))
+    cache = {"k": kq, "v": vq, "k_scale": ks.T, "v_scale": vs.T}
+    table = jnp.asarray(
+        1 + rng.permutation(2 * pages).astype(np.int32).reshape(2, pages)
+    )
+    positions = jnp.asarray([2064, 0])[:, None] + jnp.arange(span)[None, :]
+    kw = dict(page_size=P, window=2048)
+    got = paged_flash_attention(q, cache, table, positions, interpret=True, **kw)
+    want = paged_gather_attention(q, cache, table, positions, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=2e-6)
+    # table widths of both serving cells (pages of 64) and a window
+    # layer's reach: the width handed to the kernel, its tile — the rule's
+    # q block and sub-tile, one sub-tile staged a step
+    for keys, padded, block_k in [
+        (512, 512, 512), (1024, 1024, 1024), (2048, 2048, 1024),
+        (4096, 4096, 1024), (8192, 8192, 1024), (8704, 9216, 1024),
+        (3136, 4096, 1024), (8320, 9216, 1024),
+    ]:
+        block = span_kv_block(keys)
+        assert -(-keys // block) * block == padded
+        assert select_span_tile(1024, padded, 128) == Tile(
+            512, block_k, block_k, 1
+        )
+    # the raw widths: sub-tiles of 128 under 2,176 staged rows; a block
+    # that is the whole 3,136
+    assert select_geometry(1024, 8704, 128).fwd == Tile(512, 2176, 128, 1)
+    assert select_geometry(1024, 3136, 128).fwd == Tile(512, 3136, 3136, 1)
 
 
 def test_act_constraint_gives_no_hint_on_a_mesh_without_its_axes():

@@ -186,6 +186,43 @@ for _w in (2048, None):
     )
 
 
+def _piece_case(*, span, table_pages, kv_heads, groups, window, page=64,
+                head_dim=128):
+    """A prefill piece's read as the model runs it on the chip: the row's
+    window gathered out of the token-major pool and made up to whole kv
+    blocks, then the flash forward kernel with the queries' offset as a
+    scalar-prefetch operand and grouped heads read in place."""
+    from kubeflow_tpu.models.transformer import paged_flash_attention
+
+    def fn(q, kp, vp, table, positions):
+        return paged_flash_attention(
+            q, {"k": kp, "v": vp}, table, positions, page_size=page,
+            window=window,
+        )
+
+    pool = (((1 + table_pages) * page, kv_heads, head_dim), jnp.bfloat16)
+    return fn, [
+        ((1, kv_heads * groups, span, head_dim), jnp.bfloat16), pool, pool,
+        ((1, table_pages), jnp.int32), ((1, span), jnp.int32),
+    ]
+
+
+# both serving cells' pieces: `trinity-mini_mixed-closed` (4 kv heads x 8,
+# 1,024 tokens) at a first piece's table, a middle one and the capped one
+# (136 pages, made up to 9,216 keys; a window layer's 49 pages to 4,096),
+# `mistral-7b_gen-closed` (8 x 4, 512 tokens against its own 512 keys)
+for _t in (16, 64, 136):
+    for _w in (None, 2048):
+        CASES[
+            f"flash-piece-trinity-t{_t}-{f'window{_w}' if _w else 'global'}"
+        ] = _piece_case(
+            span=1024, table_pages=_t, kv_heads=4, groups=8, window=_w
+        )
+CASES["flash-piece-mistral-t8-window4096"] = _piece_case(
+    span=512, table_pages=8, kv_heads=8, groups=4, window=4096
+)
+
+
 def _gmm_case(m, k, n, experts=128):
     """The grouped product of a dropless expert layer, the kernel itself
     (`grouped_matmul` asks the backend, which is this CPU), at the tiling
@@ -235,6 +272,14 @@ def test_kernel_compiles_for_v5e(v5e, name):
             head_dim=128,
         )
         assert paged_kernel_name(64, tile, 4) in text
+    if name.startswith("flash-piece"):
+        # 1,024 keys a grid step at every width over 1,024; Mistral's
+        # piece against its own 512 keys in one
+        assert (
+            "flash_fwd_q512_k512_t512_h1" if "mistral" in name
+            else "flash_fwd_q512_k1024_t1024_h1"
+        ) in text
+        assert _score_arrays(text, shapes[0][0][2]) == []
     if name.startswith("gmm-"):
         (m, k), (_, _, n) = shapes[0][0], shapes[1][0]
         assert gmm_kernel_name(m, k, n, select_gmm_tiling(m, k, n)) in text
@@ -319,10 +364,10 @@ def test_paged_pool_passes_through_the_program_without_a_copy(
     a_cache = like(engine.cache)
     if program in ("chunk", "prefill"):
         compiled = _compile_program(engine, params, program, v5e, 4)
-        # the chunk reads through the kernel, the piece gathers
-        assert ("tpu_custom_call" in compiled.as_text()) == (
-            program == "chunk"
-        )
+        # the chunk reads through the paged kernel; the piece gathers its
+        # row's window and attends through the flash forward kernel
+        kernel = "paged_decode_p" if program == "chunk" else "flash_fwd_q"
+        assert kernel in compiled.as_text()
     else:
         # the engine builds these two per prefix length, on first use:
         # use them once on the CPU, then compile what it built
@@ -385,6 +430,19 @@ def _window_arrays(text, rows, tokens, kv_heads=8, head_dim=128):
     return sorted({m for m in re.findall(r"\w+(\[[\d,]+\])", text) if m in shapes})
 
 
+def _score_arrays(text, span):
+    """float32 arrays of the compiled text with an attention score's
+    shape: ``span`` queries by 512 keys or more, under one or more
+    leading axes that hold the heads."""
+    found = set()
+    for dims in re.findall(r"f32\[([\d,]+)\]", text):
+        shape = [int(d) for d in dims.split(",")]
+        if (len(shape) >= 3 and shape[-2] == span and shape[-1] >= 512
+                and np.prod(shape[:-2]) > 1):
+            found.add(f"f32[{dims}]")
+    return sorted(found)
+
+
 @pytest.mark.parametrize(
     "program,backend,windows",
     [
@@ -394,9 +452,11 @@ def _window_arrays(text, rows, tokens, kv_heads=8, head_dim=128):
         # on the chip the chunk reads through the block table in the
         # kernel: no window of the rows exists
         ("chunk", "tpu", []),
-        # the prefill piece keeps the gather path: its text on the chip is
-        # the text the parent's read path gives, line for line
-        ("prefill", "tpu", None),
+        # the prefill piece on a plain CPU attends in XLA over float32
+        # scores (8 kv heads x 4 x 128 queries x 1,024 keys) ...
+        ("prefill", "cpu", ["f32[8,4,128,1024]"]),
+        # ... and on the chip through the flash forward kernel: none
+        ("prefill", "tpu", []),
     ],
 )
 def test_decode_chunk_holds_no_gathered_window(
@@ -405,7 +465,8 @@ def test_decode_chunk_holds_no_gathered_window(
     """`mistral-7b_gen-closed`'s geometry — 8 kv heads of 128, 64-token
     pages, 32 rows, table width 16 — lowered for the described v5e: the
     decode chunk holds no array of the window's shape (32, 1024, 8, 128)
-    or its transpose; the prefill piece is what it was."""
+    or its transpose; the 128-token prefill piece holds no float32 score
+    array over its 1,024 keys."""
     engine, params = _paged_engine("none", max_batch=32, n_heads=32)
 
     def text_for(backend):
@@ -415,8 +476,9 @@ def test_decode_chunk_holds_no_gathered_window(
     text = text_for(backend)
     kernel = "paged_decode_p64_n16_h8_f4"
     if program == "prefill":
-        assert kernel not in text and "tpu_custom_call" not in text
-        assert text == text_for("cpu")
+        assert _score_arrays(text, 128) == windows
+        assert ("flash_fwd_q128_k1024_t1024_h1" in text) == (backend == "tpu")
+        assert kernel not in text
         return
     assert _window_arrays(text, 32, 1024) == sorted(windows)
     assert (kernel in text) == (backend == "tpu")
@@ -426,18 +488,20 @@ def test_decode_chunk_holds_no_gathered_window(
 # a model whose layers differ: `trinity-mini_mixed-closed`'s own programs
 # --------------------------------------------------------------------- #
 
-@pytest.fixture(scope="module")
-def trinity():
-    """The cell's engine at its real widths, built on no weights at all:
-    the programs are lowered from shapes, so the 8.5 GB are never made."""
-    from benchmark.families import afmoe
+def _cell_engine(config: str):
+    """A serving cell's engine at its real widths, built on no weights at
+    all: the programs are lowered from shapes, so the GBs are never made.
+    ``(engine, params, pool, cfg)`` of `benchmark/configs/<config>.json`."""
+    import importlib
+
     from benchmark.manifest import ROOT
     from kubeflow_tpu.models.transformer import init_paged_kv_cache
     from kubeflow_tpu.serve.engine import LMEngine, LMEngineConfig
 
-    cfg = json.loads((ROOT / "benchmark/configs/trinity-mini-l5.json").read_text())
+    cfg = json.loads((ROOT / f"benchmark/configs/{config}.json").read_text())
+    family = importlib.import_module(f"benchmark.families.{cfg['family']}")
     serve = cfg["serve"]
-    model, pc = afmoe.serve_model(cfg)
+    model, pc = family.serve_model(cfg)
     engine = LMEngine(
         model, pc, {},
         config=LMEngineConfig(
@@ -450,12 +514,24 @@ def trinity():
     )
     params = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
-        afmoe.abstract_params(model),
+        family.abstract_params(model),
     )
     pool = jax.eval_shape(
         lambda: init_paged_kv_cache(pc, serve["kv_pool_tokens"])
     )
     return engine, params, pool, cfg
+
+
+@pytest.fixture(scope="module")
+def trinity():
+    """`trinity-mini_mixed-closed`'s engine."""
+    return _cell_engine("trinity-mini-l5")
+
+
+@pytest.fixture(scope="module")
+def mistral():
+    """`mistral-7b_gen-closed`'s engine."""
+    return _cell_engine("mistral-7b-l16")
 
 
 @pytest.mark.parametrize("program", ["chunk", "prefill"])
@@ -490,3 +566,37 @@ def test_trinity_programs_hold_no_array_they_should_not(
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 10e9 < held < 15.75e9
+
+
+@pytest.mark.parametrize(
+    "cell,table_pages,kernels",
+    [
+        # a prompt's first piece: every layer reads the piece's own keys
+        ("trinity", 16, ["flash_fwd_q512_k1024_t1024_h1"]),
+        # 4,096 keys: the global layer reads them all, a window layer the
+        # 49 pages its windows reach, made up to 4,096 too
+        ("trinity", 64, ["flash_fwd_q512_k1024_t1024_h1"]),
+        # the capped table (max_seq 8,704), made up to 9,216 keys
+        ("trinity", 136, ["flash_fwd_q512_k1024_t1024_h1"]),
+        # one 512-token piece a request against its own 512 keys
+        ("mistral", 8, ["flash_fwd_q512_k512_t512_h1"]),
+    ],
+)
+def test_prefill_piece_attends_through_flash_without_a_score_array(
+    v5e, on_tpu, request, cell, table_pages, kernels
+):
+    """Both serving cells' prefill piece, lowered for the described v5e at
+    the cell's sizes (Trinity: 1,024 tokens, 4 kv heads x 8, window 2,048
+    on four layers of five; Mistral: 512 tokens, 8 x 4): every layer's
+    attention is the flash forward kernel over the row's gathered window
+    — 1,024 keys a grid step whatever the table's width — and
+    the program holds no float32 array of a score's shape, which the
+    gather path wrote and read three times a layer ([4,8,1024,8704] and
+    [4,8,1024,3136] at the capped table: 1.2 GB of temporaries)."""
+    engine, params, pool, _ = request.getfixturevalue(cell)
+    engine.cache = pool
+    compiled = _compile_program(engine, params, "prefill", v5e, table_pages)
+    text = compiled.as_text()
+    assert sorted(set(re.findall(r"flash_fwd_q\d+_k\d+_t\d+_h\d+", text))) == kernels
+    assert _score_arrays(text, engine.prefill_chunk) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9

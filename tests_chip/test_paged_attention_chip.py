@@ -14,7 +14,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from kubeflow_tpu.models.transformer import paged_gather_attention
+from kubeflow_tpu.models.transformer import (
+    paged_flash_attention,
+    paged_gather_attention,
+)
 from kubeflow_tpu.ops.flash_tuning import PagedTile, select_paged_geometry
 from kubeflow_tpu.ops.paged_attention import (
     paged_attention,
@@ -208,6 +211,78 @@ def test_trinity_geometry_compiled_with_and_without_a_window(window):
     _kernel_vs_gather(*_cell_case(136, kv_heads=4, seed=7), tile=None, window=window)
     # and a narrower table, as shorter rows get
     _kernel_vs_gather(*_cell_case(32, kv_heads=4, seed=8), tile=None, window=window)
+
+
+#: a prefill piece of each serving cell: (span, first position, table
+#: pages, kv heads, groups, window)
+PIECES = {
+    # `trinity-mini_mixed-closed`: 1,024 tokens, 4 kv heads x 8
+    "trinity-first-global": (1024, 0, 16, 4, 8, None),
+    "trinity-first-window": (1024, 0, 16, 4, 8, 2048),
+    "trinity-deep-global": (1024, 7680, 136, 4, 8, None),
+    "trinity-deep-window": (1024, 7680, 136, 4, 8, 2048),
+    # after a prefix hit whose base is no multiple of the piece
+    "trinity-prefix-window": (1024, 4112, 128, 4, 8, 2048),
+    # `mistral-7b_gen-closed`: 512 tokens against their own 512 keys; and
+    # the same piece far into a row of the widest table (130 pages)
+    "mistral-first": (512, 0, 8, 8, 4, 4096),
+    "mistral-deep": (512, 7680, 130, 8, 4, 4096),
+}
+
+
+@pytest.mark.parametrize("piece", sorted(PIECES))
+def test_piece_flash_read_compiled_against_the_gather(piece):
+    """The prefill piece's read on the chip, bf16 at both serving cells'
+    shapes: the row's window gathered and the flash forward kernel over
+    it (the queries' offset a scalar-prefetch operand, grouped heads read
+    in place, the width made up to whole kv blocks) against the gather's
+    float32 score passes. The two round the probabilities against
+    different maxima (running | the row's), so they agree to bf16."""
+    span, offset, pages, kv_heads, groups, window = PIECES[piece]
+    P, D = 64, 128
+    rng = np.random.default_rng(pages)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(pages + offset), 3)
+    pool_pages = 1 + pages + 32
+    q = jax.random.normal(kq, (1, kv_heads * groups, span, D), jnp.bfloat16)
+    cache = {
+        "k": jax.random.normal(kk, (pool_pages * P, kv_heads, D), jnp.bfloat16),
+        "v": jax.random.normal(kv, (pool_pages * P, kv_heads, D), jnp.bfloat16),
+    }
+    table = jnp.asarray(
+        1 + rng.permutation(pool_pages - 1)[:pages].astype(np.int32)[None, :]
+    )
+    positions = offset + jnp.arange(span)[None, :]
+    kw = dict(page_size=P, window=window)
+    got = jax.jit(lambda *a: paged_flash_attention(*a, **kw))(
+        q, cache, table, positions
+    )
+    want = jax.jit(lambda *a: paged_gather_attention(*a, **kw))(
+        q, cache, table, positions
+    )
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+
+
+def test_block_sweep_times_the_piece_forward(tmp_path):
+    """`flash_tuning.sweep_blocks` with a query offset times the piece's
+    forward alone — 1,024 queries deep among 8,704 keys of 4 kv heads —
+    at the span tile (the keys made up to 9,216, 1,024 a grid step) and
+    at the tile the raw width would get (2,176 rows staged in sub-tiles
+    of 128), which it must beat."""
+    from kubeflow_tpu.ops import flash_tuning as ft
+
+    raw = ft.select_geometry(1024, 8704, 128, heads=32)
+    rows = ft.sweep_blocks(
+        batch=1, heads=32, seq=1024, head_dim=128, seq_kv=8704, kv_heads=4,
+        q_offset=7680, candidates=(None, raw), steps=3, logdir=str(tmp_path),
+    )
+    assert not any("error" in r for r in rows), rows
+    assert [r["seq_kv"] for r in rows] == [9216, 8704]
+    assert rows[0]["geometry"][0] == [512, 1024, 1024, 1]
+    assert rows[1]["geometry"][0] == [512, 2176, 128, 1]
+    assert all(r["fwd_ms"] > 0 and r["dq_ms"] == 0 for r in rows), rows
+    assert rows[0]["fwd_ms"] < rows[1]["fwd_ms"], rows
 
 
 def test_verify_span_and_int8_pages_compiled():
