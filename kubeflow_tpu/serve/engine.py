@@ -31,10 +31,16 @@ shapes):
   drain/postprocess of chunk N with chunk N+1's device compute (the same
   gap vLLM's async engine loop closes for GPUs). Admissions, prefill
   completions, cancellations and page reallocation are *epochs*: they
-  dirty the carry, force a merged drain of the in-flight chunk (with the
-  speculative results of retired rows masked out), and re-upload the
-  per-row arrays ONCE — the only H2D left. ``pipeline_depth=0`` keeps the
-  old fully-synchronous loop selectable for parity testing and debugging.
+  dirty the carry, and the next chunk's carry is then merged ON THE
+  DEVICE — the in-flight chunk's outputs for rows the host left alone,
+  the admitted rows' first tokens still as device handles, the host's
+  values for every row it edited — after one H2D of the fields only the
+  host changes. The epoch's chunk is dispatched before any first token
+  is read or the chunk in flight is drained, so an epoch keeps the
+  pipeline full; it drains first only when nothing is in flight (the
+  batch ran empty) and at the end of a burst. ``pipeline_depth=0`` keeps
+  the old fully-synchronous loop selectable for parity testing and
+  debugging.
 
 Correctness contract (pinned by tests/test_engine.py): a request's tokens
 are IDENTICAL to what the whole-batch ``make_generate_fn`` path produces
@@ -43,7 +49,7 @@ pipelined carry are scheduling optimizations, never a numerics change.
 The speculative chunk is safe because every per-row liveness decision the
 device needs (EOS, budget exhaustion) is already computed in-graph; only
 host-initiated transitions (admit/cancel/prefill-activate) require an
-epoch, and those are exactly the points that re-upload.
+epoch, and those are exactly the rows the merge takes from the host.
 """
 
 from __future__ import annotations
@@ -108,9 +114,9 @@ _SCHED_PHASES = (
     "admit",             # retire cancelled/expired rows, queue poll, pages,
                          # mirrors, prefix store, first-token push
     "prefill_dispatch",  # build one prefill piece and enqueue its program
-    "prefill_wait",      # blocked until the device finished that piece —
-                         # and every program queued before it
-    "carry_upload",      # the epoch's H2D of the per-row arrays
+    "prefill_wait",      # blocked on a final piece's first token — and on
+                         # every program queued before that piece
+    "carry_upload",      # the epoch's H2D of the host's fields + the merge
     "chunk_dispatch",    # rng split, table widening, enqueue a decode chunk
     "drain_wait",        # blocked on a chunk's results (D2H)
     "drain_emit",        # credit tokens, push, spans, retirements
@@ -389,6 +395,10 @@ class EngineOverloaded(RuntimeError):
 #: tokens (`LMEngine._moe_counts`), each kept per phase in ``stats``
 _MOE_COUNTERS = ("assignments", "experts_touched", "layer_steps", "load_max")
 
+#: where the epoch's merge takes a row's carry from (``LMEngine._merge``):
+#: the chunk in flight, the host mirrors, or its final prefill piece
+_KEEP, _HOST, _FIRST = 0, 1, 2
+
 
 class LMEngine:
     """Continuous-batching engine over a TransformerLM + params.
@@ -666,6 +676,11 @@ class LMEngine:
                 f"moe_{name}_{phase}": 0
                 for name in _MOE_COUNTERS for phase in ("decode", "prefill")
             },
+            # carry rebuilds a host edit caused (admission, a first token,
+            # a host retirement), and of them those made with nothing in
+            # flight: the pipeline drained first (the batch ran empty, the
+            # end of a burst, pipeline_depth=0) instead of merging
+            "epochs": 0, "epoch_drains": 0,
             # the scheduler thread's wall time, once per loop iteration, and
             # under it each phase's self seconds and entries (_phase)
             "sched_loop_s": 0.0,
@@ -680,8 +695,23 @@ class LMEngine:
         # holds the pipeline gauges exported as kft_engine_* (obs/names.py).
         self._carry: dict[str, Any] | None = None
         self._carry_dirty = True
+        #: rows whose host mirrors are newer than the device carry's
+        #: (admitted since the last carry build): the merge takes them
+        #: from the host even where the host says they decode
+        self._carry_edit = np.zeros((max_batch,), bool)
+        #: final prefill pieces dispatched and not yet read: (row, request,
+        #: first token, its validity, routing counts), device handles
+        self._firsts: list[tuple] = []
+        #: the int8 pool's quantization error of each piece, unread
+        self._qerrs: list = []
+        #: a (token, validity) pair of device scalars that stands in the
+        #: merge for every row without a first token: the merge program's
+        #: arguments then have one structure, and — a real piece's outputs
+        #: once there is one — the same placement as a first token's
+        self._filler: tuple | None = None
         self._carry_chunks = 0   # chunks dispatched since last upload
-        self._carry_h0 = 0       # max(real_len+gen_count) at upload
+        self._carry_h0 = 0       # max(real_len+gen_count) at upload, plus
+                                 # the in-flight chunk's span when merged
         self._carry_hcap = 0     # max(real_len+budget) at upload
         self._carry_pages_w = 0  # uploaded table width (pages)
         self._last_dispatch: float | None = None
@@ -776,6 +806,7 @@ class LMEngine:
             else self._chunk_paged_impl,
             donate_argnums=chunk_donate, static_argnames=("seeded",),
         )
+        self._merge = jax.jit(self._merge_carry_impl)
         #: the model's programs are traced and run with the engine's mesh
         #: in force, so what they decide by it (the paged read path: a
         #: Mosaic kernel is not partitioned automatically) they decide
@@ -1201,6 +1232,34 @@ class LMEngine:
         return (cache, tok, gen_count, active, toks.T, valid.T) + self._moe * (
             jax.tree_util.tree_map(lambda x: x.sum(0), moe),
         )
+
+    def _merge_carry_impl(
+        self, last_tok, gen_count, active, host, toks, valids,
+        hist=None, hist_host=None, real_len=None,
+    ):
+        """The epoch's carry of the rows' decode state, built on the
+        device. ``host`` is (4, B): the source of each row (``_KEEP``,
+        ``_HOST``, ``_FIRST``) and the host mirrors of its last token,
+        generation count and liveness. A kept row takes the carry given
+        (the in-flight chunk's outputs); a host row its mirrors; a first
+        row its final piece's token (``toks``, one device scalar a row),
+        one token generated, and live if the host lets it decode and the
+        token is not EOS (``valids``). Under speculation the history is
+        merged the same way, with a first row's token written at its
+        prompt's end."""
+        mode, h_last, h_gen, h_act = host
+        keep, first = mode == _KEEP, mode == _FIRST
+        tok = jnp.stack(toks).astype(last_tok.dtype)
+        valid = jnp.stack(valids)
+        last_tok = jnp.where(keep, last_tok, jnp.where(first, tok, h_last))
+        gen_count = jnp.where(keep, gen_count, jnp.where(first, 1, h_gen))
+        active = jnp.where(keep, active, (h_act != 0) & (valid | ~first))
+        if hist is None:
+            return last_tok, gen_count, active
+        col = jnp.arange(hist.shape[1])[None, :]
+        hist = jnp.where(keep[:, None], hist, hist_host)
+        put = (first & valid)[:, None] & (col == real_len[:, None])
+        return last_tok, gen_count, active, jnp.where(put, tok[:, None], hist)
 
     # -- host scheduler ----------------------------------------------------- #
 
@@ -1671,8 +1730,9 @@ class LMEngine:
         # admission looks for space — a disconnected client must not hold a
         # row, and a row past its budget must stop costing decode steps.
         # This runs at the top of every loop iteration, i.e. exactly the
-        # PR 6 epoch seam: _finish dirties the carry, the in-flight chunk
-        # drain-merges with the retired row masked out, then ONE re-upload.
+        # epoch seam: _finish dirties the carry, the next chunk's carry is
+        # merged with the retired row inactive, and the in-flight chunk's
+        # results for it are masked out when it drains.
         now = time.monotonic()
         for row in range(self.max_batch):
             req = self._slots[row]
@@ -1875,12 +1935,13 @@ class LMEngine:
             "n_pieces": n_pieces, "piece": 0,
         }
         # admission epoch: the per-row mirrors and the block table changed —
-        # the next dispatch must merge+re-upload the carry
+        # the next dispatch must rebuild the carry, this row from the host
         self._carry_dirty = True
+        self._carry_edit[row] = True
         if n_pieces == 1:
-            # single-piece prompts admit synchronously (no interleaving to
-            # gain); multi-piece rows take ONE piece per loop iteration via
-            # _advance_prefills so decode chunks run between pieces
+            # single-piece prompts dispatch their piece now (no interleaving
+            # to gain); multi-piece rows take ONE piece per loop iteration
+            # via _advance_prefills so decode chunks run between pieces
             self._advance_prefill(row)
 
     def _admit_injected(self, req: _Request, row: int) -> None:
@@ -1902,6 +1963,7 @@ class LMEngine:
         self._implant_paged(tree, row, n16)
         req.row = row
         self._slots[row] = req
+        self._carry_edit[row] = True
         self.real_len[row] = len(req.ids)
         if self.spec_k:
             self.hist_host[row, :] = self.pad_id
@@ -1963,8 +2025,11 @@ class LMEngine:
         return tuple(key), jtree
 
     def _advance_prefill(self, row: int) -> None:
-        """Run ONE prefill piece for a prefilling row; the final piece
-        yields the first token and activates (or finishes) the request."""
+        """Dispatch ONE prefill piece for a prefilling row and return
+        without waiting for it. The final piece's first token stays a
+        device handle in ``_firsts``: the epoch's merge activates the row
+        with it on the device, and ``_take_firsts`` reads it once the
+        chunk that decodes the row is queued."""
         with self._phase("prefill_dispatch"):
             st = self._prefilling[row]
             req, rest, base, C = st["req"], st["rest"], st["base"], st["C"]
@@ -1998,12 +2063,7 @@ class LMEngine:
                     seeded=req.seed is not None,
                 )
         if self.kv_quant == "int8":
-            # same inline sync budget as the final piece's int(tok) below:
-            # prefill is synchronous by design (one row, host-driven)
-            with self._phase("prefill_wait"):
-                e, d = float(qerr[0]), float(qerr[1])
-            if d > 0:
-                self._ewma("kv_quant_error", e / d)
+            self._qerrs.append(qerr)  # read with the first tokens
         self.stats["prefill_pieces"] += 1
         self.stats["prefill_pieces_flash_read"] += flash_read
         self.stats["prefill_tokens"] += len(piece_ids)
@@ -2014,19 +2074,54 @@ class LMEngine:
         if not final:
             return  # tok is a throwaway sample from a non-final position
         del self._prefilling[row]
-        if st["moe"]:
+        # the row's mirrors as the merged carry holds them before the token
+        # is read: one token generated, live if it is to decode at all (an
+        # EOS first token retires it in _take_firsts)
+        self.gen_count[row] = 1
+        self.active[row] = not req.want_kv_span and req.max_new_tokens > 1
+        self._carry_dirty = True
+        self._carry_edit[row] = True
+        self._filler = (tok, valid)
+        self._firsts.append((row, req, tok, valid, st["moe"]))
+        if req.pspan is not None and not st["moe"]:
+            req.pspan.end()  # admission to the last piece's dispatch
+            req.pspan = None
+
+    def _take_firsts(self) -> None:
+        """Read the first tokens of the final pieces dispatched so far —
+        in an epoch after its chunk is queued, so the reads wait only on
+        programs ahead of that chunk — and finish their admissions: push
+        the token, settle the row's mirrors, retire a one-token
+        completion (the carry already gates it), store the prompt's
+        prefix, export a KV span."""
+        if self._qerrs:
             with self._phase("prefill_wait"):
-                routed = self._count_moe(st["moe"], "prefill")
-            if req.pspan is not None:
-                req.pspan.set_attr("assignments", routed["assignments"])
-                req.pspan.set_attr("experts_touched", routed["experts_touched"])
+                errs = [(float(q[0]), float(q[1])) for q in self._qerrs]
+            self._qerrs = []
+            for e, d in errs:
+                if d > 0:
+                    self._ewma("kv_quant_error", e / d)
+        firsts, self._firsts = self._firsts, []
+        for row, req, tok, valid, moe in firsts:
+            if self._slots[row] is not req:
+                continue  # failed before its first token was read (stop)
+            self._take_first(row, req, tok, valid, moe)
+
+    def _take_first(self, row, req, tok, valid, moe) -> None:
+        with self._phase("prefill_wait"):
+            routed = self._count_moe(moe, "prefill") if moe else None
+            tok, valid = int(tok), bool(valid)
         if req.pspan is not None:
+            # a routed model's prefill span ends where its counts are read
+            if routed is not None:
+                req.pspan.set_attr("assignments", routed["assignments"])
+                req.pspan.set_attr(
+                    "experts_touched", routed["experts_touched"]
+                )
             req.pspan.end()
             req.pspan = None
         if self._prefix_cache is not None:
             self._store_prefix(req.ids, row)
-        with self._phase("prefill_wait"):
-            tok, valid = int(tok), bool(valid)
         if req.want_kv_span:
             # disaggregated prefill: extract the finished span (ceil-16
             # window) and retire the row WITHOUT activating — a prefill
@@ -2041,23 +2136,17 @@ class LMEngine:
                 "valid": valid,
             }
             self.stats["kv_spans_exported"] += 1
-            self._finish(row)
+            self._finish(row, carry_stale=False)
             return
         if valid:
             req.push([tok])
             if self.spec_k:
                 self.hist_host[row, len(req.ids)] = tok
         self.last_tok[row] = tok
-        # one-token completions (eos first, or budget 1) finish here
-        finished = (not valid) or req.max_new_tokens <= 1
-        if finished:
-            self._finish(row)
-        else:
-            self.active[row] = True
-            self.gen_count[row] = 1
-            # activation epoch: the row joins the device batch at the next
-            # carry upload
-            self._carry_dirty = True
+        # one-token completions (eos first, or budget 1) finish here; the
+        # merge left them inactive on the device
+        if (not valid) or req.max_new_tokens <= 1:
+            self._finish(row, carry_stale=False)
 
     def _advance_prefills(self) -> None:
         for row in list(self._prefilling):
@@ -2214,13 +2303,23 @@ class LMEngine:
             self.stats["sched_loop_s"] += time.perf_counter() - t0
 
     def _loop_once(self, pending: _PendingChunk | None) -> _PendingChunk | None:
-        """One scheduler iteration; takes and returns the chunk in flight."""
+        """One scheduler iteration; takes and returns the chunk in flight.
+
+        An iteration that admits, activates or retires rows is an epoch,
+        and it keeps the pipeline full: every admitted row's pieces are
+        dispatched back to back, the next chunk's carry is merged on the
+        device from the chunk in flight (``_upload_carry``), that chunk
+        is dispatched, and only then are the first tokens read and the
+        chunk in flight drained. With nothing in flight the first tokens
+        are read at once and the carry is built from current mirrors."""
         # watchdog heartbeat: stale while work exists ⇒ the loop is
         # wedged inside a device call (or a chaos hook)
         self._beat = time.monotonic()
         with self._phase("admit"):
             self._admit_all()
             self._advance_prefills()  # one piece per prefilling row
+            if pending is None:
+                self._take_firsts()  # nothing in flight to hide them
         if not self.active.any():
             if pending is not None:
                 # burst tail: the speculative chunk outlived its rows
@@ -2246,38 +2345,48 @@ class LMEngine:
             # immediate D2H drain — the pre-pipeline hot loop, kept
             # selectable so pipelined parity is provable seed-for-seed
             with self._phase("carry_upload"):
+                self._count_epoch(drained=True)
                 self._upload_carry()
             with self._phase("chunk_dispatch"):
                 nxt = self._dispatch_chunk()
             self._drain_chunk(nxt)
             return None
-        if self._carry_dirty:
-            if pending is not None:
-                # merge point: drain the in-flight chunk first so the
-                # host mirrors are current (retired rows masked out),
-                # then loop — the drain may free rows/pages admission
-                # wants before the single merged re-upload
-                self._drain_chunk(pending)
-                return None
-            with self._phase("carry_upload"):
-                self._upload_carry()
-        if pending is not None and self._all_may_retire():
+        if pending is not None and not self._firsts and self._all_may_retire():
             # end-of-burst: every active row can exhaust its budget
             # inside the in-flight chunk, so a speculative dispatch
             # would likely decode only dead rows — drain first instead
             # and let the retirements land (EOS tails still cost at
             # most one dead chunk; budgets are host-knowable, EOS
-            # isn't)
+            # isn't). A row whose first token is pending needs a chunk.
             self._drain_chunk(pending)
             return None
-        # one-chunk-ahead: dispatch N+1 on the device carry BEFORE
-        # draining N, so N's token D2H + host postprocess overlap
-        # N+1's device compute
+        if self._carry_dirty:
+            # the epoch: with a chunk in flight the carry is merged from
+            # its outputs, which the host mirrors lag by up to a chunk
+            with self._phase("carry_upload"):
+                self._count_epoch(drained=pending is None)
+                self._upload_carry(
+                    lag=0 if pending is None else self._chunk_span
+                )
+        # one-chunk-ahead: dispatch N+1 on the device carry BEFORE reading
+        # the epoch's first tokens and draining N, so N's token D2H + host
+        # postprocess overlap N+1's device compute
         with self._phase("chunk_dispatch"):
             nxt = self._dispatch_chunk()
+        if self._firsts:
+            with self._phase("admit"):
+                self._take_firsts()
         if pending is not None:
             self._drain_chunk(pending)
         return nxt
+
+    def _count_epoch(self, *, drained: bool) -> None:
+        """Count a carry rebuild that a host edit caused, and whether the
+        pipeline was empty for it (``stats["epochs"]``,
+        ``stats["epoch_drains"]``)."""
+        if self._carry_dirty:
+            self.stats["epochs"] += 1
+            self.stats["epoch_drains"] += int(drained)
 
     # -- pipelined decode: carry upload / dispatch / drain ------------------- #
 
@@ -2304,10 +2413,19 @@ class LMEngine:
             (1.0 - alpha) * cur + alpha * value
         )
 
-    def _upload_carry(self) -> None:
-        """Upload the per-row scheduling arrays from the host mirrors —
-        the ONE H2D an epoch pays. Must only run with the mirrors current
-        (no undrained chunk): the pipelined loop drains before editing.
+    def _upload_carry(self, lag: int = 0) -> None:
+        """Build the carry of the next dispatch — the ONE H2D an epoch
+        pays: the fields only the host changes (lengths, budgets,
+        temperatures, seeds, the block table, under speculation the
+        history) from the mirrors, and the decode state (last token,
+        generation count, liveness) by the merge program (``_merge``)
+        from the carry a chunk left, row by row: a row the host says
+        decodes and did not edit keeps the device's values (the chunk in
+        flight may have moved it on), a row whose final prefill piece is
+        pending takes that piece's token while it is still a device
+        handle, every other row takes the host's. ``lag``: how far the
+        mirrors of the kept rows may trail the device — a chunk's span
+        when one is in flight, which the page window must cover.
 
         Every mirror is ``.copy()``-snapshotted first: on the CPU backend
         ``jnp.asarray`` of an aligned numpy buffer is ZERO-COPY, so the
@@ -2316,27 +2434,60 @@ class LMEngine:
         rewrite what an in-flight chunk reads — an interleaving-dependent
         wrong-token/lost-row race (observed as chunked-prefill rows
         truncating to their first token under churn)."""
+        prev = self._carry
+        first = np.zeros((self.max_batch,), bool)
+        if self._filler is None:  # no piece yet: an engine of implants
+            self._filler = (
+                jnp.asarray(np.zeros((), np.int32)),
+                jnp.asarray(np.zeros((), np.bool_)),
+            )
+        toks, valids = (
+            [self._filler[0]] * self.max_batch,
+            [self._filler[1]] * self.max_batch,
+        )
+        for row, _, tok, valid, _ in self._firsts:
+            first[row], toks[row], valids[row] = True, tok, valid
+        keep = self.active & ~self._carry_edit & ~first & (prev is not None)
+        host = np.stack([
+            np.where(keep, _KEEP, np.where(first, _FIRST, _HOST)),
+            self.last_tok, self.gen_count, self.active,
+        ]).astype(np.int32)
         c: dict[str, Any] = {
-            "last_tok": jnp.asarray(self.last_tok.copy()),
-            "gen_count": jnp.asarray(self.gen_count.copy()),
-            "active": jnp.asarray(self.active.copy()),
             "real_len": jnp.asarray(self.real_len.copy()),
             "budget": jnp.asarray(self.budget.copy()),
             "temp": jnp.asarray(self.temp.copy()),
             "seed": jnp.asarray(self.seeds.copy()),
         }
+        if prev is None:
+            prev = {
+                "last_tok": jnp.asarray(self.last_tok.copy()),
+                "gen_count": jnp.asarray(self.gen_count.copy()),
+                "active": jnp.asarray(self.active.copy()),
+            }
+            if self.spec_k:
+                prev["hist"] = jnp.asarray(self.hist_host.copy())
+        # the device history is rewritten in-graph chunk→chunk; an epoch
+        # takes the rows it edits from the host mirror — one small int32
+        # H2D per epoch
+        hist = (
+            (prev["hist"], jnp.asarray(self.hist_host.copy()), c["real_len"])
+            if self.spec_k else ()
+        )
+        with self._mesh_scope():
+            c["last_tok"], c["gen_count"], c["active"], *merged = self._merge(
+                prev["last_tok"], prev["gen_count"], prev["active"],
+                jnp.asarray(host), tuple(toks), tuple(valids), *hist,
+            )
+        if self.spec_k:
+            (c["hist"],) = merged
+        self._carry_edit[:] = False
         # host-side twin of c["seed"]: picks the chunk-program variant
         # without a device sync (static `seeded` jit specialization)
         self._carry_seeded = bool((self.seeds >= 0).any())
-        if self.spec_k:
-            # the device history is rewritten in-graph chunk→chunk; an
-            # epoch rebuilds it from the host mirror (current: epochs
-            # always drain first) — one small int32 H2D per epoch
-            c["hist"] = jnp.asarray(self.hist_host.copy())
         act = self.active
         if act.any():
             reach = self.real_len + self.gen_count
-            self._carry_h0 = int(reach[act].max())
+            self._carry_h0 = int(reach[act].max()) + lag
             self._carry_hcap = int((self.real_len + self.budget)[act].max())
         else:
             self._carry_h0 = self._carry_hcap = 0

@@ -91,3 +91,35 @@ class GenerateOracle:
             jax.random.PRNGKey(7), np.zeros((1,), np.float32),
         )
         return [int(t) for t in np.asarray(toks)[0, : int(n_valid[0])]]
+
+
+def drive_schedule(eng, schedule, *, cancel=None, max_iters=400):
+    """Run an unstarted engine's scheduler on this thread, one
+    ``_loop_once`` at a time, so that a test decides which iteration a
+    request arrives in — and so what is in flight when it is admitted.
+    ``schedule`` maps an iteration to the requests enqueued before it,
+    each ``(ids, max_new_tokens, {"temperature": .., "seed": ..})`` (the
+    dict optional); ``cancel`` maps an iteration to the indices, in
+    arrival order, of requests cancelled before it. Iterations with
+    nothing to run are skipped. Returns the requests in arrival order."""
+    reqs, pending, it = [], None, 0
+    cancel = cancel or {}
+    last = max([*schedule, *cancel])
+    while True:
+        assert it < max_iters, "the schedule never drained"
+        for ids, new, *kw in schedule.get(it, ()):
+            kw = kw[0] if kw else {}
+            reqs.append(eng._enqueue(
+                list(ids), new, kw.get("temperature", 0.0), live=False,
+                seed=kw.get("seed"),
+            ))
+        for i in cancel.get(it, ()):
+            reqs[i].cancelled.set()
+        it += 1
+        if pending is None and not eng.busy():
+            if it > last:
+                break
+            continue  # nothing resident: the next arrival is the work
+        pending = eng._loop_once(pending)
+    assert all(r.done.is_set() for r in reqs)
+    return reqs

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import GenerateOracle
+from conftest import GenerateOracle, drive_schedule
 
 from kubeflow_tpu.models.transformer import TransformerConfig, TransformerLM
 from kubeflow_tpu.serve.engine import LMEngine
@@ -1025,15 +1025,19 @@ def test_pipelined_inline_token_parity_under_churn(model_and_params):
     """The tentpole contract: pipeline_depth=1 (device-resident carry +
     one-chunk-ahead dispatch) emits byte-identical token streams to the
     inline pipeline_depth=0 path for the same seed, under admission churn
-    (7 staggered requests through 3 rows), chunked prefill (prefill_chunk
-    splits the long prompts), and a mid-stream cancellation."""
+    (9 staggered requests through 3 rows), chunked prefill (prefill_chunk
+    splits the long prompts), a one-token budget, a seeded sampled row
+    and a mid-stream cancellation — admissions landing while a chunk is
+    in flight, whose carry the epoch merges on the device."""
     model, params = model_and_params
     rng = np.random.default_rng(71)
     # mixed lengths: several short, two long enough for multi-piece prefill
     prompts = _prompts(rng, 5, lo=3, hi=14) + [
         [int(x) for x in rng.integers(2, CFG.vocab_size, size=n)]
         for n in (34, 41)
-    ]
+    ] + _prompts(rng, 2, lo=3, hi=14)
+    budgets = [12] * 7 + [1, 12]      # 7: done at its first token
+    sampled = {8: {"temperature": 0.8, "seed": 99}}
 
     def run_mode(depth):
         eng = LMEngine(
@@ -1047,7 +1051,10 @@ def test_pipelined_inline_token_parity_under_churn(model_and_params):
         def worker(i):
             try:
                 time.sleep(0.02 * i)  # staggered arrivals → admission churn
-                outs[i] = eng.submit(prompts[i], max_new_tokens=12)
+                outs[i] = eng.submit(
+                    prompts[i], max_new_tokens=budgets[i],
+                    **sampled.get(i, {}),
+                )
             except Exception as e:  # noqa: BLE001
                 errors.append(e)
 
@@ -1066,24 +1073,31 @@ def test_pipelined_inline_token_parity_under_churn(model_and_params):
                 t.join(180)
             stats = dict(eng.stats)
             uploads = eng.overlap["carry_uploads"]
+            merges = eng._merge._cache_size()
         finally:
             eng.stop()
         assert not errors, errors
-        return outs, stats, uploads
+        return outs, stats, uploads, merges
 
-    pipe, pipe_stats, pipe_uploads = run_mode(1)
-    inline, _, _ = run_mode(0)
+    pipe, pipe_stats, pipe_uploads, merges = run_mode(1)
+    inline, _, _, _ = run_mode(0)
     assert len(pipe) == len(prompts)
     for i in range(len(prompts)):
         assert pipe[i] == inline[i], (i, pipe[i], inline[i])
+        if i in sampled:
+            continue  # seeded: position-folded draws, equal across depths
         # and both equal the pinned whole-batch reference (greedy)
-        want = _reference_completion(model, params, prompts[i], 12)
+        want = _reference_completion(model, params, prompts[i], budgets[i])
         assert pipe[i] == want, (i, pipe[i], want)
+    assert len(pipe[7]) == 1
     assert pipe_stats["max_concurrent"] >= 2  # churn really happened
     assert pipe_stats["prefill_pieces"] > len(prompts)  # chunked prefills ran
     # epochs, not chunks: uploads bounded by admissions/activations, far
     # below one per chunk once decode is the steady state
     assert pipe_uploads < pipe_stats["chunks"] + 2 * pipe_stats["admitted"]
+    # admissions merged into a running batch, by one program
+    assert pipe_stats["epochs"] > pipe_stats["epoch_drains"]
+    assert merges == 1
 
 
 def test_pipelined_steady_state_uploads_are_epochs_not_chunks(
@@ -1201,7 +1215,8 @@ def test_carry_upload_never_aliases_host_mirrors(model_and_params):
     refresh) retroactively rewrites what an in-flight chunk reads. That
     raced as chunked-prefill rows truncating to their first token under
     churn. The carry (and the paged device table) must be immune to
-    mirror mutation after upload."""
+    mirror mutation after upload — the carry an epoch merges with a
+    chunk in flight as much as one built from drained mirrors."""
     model, params = model_and_params
     eng = LMEngine(
         model, CFG, params, max_batch=2, max_seq=64, chunk_steps=2,
@@ -1227,6 +1242,63 @@ def test_carry_upload_never_aliases_host_mirrors(model_and_params):
     pager.free(0)
     pager.alloc(1, 3)
     assert (np.asarray(dev) == before).all()
+
+    # in flight: admissions merged into a running batch — every mirror is
+    # scribbled over while the merge and each chunk run, and no stream
+    # may notice
+    mirrors = ("last_tok", "gen_count", "active", "real_len", "budget",
+               "temp", "seeds")
+
+    def scribbling(eng, fn):
+        def call(*args, **kw):
+            saved = {m: getattr(eng, m).copy() for m in mirrors}
+            table = eng.pager.table.copy()
+            for m in mirrors:
+                arr = getattr(eng, m)
+                arr[...] = ~arr if arr.dtype == bool else arr + 7
+            eng.pager.table[...] = 0
+            try:
+                out = fn(*args, **kw)
+                jax.block_until_ready(out)
+            finally:
+                for m in mirrors:
+                    getattr(eng, m)[...] = saved[m]
+                eng.pager.table[...] = table
+            return out
+
+        return call
+
+    def aligned(a):
+        # a 64-byte-aligned copy: the CPU backend's jnp.asarray then shares
+        # the buffer instead of copying it, so an upload that skipped its
+        # snapshot WOULD alias — the race is certain here, not occasional
+        buf = np.zeros(a.nbytes + 64, np.uint8)
+        off = -buf.ctypes.data % 64
+        out = buf[off:off + a.nbytes].view(a.dtype).reshape(a.shape)
+        out[...] = a
+        return out
+
+    prompts = _prompts(np.random.default_rng(29), 3, lo=4, hi=12)
+    eng = LMEngine(
+        model, CFG, params, max_batch=3, max_seq=64, chunk_steps=2,
+        prefill_buckets=(32,), eos_id=EOS, kv_pool_tokens=16 * 12,
+        page_size=16,
+    )
+    for m in mirrors:
+        setattr(eng, m, aligned(getattr(eng, m)))
+    eng.pager.table = aligned(eng.pager.table)
+    eng._merge = scribbling(eng, eng._merge)
+    eng._chunk = scribbling(eng, eng._chunk)
+    try:
+        reqs = drive_schedule(eng, {
+            0: [(prompts[0], 20)], 2: [(prompts[1], 8)],
+            3: [(prompts[2], 8)],
+        })
+    finally:
+        eng.stop()
+    assert eng.stats["epochs"] > eng.stats["epoch_drains"]  # merged
+    for req, new in zip(reqs, (20, 8, 8)):
+        assert req.tokens == _reference_completion(model, params, req.ids, new)
 
 
 def test_engine_config_object_and_depth_validation(model_and_params):

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import GenerateOracle
+from conftest import GenerateOracle, drive_schedule
 
 from kubeflow_tpu.models.transformer import TransformerConfig, TransformerLM
 from kubeflow_tpu.serve.engine import LMEngine
@@ -423,10 +423,121 @@ def test_paged_pipelined_parity_across_horizon_growth(model_and_params):
                 assert (
                     eng.overlap["carry_uploads"] < eng.stats["chunks"]
                 ), (eng.overlap["carry_uploads"], eng.stats["chunks"])
+            # one request at a time: every admission finds the batch empty,
+            # so every epoch is built from drained mirrors
+            assert eng.stats["epoch_drains"] == eng.stats["epochs"] >= 3
         finally:
             eng.stop()
     assert outs[0] == outs[1], (outs[0], outs[1])
     assert any(len(o) > 0 for o in outs[1])
+
+
+def _first_token(model, params, ids):
+    return GenerateOracle(model, CFG, params, eos_id=EOS).submit(ids, 1)[0]
+
+
+#: the admission epoch with a chunk in flight, case by case: engine
+#: settings beyond ``_EPOCH_KW``, arrivals by scheduler iteration
+#: (``conftest.drive_schedule``: prompt indices into ``_epoch_prompts``,
+#: a budget, sampling) and cancellations. A long first request keeps a
+#: chunk in flight — and the batch from ever running empty — while the
+#: others are admitted.
+_EPOCH_CASES = {
+    # three admissions, the last held in the queue until a row frees
+    "admit_in_flight": ({}, {0: [(0, 40)], 2: [(1, 12)], 3: [(2, 12)],
+                             5: [(3, 12)]}, {}),
+    # the engine's EOS is prompt 6's first token: retired at its first
+    "eos_first": ({"eos_id": "first token of 6"},
+                  {0: [(0, 40)], 2: [(6, 12)], 3: [(1, 12)]}, {}),
+    "budget_one": ({}, {0: [(0, 40)], 2: [(1, 1)], 3: [(2, 12)]}, {}),
+    # a row killed by the host and re-admitted in one merge
+    "cancel_in_merge": ({"max_batch": 2},
+                        {0: [(0, 40)], 1: [(1, 30)], 4: [(2, 12)]},
+                        {4: [1]}),
+    # three 16-token pieces: the last lands two epochs after admission
+    "multi_piece": ({"prefill_chunk": 16},
+                    {0: [(0, 40)], 2: [(8, 12)], 3: [(1, 12)]}, {}),
+    # prompt 10 stores its prefix; prompt 11 hits it in flight
+    "prefix_hit": ({"prefix_cache_entries": 4},
+                   {0: [(0, 40)], 1: [(10, 6)], 5: [(11, 10)]}, {}),
+    "seeded": ({}, {0: [(0, 40)], 2: [(1, 16, {"temperature": 0.9,
+                                               "seed": 1234})],
+                    3: [(2, 12)]}, {}),
+    "spec": ({"spec_draft_tokens": 3},
+             {0: [(9, 40)], 2: [(1, 12)], 3: [(2, 12)]}, {}),
+}
+_EPOCH_KW = dict(
+    max_batch=3, max_seq=64, chunk_steps=4, prefill_buckets=(32,),
+    eos_id=EOS, kv_pool_tokens=16 * 12, page_size=16, seed=7,
+)
+
+
+def _epoch_prompts():
+    rng = np.random.default_rng(61)
+    shared = [7] * 20
+    return _prompts(rng, 8, lo=4, hi=11) + [
+        [int(x) for x in rng.integers(2, 89, size=40)],   # 8: three pieces
+        [5, 6, 7] * 4,                                    # 9: drafts match
+        shared + [11, 12],                                # 10, 11: a prefix
+        shared + [13, 14, 15],
+    ]
+
+
+@pytest.mark.parametrize("case", list(_EPOCH_CASES))
+def test_paged_pipelined_parity_in_epochs(model_and_params, case):
+    """An admission epoch with a chunk in flight merges the next chunk's
+    carry on the device and dispatches it before any first token is read
+    (serve/engine.py ``_upload_carry``): every stream equals the inline
+    ``pipeline_depth=0`` engine's token for token — greedy ones the
+    whole-batch path's too — while the pipeline is never drained for an
+    epoch after the first dispatch, and the merge is one program."""
+    model, params = model_and_params
+    overrides, arrivals, cancel = _EPOCH_CASES[case]
+    prompts = _epoch_prompts()
+    kw = dict(_EPOCH_KW, **overrides)
+    if kw["eos_id"] != EOS:
+        kw["eos_id"] = _first_token(model, params, prompts[6])
+    schedule = {
+        it: [(prompts[i], *rest) for i, *rest in reqs]
+        for it, reqs in arrivals.items()
+    }
+    outs, stats = {}, {}
+    for depth in (0, 1):
+        eng = LMEngine(model, CFG, params, pipeline_depth=depth, **kw)
+        try:
+            reqs = drive_schedule(eng, schedule, cancel=cancel)
+            assert not [r.error for r in reqs if r.error is not None]
+            outs[depth] = [r.tokens for r in reqs]
+            stats[depth] = dict(eng.stats)
+            assert eng.pager.used_pages == 0
+            if depth == 1:
+                assert eng._merge._cache_size() == 1
+        finally:
+            eng.stop()
+    oracle = GenerateOracle(model, CFG, params, eos_id=kw["eos_id"])
+    flat = [r for it in sorted(schedule) for r in schedule[it]]
+    cancelled = {i for ids in cancel.values() for i in ids}
+    for i, (ids, new, *sampling) in enumerate(flat):
+        want = oracle.submit(ids, new) if not sampling else outs[0][i]
+        if i in cancelled:  # walked away mid-stream: a prefix of its own
+            assert outs[1][i] == want[: len(outs[1][i])], (i, outs[1][i])
+            continue
+        assert outs[1][i] == outs[0][i], (i, outs[1][i], outs[0][i])
+        assert outs[1][i] == want, (i, outs[1][i], want)
+    s = stats[1]
+    # merged with a chunk in flight; drained only for the first dispatch
+    assert s["epochs"] > s["epoch_drains"] == 1, s
+    assert stats[0]["epochs"] == stats[0]["epoch_drains"] > 0
+    if case == "eos_first":
+        assert outs[1][1] == []
+    if case == "budget_one":
+        assert len(outs[1][1]) == 1
+    if case == "multi_piece":
+        assert s["prefill_pieces"] >= len(flat) + 2
+    if case == "prefix_hit":
+        assert s["prefix_hits"] >= 1
+    if case == "spec":
+        assert s["spec_proposed"] > 0
 
 
 def test_paged_pipelined_concurrent_with_backpressure(model_and_params):

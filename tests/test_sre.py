@@ -216,7 +216,7 @@ def test_queued_past_deadline_never_admitted(model_and_params):
 
 def test_mid_decode_deadline_cancelled_at_epoch(model_and_params):
     """A row past its deadline mid-generation is cancelled at the next
-    epoch boundary (the PR 6 drain-merge seam): the caller gets
+    epoch boundary (the carry merge seam): the caller gets
     DeadlineExceeded and the row frees for new work."""
     from kubeflow_tpu.chaos.injectors import slow_decode
 
