@@ -45,8 +45,10 @@ TIMES = 10.0
 #: the loosest limits any cell may state. Loose on purpose: an honest
 #: routed model that holds all of its experts reads mean 0.017, while
 #: mathematics wrong throughout (the chosen experts' weights not normalised)
-#: reads mean 1.1, p99 3.4, 17 % the reference's choice (PERF.md section 4)
-BACKSTOP = {"regret_mean": 0.05, "regret_p99": 0.5, "argmax_share_min": 0.8}
+#: reads mean 1.1, p99 3.4, 17 % the reference's choice (PERF.md section 4).
+#: p99: Trinity-Mini's sound runs read up to 0.4991 and its int8-weights
+#: control at least 0.9576, so the cell's limit, 0.7, lies between them
+BACKSTOP = {"regret_mean": 0.05, "regret_p99": 0.7, "argmax_share_min": 0.8}
 P99_MIN_TOKENS = 1000
 
 #: limit key -> the statistic it bounds

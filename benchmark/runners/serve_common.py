@@ -121,8 +121,9 @@ def length_bounds(spec) -> tuple[int, int]:
 
 class Client:
     """Sends the mix's requests to one URL and records what came back.
-    ``mode`` ``closed``: ``clients`` callers, each sending its next request
-    when the last one ended. ``open``: every request at its due time."""
+    ``mode`` ``closed``: ``clients`` callers, each sending the next unsent
+    request when its last one ended. ``open``: every request at its due
+    time."""
 
     def __init__(self, url: str, requests, *, mode: str, clients: int, traced: bool, seed: int):
         self.url, self.requests = url, requests
@@ -220,12 +221,19 @@ class Client:
             self.t0 = time.perf_counter()
             tasks: list[asyncio.Task] = []
             if self.mode == "closed":
-                async def caller(k: int) -> None:
-                    for i in range(k, len(self.requests), self.clients):
+                # one cursor over the list: a free caller takes the next
+                # unsent request, so the requests go out in index order and
+                # admission follows the traffic's stratified blocks, however
+                # long the requests a caller happened to draw. The callers
+                # share this loop's thread, so ``next`` needs no lock
+                unsent = iter(range(len(self.requests)))
+
+                async def caller() -> None:
+                    for i in unsent:
                         self.samples[i].t_due = time.perf_counter()
                         await self._one(session, i)
 
-                tasks = [asyncio.create_task(caller(k)) for k in range(self.clients)]
+                tasks = [asyncio.create_task(caller()) for _ in range(self.clients)]
                 while not self._stop.is_set():
                     await asyncio.sleep(0.01)
             else:

@@ -6,6 +6,7 @@ recorded counter set, and the cell runs end to end at a tiny size on the
 CPU (control flow and counts — never a speed)."""
 
 import json
+import shutil
 import time
 
 import jax
@@ -83,47 +84,95 @@ def test_the_new_files_load_by_name_and_hold_the_published_widths(manifest):
     # the callers fill every row and leave a queue behind them, inside
     # what the engine takes before it sheds (rows + its default queue of 64)
     assert 0 < mix["clients"] - cfg["serve"]["max_batch"] <= 64
-    names = [m["name"] for m in manifest.metrics_of(CELL, "per_layer")]
-    assert set(names) == {f"{n}.mixed" for n in GEN_CLOSED} | set(NEW) | {
-        "programs_compiled", "compiles_in_window"}
+    check_trinity_layers(manifest)
     assert [m["name"] for m in manifest.metrics_of(CELL, "end_to_end")] == [
         "output_tokens_per_s", "setup_s"]
+
+
+def check_trinity_layers(manifest):
+    """The cell reports PR 34's per-layer set, and beyond it only names
+    entered after PR 34's entries; every one has a reader."""
+    names = [m["name"] for m in manifest.metrics_of(CELL, "per_layer")]
+    pr34 = {f"{n}.mixed" for n in GEN_CLOSED} | set(NEW) | {"programs_compiled", "compiles_in_window"}
+    assert pr34 <= set(names)
+    assert set(names) - pr34 <= set(check_appended(manifest.doc))
     for name in names:
         assert hasattr(plugin("readers", manifest.layer_metric(name)["reader"]), "read")
 
 
-#: what ``BENCHMARK.json`` held before PR 34, in its order
+#: what ``BENCHMARK.json`` held once PR 34 was accepted, in its order: what
+#: a later PR adds stands after these
 ACCEPTED = {
-    "configs": ["bert-base", "mistral-7b-l16", "mistral-7b-l12-x4"],
-    "workloads": ["bert-base_mlm-s512", "mistral-7b_gen-closed", "mistral-7b_pretrain-x4"],
+    "configs": ["bert-base", "mistral-7b-l16", "mistral-7b-l12-x4", "trinity-mini-l5"],
+    "workloads": ["bert-base_mlm-s512", "mistral-7b_gen-closed", "mistral-7b_pretrain-x4", CELL],
 }
+PR34_LAYERS = [f"{n}.mixed" for n in GEN_CLOSED] + list(NEW)
 
 
-def test_the_entries_stand_at_the_ends_of_their_lists(manifest):
-    """The accepted entries come first and in their order, the new ones
-    after them; names stay unique, the new configuration has its cell, the
-    cell is appended to its end-to-end metric's list, every entry this PR
-    added reads the new cell alone, and the file stays small."""
-    doc = manifest.doc
+def check_appended(doc) -> list[str]:
+    """The accepted entries come first and in their order, and anything
+    after them is an addition: names stay unique, every configuration has a
+    cell, PR 34's per-layer entries stand directly after ``serve_model_mfu``
+    each reading the new cell alone, no earlier entry reads it, and the file
+    stays small. Returns the per-layer names entered after PR 34's."""
     for key, names in ACCEPTED.items():
-        assert [e["name"] for e in doc[key]] == names + [
-            {"configs": "trinity-mini-l5", "workloads": CELL}[key]]
+        assert [e["name"] for e in doc[key]][: len(names)] == names
     layers = [m["name"] for m in doc["per_layer"]]
-    first_new = layers.index("engine_decode_step_ms.mixed")
+    first_new = layers.index(PR34_LAYERS[0])
+    end = first_new + len(PR34_LAYERS)
     assert layers[first_new - 1] == "serve_model_mfu"
-    assert layers[first_new:] == [f"{n}.mixed" for n in GEN_CLOSED] + list(NEW)
-    for metric in doc["per_layer"][first_new:]:
+    assert layers[first_new:end] == PR34_LAYERS
+    for metric in doc["per_layer"][first_new:end]:
         assert metric["workloads"] == [CELL] and metric["moves"] == "output_tokens_per_s"
     for metric in doc["per_layer"][:first_new]:
         assert CELL not in metric.get("workloads", [])
     by_name = {m["name"]: m for m in doc["end_to_end"]}
-    assert by_name["output_tokens_per_s"]["workloads"] == ["mistral-7b_gen-closed", CELL]
+    assert by_name["output_tokens_per_s"]["workloads"][:2] == ["mistral-7b_gen-closed", CELL]
     assert CELL not in by_name["tokens_per_s"]["workloads"]
     names = [m["name"] for m in doc["end_to_end"] + doc["per_layer"]]
     assert len(names) == len(set(names))
     assert {w["config"] for w in doc["workloads"]} == {c["name"] for c in doc["configs"]}
-    assert sum(w["chips"] == 4 for w in doc["workloads"]) == 1
+    assert sum(w["chips"] == 4 for w in doc["workloads"]) <= max(1, len(doc["workloads"]) // 4)
+    assert len(json.dumps(doc, indent=1)) <= 64 * 1024
+    return layers[end:]
+
+
+def test_the_entries_stand_at_the_ends_of_their_lists(manifest):
+    """The manifest as committed: PR 34's entries where it put them, and
+    ``engine_epoch_drain_share`` for each serving cell appended after them."""
+    later = check_appended(manifest.doc)
+    assert later[:2] == ["engine_epoch_drain_share", "engine_epoch_drain_share.mixed"]
     assert len((ROOT / "BENCHMARK.json").read_bytes()) <= 64 * 1024
+
+
+def test_an_appended_configuration_cell_and_metric_need_no_edit(manifest, tmp_path):
+    """A later PR's additions: one configuration, one cell on it, the cell
+    added to its end-to-end metric's list and one per-layer entry with a
+    ``workloads`` list, all appended — the checks above pass unchanged."""
+    root = tmp_path / "checkout"
+    shutil.copytree(ROOT / "benchmark", root / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    doc = json.loads(json.dumps(manifest.doc))
+    cfg = dict(manifest.config("mistral-7b-l16"), num_hidden_layers=8)
+    (root / "benchmark/configs/appended-probe.json").write_text(json.dumps(cfg))
+    doc["configs"].append({"name": "appended-probe", "source": cfg["source"],
+                           "file": "benchmark/configs/appended-probe.json",
+                           "reduced": ["num_hidden_layers"], "why": "test"})
+    doc["workloads"].append({"name": "appended-probe_gen-closed", "config": "appended-probe",
+                             "traffic": "gen-closed", "chips": 1, "why": "test"})
+    (e2e,) = [m for m in doc["end_to_end"] if m["name"] == "output_tokens_per_s"]
+    e2e["workloads"].append("appended-probe_gen-closed")
+    doc["per_layer"].append({"name": "serve_model_mfu.appended_probe", "unit": "%", "better": "higher",
+                             "source": "program_counter", "layer": "model",
+                             "moves": "output_tokens_per_s",
+                             "workloads": ["appended-probe_gen-closed"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(doc, indent=1))
+
+    scratch = Manifest(root)
+    assert check_appended(scratch.doc)[-1] == "serve_model_mfu.appended_probe"
+    check_trinity_layers(scratch)
+    assert [m["name"] for m in scratch.metrics_of("appended-probe_gen-closed", "per_layer")][-1] == (
+        "serve_model_mfu.appended_probe")
 
 
 def test_the_parameter_count_is_the_one_the_file_states(manifest):

@@ -212,6 +212,68 @@ def test_serve_closed(manifest):
     assert "hbm_peak_gib.closed" in layers and "hbm_peak_gib" not in layers
 
 
+def test_closed_loop_callers_take_the_next_unsent_request():
+    """Six callers, forty requests, and a stub endpoint whose reply time
+    varies by request, the first by far the slowest: the requests go out in
+    index order, each once, and the slow one holds back none after it —
+    where callers that each own every sixth request would leave 6, 12, …
+    waiting for it."""
+    import asyncio
+    import threading
+
+    from aiohttp import web
+
+    from benchmark.traffic import Request
+
+    received: list[int] = []
+
+    async def generate(request):
+        i = (await request.json())["input_ids"][0]
+        received.append(i)
+        resp = web.StreamResponse()
+        await resp.prepare(request)
+        await asyncio.sleep(2.0 if i == 0 else 0.005 * (1 + i % 7))
+        await resp.write(b"data: " + json.dumps({"token_ids": [i]}).encode() + b"\n\n")
+        await resp.write(b'data: {"done": true}\n\n')
+        await resp.write_eof()
+        return resp
+
+    app = web.Application()
+    app.router.add_post("/generate_stream", generate)
+    runner = web.AppRunner(app)
+    loop = asyncio.new_event_loop()
+    loop.run_until_complete(runner.setup())
+    site = web.TCPSite(runner, "127.0.0.1", 0)
+    loop.run_until_complete(site.start())
+    port = site._server.sockets[0].getsockname()[1]
+    server = threading.Thread(target=loop.run_forever, name="stub-endpoint")
+    server.start()
+    requests = [Request(index=i, prompt=(i,), max_new_tokens=1) for i in range(40)]
+    client = serve_common.Client(
+        f"http://127.0.0.1:{port}/generate_stream", requests,
+        mode="closed", clients=6, traced=False, seed=3,
+    )
+    try:
+        client.start()
+        deadline = time.monotonic() + 60
+        while any(s.t_done is None for s in client.samples) and time.monotonic() < deadline:
+            time.sleep(0.01)
+        client.stop()
+    finally:
+        asyncio.run_coroutine_threadsafe(runner.cleanup(), loop).result(timeout=30)
+        loop.call_soon_threadsafe(loop.stop)
+        server.join(timeout=30)
+        loop.close()
+    assert not server.is_alive()
+    samples = client.samples
+    assert all(s.ok for s in samples), [s.error for s in samples if not s.ok]
+    assert sorted(received) == list(range(40))
+    assert sorted(range(40), key=lambda i: samples[i].t_sent) == list(range(40))
+    assert all(samples[i].t_due <= samples[i].t_sent for i in range(40))
+    # every other request went out and came back while the first was served
+    assert max(samples[i].t_done for i in range(1, 40)) < samples[0].t_done
+
+
 def test_serve_open_traced(manifest):
     """No cell is open-loop yet (PERF.md, first open question); the runner
     and the mix ISSUE 22 specified are kept for the one that will be."""

@@ -143,3 +143,30 @@ def test_the_six_new_entries_read_beside_the_old_ones(manifest, reduction):
     parent = evidence(reduction, **{"context.chunk_steps": 8.0})
     got = collect_metrics(manifest, parent, traced=True)
     assert set(NEW) & set(got) == set(NEW[:3])
+
+
+def test_the_epoch_drain_share_reads_the_two_counters_of_each_serving_cell(manifest):
+    """``engine_epoch_drain_share``: the carry rebuilds (epochs) that found
+    the pipeline empty, of all of them. Each serving cell reads it under its
+    own entry; a program without the two counters reads nothing."""
+    spec = manifest.layer_metric("engine_epoch_drain_share")
+    assert spec == {"reader": "counter_share", "plus": ["engine.epoch_drains"],
+                    "over": ["engine.epochs"], "scale": 100.0}
+    assert manifest.layer_metric("engine_epoch_drain_share.mixed") == spec
+    for cell, name in ((CELL, "engine_epoch_drain_share"),
+                       ("trinity-mini_mixed-closed", "engine_epoch_drain_share.mixed")):
+        (entry,) = [m for m in manifest.doc["per_layer"] if m["name"] == name]
+        assert entry == {"name": name, "unit": "%", "better": "lower", "source": "program_counter",
+                         "layer": "engine", "moves": "output_tokens_per_s", "workloads": [cell]}
+        ev = Evidence(cell={"name": cell})
+        ev.numbers.update({"engine.epochs": 168.0, "engine.epoch_drains": 0.0})
+        assert collect_metrics(manifest, ev, traced=True)[name] == {"value": 0.0, "unit": "%"}
+        ev.numbers["engine.epoch_drains"] = 42.0
+        assert collect_metrics(manifest, ev, traced=True)[name]["value"] == pytest.approx(25.0)
+        # the program before the epoch kept the pipeline full had neither counter
+        assert name not in collect_metrics(manifest, Evidence(cell={"name": cell}), traced=True)
+    # training cells have no entry
+    train = Evidence(cell={"name": "bert-base_mlm-s512"})
+    train.numbers.update({"engine.epochs": 10.0, "engine.epoch_drains": 1.0})
+    assert not {"engine_epoch_drain_share", "engine_epoch_drain_share.mixed"} & set(
+        collect_metrics(manifest, train, traced=True))
