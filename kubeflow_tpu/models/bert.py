@@ -24,6 +24,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from kubeflow_tpu.core.parts import HEAD, LOSS
 from kubeflow_tpu.models.transformer import TransformerConfig, dispatch_attention
 
 
@@ -156,7 +157,8 @@ class BertForMaskedLM(nn.Module):
             input_ids, attention_mask, token_type_ids
         )
         h = nn.Dense(self.cfg.hidden_size, dtype=self.cfg.dtype, name="mlm_transform")(seq)
-        h = nn.gelu(h, approximate=False)
+        with jax.named_scope(HEAD):
+            h = nn.gelu(h, approximate=False)
         h = nn.LayerNorm(epsilon=self.cfg.layer_norm_eps, name="mlm_ln")(h)
         return nn.Dense(
             self.cfg.vocab_size, use_bias=True, dtype=jnp.float32, name="unembed"
@@ -190,15 +192,17 @@ def make_mlm_loss_fn(model: BertForMaskedLM, mask_rate: float = 0.15):
 
     def loss_fn(params, batch, rng):
         tokens = batch["inputs"]
-        mask = jax.random.bernoulli(rng, mask_rate, tokens.shape)
-        corrupted = jnp.where(mask, MASK_TOKEN, tokens)
+        with jax.named_scope(LOSS):
+            mask = jax.random.bernoulli(rng, mask_rate, tokens.shape)
+            corrupted = jnp.where(mask, MASK_TOKEN, tokens)
         logits = model.apply({"params": params}, corrupted)
-        per_tok = optax.softmax_cross_entropy_with_integer_labels(logits, tokens)
-        denom = jnp.maximum(mask.sum(), 1)
-        loss = jnp.where(mask, per_tok, 0.0).sum() / denom
-        acc = jnp.where(
-            mask, jnp.argmax(logits, -1) == tokens, False
-        ).sum() / denom
+        with jax.named_scope(LOSS):
+            per_tok = optax.softmax_cross_entropy_with_integer_labels(logits, tokens)
+            denom = jnp.maximum(mask.sum(), 1)
+            loss = jnp.where(mask, per_tok, 0.0).sum() / denom
+            acc = jnp.where(
+                mask, jnp.argmax(logits, -1) == tokens, False
+            ).sum() / denom
         return loss, {"masked_accuracy": acc}
 
     return loss_fn

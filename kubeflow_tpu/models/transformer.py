@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from kubeflow_tpu.core.mesh import Axis
+from kubeflow_tpu.core.parts import HEAD, LOSS
 from kubeflow_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_span,
@@ -997,7 +998,8 @@ class TransformerLM(nn.Module):
             else:
                 x = block(x, positions, segment_ids)
         if logit_positions is not None:
-            x = jnp.take_along_axis(x, logit_positions[:, :, None], axis=1)
+            with jax.named_scope(HEAD):
+                x = jnp.take_along_axis(x, logit_positions[:, :, None], axis=1)
         x = RMSNorm(cfg.norm_eps, name="ln_f")(x)
         logits = nn.Dense(
             cfg.vocab_size, use_bias=False, dtype=jnp.float32, name="unembed"
@@ -1094,17 +1096,18 @@ def make_loss_fn(model: TransformerLM):
         logits, vars_out = model.apply(
             {"params": params}, batch["inputs"], mutable=["losses"]
         )
-        loss = optax.softmax_cross_entropy_with_integer_labels(
-            logits, batch["targets"]
-        ).mean()
-        metrics = {"lm_loss": loss}
-        aux_tree = vars_out.get("losses", {})
-        aux = sum(jnp.sum(v) for v in jax.tree_util.tree_leaves(aux_tree))
-        if aux_tree:
-            loss = loss + aux
-            metrics["moe_aux"] = aux
-        acc = (jnp.argmax(logits, -1) == batch["targets"]).mean()
-        metrics["accuracy"] = acc
+        with jax.named_scope(LOSS):
+            loss = optax.softmax_cross_entropy_with_integer_labels(
+                logits, batch["targets"]
+            ).mean()
+            metrics = {"lm_loss": loss}
+            aux_tree = vars_out.get("losses", {})
+            aux = sum(jnp.sum(v) for v in jax.tree_util.tree_leaves(aux_tree))
+            if aux_tree:
+                loss = loss + aux
+                metrics["moe_aux"] = aux
+            acc = (jnp.argmax(logits, -1) == batch["targets"]).mean()
+            metrics["accuracy"] = acc
         return loss, metrics
 
     return loss_fn

@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from kubeflow_tpu.core.parts import CARRY, HEAD, MERGE, MOE_STATS, SAMPLE
 from kubeflow_tpu.models.transformer import (
     TransformerConfig,
     TransformerLM,
@@ -122,6 +123,9 @@ _SCHED_PHASES = (
     "drain_emit",        # credit tokens, push, spans, retirements
     "park",              # nothing to do
 )
+# As the phases above name the scheduler's time, parts name the device's:
+# the programs below scope what they compute outside the model by
+# core/parts.py's names.
 
 #: disaggregated-serving wire metrics: per-request KV span bytes by leg
 #: (``export`` = prefill replica serving :prefill, ``import`` = decode
@@ -1060,9 +1064,10 @@ class LMEngine:
             variables, tokens, mutable=mutable, **kw
         )
         leaves = jax.tree_util.tree_leaves
+        with jax.named_scope(MOE_STATS):
+            moe = self._moe_counts(leaves(sown["moe_stats"])) if self._moe else None
         return (
-            logits, cache,
-            self._moe_counts(leaves(sown["moe_stats"])) if self._moe else None,
+            logits, cache, moe,
             sum(leaves(sown["quant_stats"])) if quant_stats else None,
         )
 
@@ -1114,29 +1119,34 @@ class LMEngine:
         read window is ``table`` width × page_size (pow2-bucketed by the
         caller)."""
         S = suffix.shape[1]
-        positions = offset + jnp.arange(S)[None, :]          # (1, S)
-        write_ok = (jnp.arange(S) < slen[:, None])           # (1, S)
+        with jax.named_scope(CARRY):
+            positions = offset + jnp.arange(S)[None, :]      # (1, S)
+            write_ok = (jnp.arange(S) < slen[:, None])       # (1, S)
         # the ONLY program that materializes the quantization-error
         # telemetry the model sows (abs, den): per-admission amortization,
         # and the scan-carry chunk programs stay telemetry-free. The head
         # is computed at the one position whose logits are sampled: the
         # piece's other S - 1 rows of (S, vocab) are never built
+        with jax.named_scope(HEAD):
+            wanted = (slen - 1)[:, None]
         logits, cache, moe, qerr = self._forward(
             params, suffix, cache, quant_stats=self.kv_quant == "int8",
             positions=positions, page_table=table, page_write_ok=write_ok,
-            logit_positions=(slen - 1)[:, None],
+            logit_positions=wanted,
         )
         if qerr is None:
             qerr = jnp.zeros((2,), jnp.float32)
-        last = logits[:, 0]
-        tok = _sample(last, rng, temperature[None])
-        if seeded:
-            tok = self._seeded_sample(
-                last, jnp.asarray(seed, jnp.int32)[None],
-                jnp.asarray(pos, jnp.int32)[None], temperature[None], tok,
-            )
-        tok = tok[0]
-        return (cache, tok, tok != self.eos_id, qerr) + self._moe * (moe,)
+        with jax.named_scope(SAMPLE):
+            last = logits[:, 0]
+            tok = _sample(last, rng, temperature[None])
+            if seeded:
+                tok = self._seeded_sample(
+                    last, jnp.asarray(seed, jnp.int32)[None],
+                    jnp.asarray(pos, jnp.int32)[None], temperature[None], tok,
+                )
+            tok = tok[0]
+            live = tok != self.eos_id
+        return (cache, tok, live, qerr) + self._moe * (moe,)
 
     def _implant_paged(self, stored, row: int, n16: int):
         """Scatter a stored prefix (1, kv_heads, n16, D per layer —
@@ -1203,22 +1213,26 @@ class LMEngine:
 
         def step(carry, _):
             cache, tok, gen_count, active, rng = carry
-            rng, sub = jax.random.split(rng)
-            live = active & (gen_count < budget)             # (B,)
-            cur = real_len + gen_count - 1                   # (B,) token idx
+            with jax.named_scope(SAMPLE):
+                rng, sub = jax.random.split(rng)
+            with jax.named_scope(CARRY):
+                live = active & (gen_count < budget)         # (B,)
+                cur = real_len + gen_count - 1               # (B,) token idx
             lg, cache, moe, _ = self._forward(
                 params, tok[:, None], cache, positions=cur[:, None],
                 page_table=table, page_write_ok=live[:, None],
             )
-            nxt = _sample(lg[:, 0], sub, temperature)
-            if seeded:
-                nxt = self._seeded_sample(
-                    lg[:, 0], seed, real_len + gen_count, temperature, nxt
-                )
-            valid = live & (nxt != self.eos_id)
-            out = jnp.where(valid, nxt, self.pad_id)
-            gen_count = jnp.where(live, gen_count + 1, gen_count)
-            tok = jnp.where(valid, out, tok)
+            with jax.named_scope(SAMPLE):
+                nxt = _sample(lg[:, 0], sub, temperature)
+                if seeded:
+                    nxt = self._seeded_sample(
+                        lg[:, 0], seed, real_len + gen_count, temperature, nxt
+                    )
+            with jax.named_scope(CARRY):
+                valid = live & (nxt != self.eos_id)
+                out = jnp.where(valid, nxt, self.pad_id)
+                gen_count = jnp.where(live, gen_count + 1, gen_count)
+                tok = jnp.where(valid, out, tok)
             return (cache, tok, gen_count, valid, rng), (out, valid, moe)
 
         (cache, tok, gen_count, active, _), (toks, valid, moe) = jax.lax.scan(
@@ -1229,9 +1243,11 @@ class LMEngine:
         )
         # (B, T) tokens; beside them, where the model routes, the chunk's
         # routing counts summed over its steps
-        return (cache, tok, gen_count, active, toks.T, valid.T) + self._moe * (
-            jax.tree_util.tree_map(lambda x: x.sum(0), moe),
-        )
+        with jax.named_scope(CARRY):
+            toks, valid = toks.T, valid.T
+        with jax.named_scope(MOE_STATS):
+            moe = jax.tree_util.tree_map(lambda x: x.sum(0), moe)
+        return (cache, tok, gen_count, active, toks, valid) + self._moe * (moe,)
 
     def _merge_carry_impl(
         self, last_tok, gen_count, active, host, toks, valids,
@@ -1247,19 +1263,20 @@ class LMEngine:
         token is not EOS (``valids``). Under speculation the history is
         merged the same way, with a first row's token written at its
         prompt's end."""
-        mode, h_last, h_gen, h_act = host
-        keep, first = mode == _KEEP, mode == _FIRST
-        tok = jnp.stack(toks).astype(last_tok.dtype)
-        valid = jnp.stack(valids)
-        last_tok = jnp.where(keep, last_tok, jnp.where(first, tok, h_last))
-        gen_count = jnp.where(keep, gen_count, jnp.where(first, 1, h_gen))
-        active = jnp.where(keep, active, (h_act != 0) & (valid | ~first))
-        if hist is None:
-            return last_tok, gen_count, active
-        col = jnp.arange(hist.shape[1])[None, :]
-        hist = jnp.where(keep[:, None], hist, hist_host)
-        put = (first & valid)[:, None] & (col == real_len[:, None])
-        return last_tok, gen_count, active, jnp.where(put, tok[:, None], hist)
+        with jax.named_scope(MERGE):
+            mode, h_last, h_gen, h_act = host
+            keep, first = mode == _KEEP, mode == _FIRST
+            tok = jnp.stack(toks).astype(last_tok.dtype)
+            valid = jnp.stack(valids)
+            last_tok = jnp.where(keep, last_tok, jnp.where(first, tok, h_last))
+            gen_count = jnp.where(keep, gen_count, jnp.where(first, 1, h_gen))
+            active = jnp.where(keep, active, (h_act != 0) & (valid | ~first))
+            if hist is None:
+                return last_tok, gen_count, active
+            col = jnp.arange(hist.shape[1])[None, :]
+            hist = jnp.where(keep[:, None], hist, hist_host)
+            put = (first & valid)[:, None] & (col == real_len[:, None])
+            return last_tok, gen_count, active, jnp.where(put, tok[:, None], hist)
 
     # -- host scheduler ----------------------------------------------------- #
 

@@ -24,6 +24,7 @@ from flax.training import train_state
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubeflow_tpu.core.mesh import Axis, MeshSpec, build_mesh, per_device_batch
+from kubeflow_tpu.core.parts import OPTIMIZER
 from kubeflow_tpu.train.checkpoint import CheckpointConfig, Checkpointer
 from kubeflow_tpu.train.metrics import MetricWriter
 
@@ -310,7 +311,8 @@ class Trainer:
                 grads = jax.tree_util.tree_map(lambda g: g / accum, g_sum)
                 loss = loss_sum / accum
                 aux = jax.tree_util.tree_map(lambda a: a / accum, aux_sum)
-            new_state = state.apply_gradients(grads=grads)
+            with jax.named_scope(OPTIMIZER):
+                new_state = state.apply_gradients(grads=grads)
             metrics = {"loss": loss, **aux}
             return new_state, metrics
 
