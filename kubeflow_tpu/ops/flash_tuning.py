@@ -443,7 +443,9 @@ def select_paged_geometry(
     them a step, is for int8 pools (the scale planes lie heads-major, so
     a page is dequantized a head at a time), a single kv head (nothing to
     fold) and spans whose folded scores would not fit. The rows do not
-    enter: every row is a grid step of its own."""
+    enter: the grid takes a step per block of n pages a row reads
+    (`paged_attention.paged_work`), so n sets how many steps a row of a
+    given length takes."""
     page_bytes = 4 * page_size * kv_heads * head_dim * itemsize
     cap = max(1, min(_PAGED_MAX_PAGES, _PAGED_STAGED_BYTES // page_bytes))
     pages = 1

@@ -15,20 +15,30 @@ that read: the block table rides the grid as a **scalar-prefetch
 operand**, and each page operand's BlockSpec index map picks the page to
 stage —
 
-    ``lambda b, i, tbl, *_: (tbl[b, i * n + j], 0, 0)``
+    ``lambda c, k, tbl, lo, hi, pos0, row, blk, *_: (tbl[row[k], blk[k] * n + j], 0, 0)``
 
 — a ``(P, kv_heads, D)`` block: one page of ALL kv heads is one
 contiguous piece of the pool — and the pallas_call pipeline itself
 performs the HBM→VMEM page fetch (double-buffered against compute), fused
 with online-softmax attention over the staged pages. One grid step
 stages **n pages**: the pool is passed n times, operand j reading the
-table at ``i * n + j`` (`ops/flash_tuning.py` ``select_paged_geometry``
-chooses n from the call's shape — a grid of rows x pages, a page a step,
-costs more in steps than the pages' bytes). The table the kernel walks
-is resolved in front of the call: an entry the row's span cannot see
-(past its reach, before its window) names the pool's first page, and a
-block index that repeats from one step to the next is not fetched
-again — so only the pages a row holds leave HBM, once. The page size is
+table at the step's block ``* n + j`` (`ops/flash_tuning.py`
+``select_paged_geometry`` chooses n from the call's shape — a grid of
+rows x pages, a page a step, costs more in steps than the pages' bytes).
+**The grid is a work list** (:func:`paged_work`): one step per (row,
+block of n pages) that holds a page the row's span can see, rows in
+order and blocks ascending within a row, worked out in front of the call
+and passed beside the table as scalar-prefetch operands (``row``,
+``blk`` and whether the step is its row's first or last). A short row takes as
+many steps as its pages fill blocks, not the table's width, and a window
+layer's row only the blocks its window reaches; a row with no page to
+read takes one step, which writes its (zero) output. The grid's length
+is that count, a traced value: no step is left over to walk.
+The table the kernel walks is resolved in front of the call too: an
+entry the row's span cannot see (past its reach, before its window, past
+the pages the row holds) names the pool's first page, and a block index
+that repeats from one step to the next is not fetched again — so only
+the pages a row holds leave HBM, once. The page size is
 the engine's (``select_paged_page_size``).
 
 Span support: queries are a contiguous (K+1)-position speculative verify
@@ -69,6 +79,7 @@ byte-identical to the gather path.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -109,27 +120,98 @@ def dequantize_kv(codes: jax.Array, scale: jax.Array) -> jax.Array:
 # kernel
 # --------------------------------------------------------------------- #
 
-def _live_pages(pos0, *, page_size, span, window, table_pages):
+def _live_pages(pos0, *, page_size, span, window, table_pages, held=None):
     """The page ordinals ``lo <= page <= hi`` (each ``(B,)`` int32) that
     hold a key some query of the span at ``pos0`` may see: not wholly
     past the span's last query (``page * P <= pos0 + S - 1``), inside the
-    table, not wholly before the earliest query's window (``page * P + P
-    - 1 >= pos0 - window + 1``). A dead row (position -1) has none.
-    Computed once in front of the call: the index maps and the kernel
-    read the two numbers, so a page that is not computed is not fetched
-    either and neither works the bounds out again."""
-    hi = jnp.minimum((pos0 + span - 1) // page_size, table_pages - 1)
+    table and the ``held`` pages the row holds (where given), not wholly
+    before the earliest query's window (``page * P + P - 1 >= pos0 -
+    window + 1``). A dead row (position -1, or no page held) has none.
+    Plain array arithmetic, so a numpy ``pos0`` gives numpy bounds (the
+    engine's counters) and a traced one traced bounds (the kernel's):
+    the index maps and the kernel read the two numbers, so a page that
+    is not computed is not fetched either and neither works the bounds
+    out again."""
+    hi = ((pos0 + span - 1) // page_size).clip(max=table_pages - 1)
+    if held is not None:
+        hi = hi.clip(max=held - 1)
     if window is None:
-        return jnp.zeros_like(hi), hi
-    return jnp.maximum((pos0 - window + 1) // page_size, 0), hi
+        return hi * 0, hi
+    return ((pos0 - window + 1) // page_size).clip(min=0), hi
+
+
+#: a grid step's flags (``flags`` operand): the first step of its row
+#: (the accumulators are reset) and its last (the output is written)
+_FIRST, _LAST = 1, 2
+
+
+class PagedWork(NamedTuple):
+    """What one call of the kernel works on: each row's live pages ``lo``
+    ... ``hi`` and the blocks of ``pages`` pages they fill (``blocks``, 0
+    for a dead row). The grid takes a step per live block and one step
+    for each dead row (its output is written)."""
+
+    lo: object
+    hi: object
+    blocks: object
+
+    @property
+    def steps(self):
+        """The grid's length: live blocks, and a step a dead row."""
+        return (self.blocks + (self.blocks == 0)).sum()
+
+    @property
+    def live(self):
+        """The grid's steps that stage a page a row reads."""
+        return self.blocks.sum()
+
+
+def paged_work(pos0, *, table_pages, page_size, span, window, pages,
+               held=None) -> PagedWork:
+    """The kernel's work in numbers, for rows whose spans start at
+    ``pos0`` (``(B,)``, numpy or traced) over a table ``table_pages``
+    wide, ``pages`` staged a grid step, where each row holds ``held``
+    pages (None: the table's width). The kernel's wrapper builds its
+    grid from it and the engine counts that grid with it
+    (`stats["decode_kernel_steps"]`, `["decode_kernel_steps_live"]`), so
+    the count cannot drift from the grid."""
+    lo, hi = _live_pages(
+        pos0, page_size=page_size, span=span, window=window,
+        table_pages=table_pages, held=held,
+    )
+    return PagedWork(lo, hi, (hi // pages - lo // pages + 1) * (hi >= lo))
+
+
+def _work_list(work: PagedWork, pages: int, length: int):
+    """The grid of :func:`paged_work` as three ``(length,)`` int32 arrays
+    — each step's row, its block (pages ``block * n ...``) and its flags
+    — and the grid's length. ``length`` exceeds every count the call can
+    have (rows x the table's blocks, and one), so the arrays have a shape;
+    the entries past the count repeat the last step's row and block with
+    no flag, so an index map read one step past the grid names blocks
+    already staged."""
+    live = work.blocks > 0
+    count = jnp.maximum(work.blocks, 1).astype(jnp.int32)
+    ends = jnp.cumsum(count)
+    steps = ends[-1]
+    k = jnp.arange(length, dtype=jnp.int32)
+    kk = jnp.minimum(k, steps - 1)
+    row = jnp.sum(kk[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
+    t = kk - (ends[row] - count[row])
+    block = jnp.where(live[row], work.lo[row] // pages + t, 0)
+    flags = (k < steps) * (_FIRST * (t == 0) + _LAST * (t == count[row] - 1))
+    return row, block.astype(jnp.int32), flags.astype(jnp.int32), steps
 
 
 def _paged_attn_kernel(
     # scalar prefetch (SMEM)
-    tbl_ref,    # (B, steps * n) int32 page table, dead entries 0
+    tbl_ref,    # (B, blocks * n) int32 page table, dead entries 0
     lo_ref,     # (B,) int32 first live page ordinal
     hi_ref,     # (B,) int32 last live page ordinal
     pos0_ref,   # (B,) int32 span start positions
+    row_ref,    # (B * blocks + 1,) int32 each grid step's row
+    blk_ref,    # (B * blocks + 1,) int32 its block: pages blk * n ... + n - 1
+    flag_ref,   # (B * blocks + 1,) int32 _FIRST | _LAST
     # VMEM blocks
     q_ref,      # folded: (1, Hkv*G*S, D); else (1, Hkv, G*S, D)
     *refs,      # n K pages, n V pages (P, Hkv, D) each; when quantized n
@@ -150,12 +232,12 @@ def _paged_attn_kernel(
     if quant:
         ks_refs, vs_refs, refs = refs[:n], refs[n:2 * n], refs[2 * n:]
     o_ref, acc_ref, m_ref, l_ref = refs
-    b = pl.program_id(0)
-    i = pl.program_id(1)
+    k = pl.program_id(1)
+    b, i, flags = row_ref[k], blk_ref[k], flag_ref[k]
     P, Hkv, S = page_size, kv_heads, span
     GS = groups * span
 
-    @pl.when(i == 0)
+    @pl.when(flags & _FIRST != 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -264,7 +346,7 @@ def _paged_attn_kernel(
                     ) * mult  # (GS, P)
                     online_update(h, s, mask, [v])
 
-    @pl.when(i == pl.num_programs(1) - 1)
+    @pl.when(flags & _LAST != 0)
     def _finish():
         l = l_ref[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -352,20 +434,27 @@ def _paged_attention(
     G = H // Hkv
     W = page_table.shape[1]
     n, fold = tile.pages, tile.fold
-    steps = -(-W // n)
-    # the table as the grid walks it: steps * n columns, an entry the
+    blocks = -(-W // n)
+    page_table = page_table.astype(jnp.int32)
+    # the pages a row holds: its table up to the last entry that names a
+    # page of its own (the allocator fills a row's table from the left;
+    # a free row's is all scratch)
+    ordinal = jnp.arange(blocks * n, dtype=jnp.int32)[None, :]
+    held = jnp.max(jnp.where(page_table != 0, ordinal[:, :W] + 1, 0), axis=1)
+    work = paged_work(
+        pos0.astype(jnp.int32), table_pages=W, page_size=page_size, span=S,
+        window=window, pages=n, held=held,
+    )
+    row, blk, flags, steps = _work_list(work, n, B * blocks + 1)
+    lo, hi = work.lo, work.hi
+    # the table as the grid walks it: blocks * n columns, an entry the
     # row's span cannot see naming the pool's first page — a block index
     # that repeats from one step to the next is not fetched again, so
     # pages a row does not hold (past its reach, before its window) cost
     # no traffic. A (B, W) integer select in front of the call.
-    lo, hi = _live_pages(
-        pos0.astype(jnp.int32), page_size=page_size, span=S, window=window,
-        table_pages=W,
-    )
-    ordinal = jnp.arange(steps * n, dtype=jnp.int32)[None, :]
     table = jnp.where(
         (ordinal >= lo[:, None]) & (ordinal <= hi[:, None]),
-        jnp.pad(page_table.astype(jnp.int32), ((0, 0), (0, steps * n - W))),
+        jnp.pad(page_table, ((0, 0), (0, blocks * n - W))),
         0,
     )
 
@@ -386,17 +475,22 @@ def _paged_attention(
     # row g*S + s of the (G*S) query axis for kv head hkv; a folded call
     # takes all Hkv*G*S rows as one axis
     q_shape = (B, Hkv * G * S, D) if fold else (B, Hkv, G * S, D)
+    # grid step k works on row[k]: consecutive steps of one row keep its
+    # query and output blocks resident, so each is moved once a row
     row_spec = pl.BlockSpec(
         (1,) + q_shape[1:],
-        lambda b, i, *_: (b,) + (0,) * (len(q_shape) - 1),
+        lambda c, k, tbl, lo, hi, pos0, row, *_: (row[k],)
+        + (0,) * (len(q_shape) - 1),
     )
     # the block's last two dims equal the pool's, so any kv_heads / D
     # tiles; the page axis (outermost) is the one the table indexes:
-    # step i's j-th operand stages the page at table[b, i * n + j]
+    # step k's j-th operand stages the page at table[row, blk * n + j]
     page_specs = [
         pl.BlockSpec(
             (page_size, Hkv, D),
-            lambda b, i, tbl, *_, j=j: (tbl[b, i * n + j], 0, 0),
+            lambda c, k, tbl, lo, hi, pos0, row, blk, _, j=j: (
+                tbl[row[k], blk[k] * n + j], 0, 0
+            ),
         )
         for j in range(n)
     ]
@@ -412,7 +506,9 @@ def _paged_attention(
         scale_specs = [
             pl.BlockSpec(
                 (Hkv, 1, 1, page_size),
-                lambda b, i, tbl, *_, j=j: (0, tbl[b, i * n + j], 0, 0),
+                lambda c, k, tbl, lo, hi, pos0, row, blk, _, j=j: (
+                    0, tbl[row[k], blk[k] * n + j], 0, 0
+                ),
             )
             for j in range(n)
         ]
@@ -425,8 +521,11 @@ def _paged_attention(
 
     stat_shape = q_shape[1:-1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, steps),
+        num_scalar_prefetch=7,
+        # the steps under a leading axis of one: on a v5e a grid of one
+        # "arbitrary" axis halted the core at a step that reads no page
+        # (a dead row's), where the same steps under this axis run
+        grid=(1, steps),
         in_specs=in_specs,
         out_specs=row_spec,
         scratch_shapes=[
@@ -448,7 +547,7 @@ def _paged_attention(
         ),
         interpret=interpret,
         name=paged_kernel_name(page_size, tile, Hkv),
-    )(table, lo, hi, pos0.astype(jnp.int32), *operands)
+    )(table, lo, hi, pos0.astype(jnp.int32), row, blk, flags, *operands)
     return out.reshape(B, H, S, D)
 
 

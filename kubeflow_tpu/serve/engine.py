@@ -56,6 +56,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import queue
 import threading
 import time
@@ -85,6 +86,8 @@ from kubeflow_tpu.obs.trace import (
     ctx_from_headers,
     observe_request_latency,
 )
+from kubeflow_tpu.ops.flash_tuning import select_paged_geometry
+from kubeflow_tpu.ops.paged_attention import paged_work
 from kubeflow_tpu.serve.deadline import (
     ADMISSION_SHED,
     DEADLINE_EXPIRED,
@@ -671,6 +674,11 @@ class LMEngine:
             # window layer's window: held in vain on the one table every
             # layer shares — what a per-kind allocator would free
             "kv_pages_held": 0, "kv_pages_dead_window": 0,
+            # the paged kernel's grid in one decode step over all layers
+            # (`_kernel_grid`), summed at each chunk's dispatch where the
+            # chunk reads through the kernel: its length, and the steps of
+            # it that stage a page a row reads
+            "decode_kernel_steps": 0, "decode_kernel_steps_live": 0,
             # expert layers' routing of LIVE rows only (pad slots and dead
             # rows excluded), decode chunks and prefill pieces apart:
             # (token, expert) assignments, distinct (layer, step, expert)
@@ -825,10 +833,20 @@ class LMEngine:
             self.kernel_read = paged_kernel_read(
                 cfg, self.max_batch, self.spec_k + 1
             )
-        #: window layers by their window: what the dead pages counted at
-        #: each chunk's dispatch are summed over
-        self._window_layers = collections.Counter(
-            kind.window for kind in cfg.kinds if kind.window is not None
+        #: layers by their window (None: a global layer): what the dead
+        #: pages and the paged kernel's grid counted at each chunk's
+        #: dispatch are summed over
+        self._layer_windows = collections.Counter(
+            kind.window for kind in cfg.kinds
+        )
+        #: the tile the paged kernel takes at a table width, for the
+        #: chunk's span (the call's own rule, `paged_attention`)
+        pool = next(iter(self.cache.values()))["k"]
+        self._kernel_tile = functools.partial(
+            select_paged_geometry, page_size=self.page_size,
+            kv_heads=cfg.kv_heads, groups=cfg.n_heads // cfg.kv_heads,
+            span=self.spec_k + 1, head_dim=cfg.head_dim,
+            itemsize=pool.dtype.itemsize, quant=self.kv_quant == "int8",
         )
         self._implant_jits: dict[int, Any] = {}
         #: a request held back by page backpressure (FIFO preserved:
@@ -2589,18 +2607,44 @@ class LMEngine:
             self.max_batch * self._carry_pages_w
         )
         self.stats["kv_pages_held"] += self.cfg.n_layers * pages_live
-        for window, layers in self._window_layers.items():
+        for window, layers in self._layer_windows.items():
+            if window is None:
+                continue
             # the pages wholly before the last token's window: those
             # below the one that holds key (reach - 1) - window + 1
             self.stats["kv_pages_dead_window"] += layers * int(
                 (np.maximum(reach - window, 0) // self.page_size).sum()
             )
+        if self.kernel_read:
+            steps, live = self._kernel_grid()
+            self.stats["decode_kernel_steps"] += steps
+            self.stats["decode_kernel_steps_live"] += live
         return _PendingChunk(
             toks=toks, valid=valid, last_tok=tok, gen_count=gen_count,
             active_out=active, active_in=active_in,
             slots=list(self._slots), eos=eos, prop=prop, acc=acc,
             moe=moe[0] if moe else None, t_dispatch=time.monotonic(),
         )
+
+    def _kernel_grid(self) -> tuple[int, int]:
+        """The paged kernel's grid in one decode step, summed over the
+        layers: its length and, of that, the steps that stage a page a
+        row reads — from the rows' reach as the host last saw it, each
+        layer's window and the table's width, through the kernel's own
+        `paged_work`. A row the host holds inactive holds no page here."""
+        width = self._carry_pages_w
+        pos0 = self.real_len + self.gen_count - 1
+        held = np.where(self.active, width, 0)
+        pages = self._kernel_tile(table_pages=width).pages
+        steps = live = 0
+        for window, layers in self._layer_windows.items():
+            work = paged_work(
+                pos0, table_pages=width, page_size=self.page_size,
+                span=self.spec_k + 1, window=window, pages=pages, held=held,
+            )
+            steps += layers * int(work.steps)
+            live += layers * int(work.live)
+        return steps, live
 
     def _drain_chunk(self, p: _PendingChunk) -> None:
         """Bring one chunk's results to the host, credit tokens to the

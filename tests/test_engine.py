@@ -576,7 +576,8 @@ def test_reload_cycle_and_engine_metrics(model_and_params):
         assert 'kubeflow_tpu_engine_active_rows{model="lm"}' in text
         # the decode read path's counters ride the same export
         for key in ("decode_chunks_kernel_read", "decode_pages_live",
-                    "decode_pages_window", "prefill_pieces_flash_read"):
+                    "decode_pages_window", "prefill_pieces_flash_read",
+                    "decode_kernel_steps", "decode_kernel_steps_live"):
             assert f'kubeflow_tpu_engine_{key}{{model="lm"}}' in text
     finally:
         m.unload()
@@ -1367,6 +1368,16 @@ def test_decode_read_path_counters(model_and_params, interpret):
         assert eng.stats["decode_pages_window"] == 32 + 4 * 4
         assert eng.stats["decode_chunks_kernel_read"] == (
             4 if interpret else 0
+        )
+        # the kernel's grid a step, over both layers: the active row's
+        # one block (a table of 2 or 4 pages is one block of 2 or 4) and
+        # a step for each of the three rows that hold nothing, which
+        # stages no page; four chunks. The gather has no grid.
+        assert eng.stats["decode_kernel_steps"] == (
+            4 * 2 * 4 if interpret else 0
+        )
+        assert eng.stats["decode_kernel_steps_live"] == (
+            4 * 2 * 1 if interpret else 0
         )
     finally:
         eng.stop()

@@ -57,9 +57,13 @@ from kubeflow_tpu.ops.flash_tuning import (
     span_kv_block,
 )
 from kubeflow_tpu.ops.paged_attention import (
+    _FIRST,
+    _LAST,
+    _work_list,
     dequantize_kv,
     paged_attention,
     paged_kernel_name,
+    paged_work,
     quantize_kv,
 )
 from kubeflow_tpu.serve.engine import LMEngine
@@ -208,31 +212,50 @@ CELL = dict(H=32, Hkv=8, D=128, P=64)
 #: points at the scratch page, its position is real_len + gen_count - 1
 #: of a free slot)
 RAGGED = (1, 128, 129, 300, None, -1)
+#: the rows of a long table (72 or 70 pages): one key, one page, mid-page,
+#: two rows whose 2,048-key window starts mid-table, the whole width, a
+#: dead row, and -2: a freed row — its table all scratch, its position
+#: left where its request ended (the engine's free slots)
+LONG = (1, 64, 300, 2500, 3500, None, -1, -2)
 
 
 def _cell_case(table_pages, *, span=1, quant=False, dtype=jnp.bfloat16,
-               seed=0):
+               seed=0, rows=RAGGED, tight=False):
     """Queries, pools, table and positions at the cell's head shape with
-    RAGGED rows (None = the table's whole width)."""
+    ``rows`` (None = the table's whole width). ``tight``: each row holds
+    the pages its context fills and the pool those pages and the scratch
+    page, as the engine's allocator leaves them — small enough that the
+    pool bounds the kernel's grid."""
     H, Hkv, D, P = (CELL[k] for k in ("H", "Hkv", "D", "P"))
     rng = np.random.default_rng(seed)
-    ctx = [table_pages * P if c is None else c for c in RAGGED]
+    ctx = [table_pages * P if c is None else c for c in rows]
     ctx = [min(c, table_pages * P) for c in ctx]
     B = len(ctx)
-    n_pages = 1 + B * table_pages
+    live = np.asarray([c > 0 for c in ctx])
+    held = [-(-c // P) if c > 0 else 0 for c in ctx]
+    n_pages = 1 + (sum(held) if tight else B * table_pages)
     T = n_pages * P
     q = jnp.asarray(rng.normal(size=(B, H, span, D)) / np.sqrt(D), dtype)
     kp = jnp.asarray(rng.normal(size=(T, Hkv, D)), dtype)
     vp = jnp.asarray(rng.normal(size=(T, Hkv, D)), dtype)
-    table = 1 + rng.permutation(B * table_pages).astype(np.int32).reshape(
-        B, table_pages
-    )
-    live = np.asarray([c > 0 for c in ctx])
+    if tight:
+        pages = iter(1 + rng.permutation(n_pages - 1).astype(np.int32))
+        table = np.zeros((B, table_pages), np.int32)
+        for b, h in enumerate(held):
+            table[b, :h] = [next(pages) for _ in range(h)]
+    else:
+        table = 1 + rng.permutation(B * table_pages).astype(np.int32).reshape(
+            B, table_pages
+        )
     table[~live] = 0                      # a dead row owns no page
     # the span's first query sits at context - span (its last at the
-    # row's last key); a row shorter than the span starts at 0
-    pos0 = np.asarray([max(c - span, 0) if c > 0 else -1 for c in ctx],
-                      np.int32)
+    # row's last key); a row shorter than the span starts at 0; a freed
+    # row at the table's last key
+    pos0 = np.asarray(
+        [max(c - span, 0) if c > 0 else -1 if c == -1
+         else table_pages * P - span for c in ctx],
+        np.int32,
+    )
     cache = {"k": kp, "v": vp}
     if quant:
         kq, ks = quantize_kv(kp)
@@ -263,46 +286,108 @@ def _kernel_vs_gather(q, cache, table, pos0, live, *, tile, window=None):
     np.testing.assert_allclose(got[live], want[live], atol=tol, rtol=tol)
 
 
+def _grid_within_the_pool(case, window=None):
+    """The grid of a ``tight`` case: a step per live block and per dead
+    row — what `paged_work` counts from the rows' positions and the pages
+    they hold — which stays within what the pool can hold, ``B +
+    ceil(pool / n)`` blocks, itself shorter here than every row across
+    the whole table (the parent's grid)."""
+    q, cache, table, pos0, _ = case
+    B, W = table.shape
+    pool = cache["k"].shape[0] // CELL["P"]
+    n = select_paged_geometry(
+        table_pages=W, page_size=CELL["P"], kv_heads=CELL["Hkv"],
+        groups=CELL["H"] // CELL["Hkv"], span=q.shape[2],
+        head_dim=CELL["D"], itemsize=cache["k"].dtype.itemsize,
+        quant="k_scale" in cache,
+    ).pages
+    work = paged_work(
+        np.asarray(pos0), table_pages=W, page_size=CELL["P"],
+        span=q.shape[2], window=window, pages=n,
+        held=(np.asarray(table) != 0).sum(1),
+    )
+    assert work.steps <= B + -(-pool // n) < B * -(-W // n)
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("table_pages", [1, 8, 16])
+@pytest.mark.parametrize("table_pages", [1, 8, 16, 72])
 def test_selected_geometry_matches_gather_on_ragged_rows(table_pages, quant):
     """What the rule chooses for the cell's head shape at table widths 1, 8
     and 16, on ragged rows: a row of one token, a row ending exactly on a
-    page boundary, a dead row on the scratch page, a full row."""
-    case = _cell_case(table_pages, quant=quant, seed=table_pages)
-    _kernel_vs_gather(*case, tile=None)
+    page boundary, a dead row on the scratch page, a full row. At 72
+    pages, the LONG rows in a pool that bounds the grid, with no window
+    and a 2,048-key one."""
+    if table_pages < 64:
+        case = _cell_case(table_pages, quant=quant, seed=table_pages)
+        _kernel_vs_gather(*case, tile=None)
+        return
+    case = _cell_case(
+        table_pages, quant=quant, seed=table_pages, rows=LONG, tight=True
+    )
+    _grid_within_the_pool(case)
+    for window in (None, 2048):
+        _kernel_vs_gather(*case, tile=None, window=window)
 
 
 @pytest.mark.parametrize("fold", [0, 1, 2, 4], ids="fold{}".format)
-@pytest.mark.parametrize("pages", [1, 2, 4, 8, 16])
-def test_every_pages_per_step_matches_gather(pages, fold):
+@pytest.mark.parametrize("pages,table_pages", [
+    *(pytest.param(p, 16, id=str(p)) for p in (1, 2, 4, 8, 16)),
+    pytest.param(16, 72, id="16-table72"),
+])
+def test_every_pages_per_step_matches_gather(pages, table_pages, fold):
     """Every pages-per-step the rule can return (powers of two up to 16),
     a head at a time and folded in groups of 1, 2 and 4 pages, on a table
     of 16 pages — with a window that leaves whole pages of the longer
-    rows behind it (a row past the window)."""
-    case = _cell_case(16, seed=pages)
+    rows behind it (a row past the window) — and sixteen a step on the
+    LONG rows of a 72-page table in a pool that bounds the grid."""
+    if table_pages == 16:
+        case = _cell_case(16, seed=pages)
+    else:
+        case = _cell_case(table_pages, seed=fold, rows=LONG, tight=True)
     _kernel_vs_gather(
         *case, tile=PagedTile(pages, min(fold, pages)), window=200
     )
 
 
-@pytest.mark.parametrize("pages", [2, 16])
-def test_pages_per_step_int8_and_a_table_it_does_not_divide(pages):
+@pytest.mark.parametrize("pages,table_pages", [
+    pytest.param(2, 6, id="2"), pytest.param(16, 6, id="16"),
+    pytest.param(16, 70, id="16-table70"),
+])
+def test_pages_per_step_int8_and_a_table_it_does_not_divide(pages,
+                                                            table_pages):
     """int8 pools through more than one page a step, and a table width
-    (6) that the step's pages do not divide: the pages past the table are
-    neither fetched nor computed."""
-    case = _cell_case(6, quant=True, seed=pages)
+    (6; 70 with the LONG rows, in a pool that bounds the grid) that the
+    step's pages do not divide: the pages past the table are neither
+    fetched nor computed."""
+    rows = RAGGED if table_pages < 64 else LONG
+    tight = table_pages >= 64
+    case = _cell_case(
+        table_pages, quant=True, seed=pages, rows=rows, tight=tight
+    )
     _kernel_vs_gather(*case, tile=PagedTile(pages, 0), window=200)
-    case = _cell_case(6, dtype=jnp.float32, seed=pages + 1)
+    case = _cell_case(
+        table_pages, dtype=jnp.float32, seed=pages + 1, rows=rows,
+        tight=tight,
+    )
     _kernel_vs_gather(*case, tile=PagedTile(pages, 2))
 
 
-@pytest.mark.parametrize("span", [4, 5])
-def test_verify_span_matches_gather_at_the_cell_shape(span):
+@pytest.mark.parametrize("span,table_pages", [
+    pytest.param(4, 8, id="4"), pytest.param(5, 8, id="5"),
+    pytest.param(5, 72, id="5-table72"),
+])
+def test_verify_span_matches_gather_at_the_cell_shape(span, table_pages):
     """The speculative verify span (K + 1 queries a row) through the
-    rule's geometry: in-span causality, rows shorter than the span."""
-    case = _cell_case(8, span=span, seed=span)
-    _kernel_vs_gather(*case, tile=None)
+    rule's geometry: in-span causality, rows shorter than the span; on a
+    72-page table the LONG rows in a pool that bounds the grid, with no
+    window and a 2,048-key one."""
+    if table_pages < 64:
+        _kernel_vs_gather(*_cell_case(8, span=span, seed=span), tile=None)
+        return
+    case = _cell_case(table_pages, span=span, seed=span, rows=LONG, tight=True)
+    _grid_within_the_pool(case)
+    for window in (None, 2048):
+        _kernel_vs_gather(*case, tile=None, window=window)
 
 
 def test_geometry_pinned_at_the_cell_shape():
@@ -344,6 +429,90 @@ def test_geometry_pinned_at_the_cell_shape():
     assert select_paged_geometry(
         table_pages=16, span=1, itemsize=4, **shape
     ).pages == 8
+
+
+def test_work_list_counts_orders_and_flags_the_grid():
+    """`paged_work`, the kernel's grid in numbers, and `_work_list`, the
+    grid itself. At both serving cells' decode shapes, on rows as the
+    allocator leaves them: Trinity's 96 rows holding all of a 4,096-page
+    pool over a 136-page table, 16 pages a step, walk at most 288 steps
+    on a window layer (three blocks a row) and 352 on the global one (the
+    pool's 4,096 pages in blocks of 16, plus a block a row), where every
+    row across the table is 864; `mistral-7b_gen-closed`'s 32 rows over
+    16 or 32 pages (contexts up to 1,024 or 2,048) walk 32 and at most
+    64, as every row across the table did. Then, on drawn rows: the steps
+    ordered by row and by block, one step for a dead row, the live steps
+    those of the rows' live blocks, and each row's blocks those a brute
+    force finds live."""
+    rng = np.random.default_rng(0)
+    trinity = dict(page_size=64, span=1, pages=16, table_pages=136)
+    for _ in range(20):
+        held = rng.multinomial(4095 - 96, np.full(96, 1 / 96)) + 1
+        held = held.clip(max=136)
+        pos0 = held * 64 - 1 - rng.integers(0, 64, 96)
+        assert paged_work(pos0, window=2048, held=held, **trinity).steps <= 288
+        assert paged_work(pos0, window=None, held=held, **trinity).steps <= 352
+    assert 96 * -(-136 // 16) == 864
+    mistral = dict(page_size=64, span=1, window=4096, pages=16)
+    assert paged_work(
+        rng.integers(0, 1024, 32), table_pages=16, **mistral
+    ).steps == 32
+    assert paged_work(
+        rng.integers(0, 2048, 32), table_pages=32, **mistral
+    ).steps <= 64
+    P, n, W = 16, 4, 40
+    for window, span in ((None, 1), (100, 1), (None, 5), (37, 5)):
+        for _ in range(20):
+            B = int(rng.integers(1, 9))
+            held = rng.integers(0, W + 1, B)
+            # a row's span lies inside what it holds; a row that holds
+            # nothing keeps a stale position (or -1)
+            pos0 = np.where(
+                held > 0,
+                (held * P - span - rng.integers(0, P, B)).clip(min=0),
+                rng.choice([-1, W * P - span], B),
+            )
+            work = paged_work(
+                pos0, table_pages=W, page_size=P, span=span, window=window,
+                pages=n, held=held,
+            )
+            for b in range(B):
+                keys = np.arange(pos0[b], pos0[b] + span)
+                live = {
+                    page for page in range(held[b]) if any(
+                        page * P <= k and (
+                            window is None or page * P + P - 1 > k - window
+                        ) for k in keys
+                    )
+                }
+                assert work.blocks[b] == len({p // n for p in live})
+            length = B * -(-W // n)
+            row, block, flags, steps = map(
+                np.asarray, _work_list(work, n, length)
+            )
+            assert steps == work.steps == np.maximum(work.blocks, 1).sum()
+            assert steps <= length == len(row)
+            real = slice(0, int(steps))
+            assert (np.diff(row[real]) >= 0).all()
+            assert np.bincount(row[real], minlength=B).tolist() == (
+                np.maximum(work.blocks, 1).tolist()
+            )
+            for b in range(B):
+                mine = row[real] == b
+                assert (np.diff(block[real][mine]) == 1).all()
+                assert flags[real][mine][0] & _FIRST
+                assert flags[real][mine][-1] & _LAST
+                assert (flags[real][mine][1:] & _FIRST == 0).all()
+                assert (flags[real][mine][:-1] & _LAST == 0).all()
+                if work.blocks[b]:
+                    assert block[real][mine][0] == work.lo[b] // n
+            assert work.live == work.blocks.sum() == (
+                work.steps - (work.blocks == 0).sum()
+            )
+            # entries past the grid repeat its last step and do nothing
+            assert (row[int(steps):] == row[int(steps) - 1]).all()
+            assert (block[int(steps):] == block[int(steps) - 1]).all()
+            assert not flags[int(steps):].any()
 
 
 def test_read_path_is_chosen_by_span_interpreter_and_mesh():

@@ -213,6 +213,45 @@ def test_trinity_geometry_compiled_with_and_without_a_window(window):
     _kernel_vs_gather(*_cell_case(32, kv_heads=4, seed=8), tile=None, window=window)
 
 
+@pytest.mark.parametrize("window", [2048, None], ids=["window2048", "global"])
+def test_trinity_work_list_compiled_with_and_without_a_window(window):
+    """The grid as a work list at `trinity-mini_mixed-closed`'s decode
+    shape: 96 rows of the mix's contexts (one key to the table's whole
+    width, 30 % past the 2,048 window) over a 136-page table, each row
+    holding the pages its context fills in a pool of those pages, beside
+    a dead row and a freed one (its table all scratch, its position left
+    where its request ended) — a step per live block, not every row
+    across the table — against the gather."""
+    P, Hkv, G, D, W = 64, 4, 8, 128, 136
+    rng = np.random.default_rng(11)
+    ctx = np.clip(
+        1200 * np.exp(rng.normal(size=94)), 1, W * P
+    ).astype(int).tolist() + [W * P, 1]
+    rows = ctx + [-1, -2]
+    B = len(rows)
+    held = [-(-c // P) if c > 0 else 0 for c in rows]
+    pool = 1 + sum(held)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(kq, (B, Hkv * G, 1, D), jnp.bfloat16)
+    cache = {
+        "k": jax.random.normal(kk, (pool * P, Hkv, D), jnp.bfloat16),
+        "v": jax.random.normal(kv, (pool * P, Hkv, D), jnp.bfloat16),
+    }
+    pages = iter(1 + rng.permutation(pool - 1).astype(np.int32))
+    table = np.zeros((B, W), np.int32)
+    for b, h in enumerate(held):
+        table[b, :h] = [next(pages) for _ in range(h)]
+    pos0 = np.asarray(
+        [c - 1 if c > 0 else -1 if c == -1 else W * P - 1 for c in rows],
+        np.int32,
+    )
+    live = np.asarray([c > 0 for c in rows])
+    _kernel_vs_gather(
+        q, cache, jnp.asarray(table), jnp.asarray(pos0), live, tile=None,
+        window=window,
+    )
+
+
 #: a prefill piece of each serving cell: (span, first position, table
 #: pages, kv heads, groups, window)
 PIECES = {
